@@ -1484,8 +1484,28 @@ def run_cache_sweep(args):
     }
 
 
+def _refuse_fleet_mode_on_tpu_host(mode):
+    """--fleet, --zipf-sweep and --restart score an in-process oracle —
+    this parent initialises a JAX backend and so holds every chip — and
+    then start replica processes that each need one. A chip belongs to
+    one process, so on a TPU host those modes do not run until the
+    oracle moves out of the parent."""
+    from photon_ml_tpu.fabric.transport import local_tpu_chips
+
+    chips = local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"bench_serving {mode}: refused on a TPU host ({len(chips)} "
+            f"chip(s)): this process scores the oracle itself, would "
+            f"hold the chips, and its replica processes could get none "
+            f"— run it with JAX_PLATFORMS=cpu for counts and parity")
+
+
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    for mode in ("restart", "zipf_sweep", "fleet"):
+        if getattr(args, mode):
+            _refuse_fleet_mode_on_tpu_host("--" + mode.replace("_", "-"))
     if args.restart:
         out = run_restart(args)
         json.dump(out, sys.stdout)

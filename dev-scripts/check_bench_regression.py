@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Fail if the staging/ingest lines of a fresh bench tail regress >20%
-vs the committed round baseline (BENCH_r05.json).
+vs a baseline bench tail (--baseline, required: the repository commits
+none until the chip benchmark exists — see PERF.md).
 
 The guarded lines are the host-side cold-fit costs the parallel
 pipelines (photon_ml_tpu/game/staging.py + photon_ml_tpu/ingest,
@@ -58,9 +59,9 @@ dump from the same run can no longer silently disagree
 (docs/OBSERVABILITY.md).
 
 Usage:
-  check_bench_regression.py --fresh TAIL.json [--baseline BENCH_r05.json]
+  check_bench_regression.py --fresh TAIL.json --baseline BASE.json
                             [--metrics-dump METRICS.prom]
-  check_bench_regression.py --run-staging     [--baseline BENCH_r05.json]
+  check_bench_regression.py --run-staging     --baseline BASE.json
 
 --fresh takes either a raw bench.py stdout object ({"metric": ...,
 "secondary": {...}}) or a bare section dict (the bench_fresh_host_suite
@@ -179,7 +180,9 @@ def main() -> int:
     src.add_argument("--run-staging", action="store_true",
                      help="measure a fresh staging tail now (slow)")
     ap.add_argument("--baseline",
-                    default=os.path.join(REPO, "BENCH_r05.json"))
+                    help="bench tail JSON to compare against, taken on "
+                         "the same machine (required with --fresh and "
+                         "--run-staging)")
     ap.add_argument("--tolerance", type=float, default=TOLERANCE,
                     help="allowed fractional regression (default 0.20)")
     ap.add_argument("--metrics-dump",
@@ -201,12 +204,17 @@ def main() -> int:
         print("need --fresh, --run-staging, or a --ledger pair")
         return 2
 
-    try:
-        with open(args.baseline) as f:
-            base = _lines(json.load(f))
-    except (OSError, ValueError) as e:
-        print(f"cannot load baseline {args.baseline}: {e}")
-        return 2
+    base = {}  # ledger-only invocation: no bench tail to gate
+    if args.fresh or args.run_staging:
+        if not args.baseline:
+            print("--fresh and --run-staging need --baseline")
+            return 2
+        try:
+            with open(args.baseline) as f:
+                base = _lines(json.load(f))
+        except (OSError, ValueError) as e:
+            print(f"cannot load baseline {args.baseline}: {e}")
+            return 2
     if args.fresh:
         try:
             with open(args.fresh) as f:

@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """Kernel-registry smoke (run_tier1.sh): every registered Pallas program
-runs through the interpreter on CPU and matches its XLA reference; the
-degradation ladder is loud; warm resolves never rebuild. Seconds on CPU
+runs through the interpreter on CPU and matches its XLA reference; a
+kernel switched on where nothing can run it is refused; an injected
+launch fault degrades loudly; warm resolves never rebuild. Seconds on CPU
 (docs/KERNELS.md).
 
 Asserts, through the REAL registry surfaces:
@@ -9,8 +10,9 @@ Asserts, through the REAL registry surfaces:
 1. with ``force_interpret()`` every kernel resolves backend=pallas and
    its output matches the registered XLA closure (bit-equal for the row
    movers, accumulation-order band for the f32 reductions);
-2. with interpret mode OFF (and no TPU), an enabled kernel degrades to
-   the XLA closure LOUDLY — one KernelFallback event per kernel and
+2. with interpret mode OFF (and no TPU), resolving an enabled kernel
+   RAISES; with an injected ``kernel.launch`` fault it degrades to the
+   XLA closure LOUDLY — one KernelFallback event per kernel and
    ``photon_kernel_fallbacks_total`` moving;
 3. warm resolves are hits, not misses: after the parity loop, resolving
    every kernel again moves only ``photon_compile_cache_hits_total`` —
@@ -101,12 +103,27 @@ def main() -> int:
     kf = [e for e in fallbacks if type(e).__name__ == "KernelFallback"]
     assert not kf, f"interpret-mode parity loop degraded: {kf}"
 
-    # 2. interpret off on a TPU-less box: loud fallback per kernel.
+    # 2. interpret off on a TPU-less box: refused. An injected launch
+    # fault (interpret back on): loud fallback per kernel.
+    from photon_ml_tpu import faults
+    from photon_ml_tpu.faults import sites
+
     reg.force_interpret(False)
     for name in fixtures:
-        resolved = reg.resolve(name)
-        assert resolved.backend == "xla", \
-            f"{name}: expected XLA fallback, got {resolved}"
+        try:
+            reg.resolve(name)
+        except RuntimeError as e:
+            assert "no TPU backend" in str(e), e
+        else:
+            raise AssertionError(f"{name}: resolved without a backend")
+    reg.force_interpret()
+    plan = faults.FaultPlan(specs=(
+        faults.FaultSpec(site=sites.KERNEL_LAUNCH, kind="raise"),))
+    with faults.installed(plan):
+        for name in fixtures:
+            resolved = reg.resolve(name)
+            assert resolved.backend == "xla", \
+                f"{name}: expected XLA fallback, got {resolved}"
     kf = [e for e in fallbacks if type(e).__name__ == "KernelFallback"]
     assert len(kf) == len(fixtures), \
         f"expected {len(fixtures)} loud fallbacks, saw {len(kf)}"

@@ -175,9 +175,11 @@ fi
 
 # Opt-in staging-bench regression gate (slow: measures a fresh 10M-row
 # staging tail, several minutes). PML_CHECK_BENCH=1 enables it; a >20%
-# regression of the guarded staging lines vs the committed round
-# baseline fails the run. See dev-scripts/check_bench_regression.py.
+# regression of the guarded staging lines vs the bench tail named by
+# PML_BENCH_BASELINE (taken on this machine; none is committed) fails
+# the run. See dev-scripts/check_bench_regression.py.
 if [ "$rc" -eq 0 ] && [ "${PML_CHECK_BENCH:-0}" = "1" ]; then
-  env JAX_PLATFORMS=cpu python dev-scripts/check_bench_regression.py --run-staging; rc=$?
+  env JAX_PLATFORMS=cpu python dev-scripts/check_bench_regression.py --run-staging \
+    --baseline "${PML_BENCH_BASELINE:?PML_CHECK_BENCH=1 needs PML_BENCH_BASELINE=<bench tail JSON>}"; rc=$?
 fi
 exit $rc

@@ -40,7 +40,7 @@ from photon_ml_tpu.data.validators import (DataValidationLevel,
                                            validate_game_dataset)
 from photon_ml_tpu.models import io as model_io
 from photon_ml_tpu.optim.problem import GLMOptimizationConfiguration
-from photon_ml_tpu.parallel.mesh import make_mesh
+from photon_ml_tpu.parallel.mesh import device_summary, make_mesh
 from photon_ml_tpu.types import TaskType
 from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
 from photon_ml_tpu.utils.logging import setup_logging
@@ -393,14 +393,12 @@ def _sync_global_devices_or_skip(tag: str) -> None:
 
     The barrier is a device collective, and the CPU backend cannot run
     multi-process collectives at all ("Multiprocess computations aren't
-    implemented" — the pre-existing DCN dryrun crash, CHANGES PR 7).
-    On such a backend the sync seam degrades to a logged no-op: the
-    checkpoint-cleanup race it guards is a real-filesystem concern that
-    CPU multi-process runs (localhost test worlds) do not actually
-    have, and crashing the whole distributed dryrun over an
-    unimplementable barrier inverts the robustness contract. Any OTHER
-    failure still raises — a silently skipped barrier on a backend that
-    needed one would be resuming-from-wrong-state by another name.
+    implemented"). On that backend the sync seam degrades to a logged
+    no-op: the checkpoint-cleanup race it guards is a real-filesystem
+    concern that CPU multi-process runs (localhost test worlds) do not
+    actually have. On every other backend a failed barrier raises — a
+    silently skipped barrier where one was needed would be
+    resuming-from-wrong-state by another name.
     """
     import jax
 
@@ -413,17 +411,7 @@ def _sync_global_devices_or_skip(tag: str) -> None:
         return
     from jax.experimental import multihost_utils
 
-    try:
-        multihost_utils.sync_global_devices(tag)
-    except (NotImplementedError, RuntimeError) as e:
-        # XLA surfaces UNIMPLEMENTED as an XlaRuntimeError (a
-        # RuntimeError); anything else is a real failure and re-raises.
-        if "implemented" not in str(e).lower():
-            raise
-        logger.warning(
-            "SKIPPING sync_global_devices(%r): backend %s cannot run "
-            "it (%s) — ranks proceed unbarriered", tag,
-            jax.default_backend(), e)
+    multihost_utils.sync_global_devices(tag)
 
 
 def _disarm_fabric() -> None:
@@ -646,6 +634,9 @@ def _run(args) -> dict:
         sweep=(parse_sweep_config(args.sweep)
                if getattr(args, "sweep", None) is not None
                else None))
+    device = device_summary()
+    logger.info("running on platform=%s device_kind=%s devices=%d",
+                device["platform"], device["kind"], device["count"])
 
     initial_models = None
     if args.model_input_dir:
@@ -773,6 +764,7 @@ def _run(args) -> dict:
                 json.dump(avro_meta.entity_vocabs, f)
     summary = {
         "task": task.value,
+        "device": device,
         # Byte-level fingerprint of the selected model: two runs (or two
         # DCN ranks) trained the SAME model iff these agree — a far
         # sharper probe than any rounded metric (VERDICT Weak #6).

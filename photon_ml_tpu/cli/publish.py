@@ -187,7 +187,7 @@ def compact_generations(args, ledger) -> dict:
 
 def push_to_fleet(args, delta, ledger) -> dict:
     """Drive the fleet's canary ladder over HTTP; raises the publish
-    taxonomy mapped back from the front door's defined statuses."""
+    error classes mapped back from the front door's defined statuses."""
     from photon_ml_tpu.serving.publish import (CanaryRejected,
                                                PublishError)
 

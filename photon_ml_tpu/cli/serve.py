@@ -22,6 +22,7 @@ import logging
 import os
 
 from photon_ml_tpu.models import io as model_io
+from photon_ml_tpu.parallel.mesh import device_summary
 from photon_ml_tpu.serving.service import ScoringService, make_http_server
 from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
 from photon_ml_tpu.utils.logging import setup_logging
@@ -226,6 +227,11 @@ def create_server(args):
 
     t_boot, e_boot = _time.perf_counter(), _time.time_ns()
     enable_compilation_cache()
+    device = device_summary()
+    # A lone server scores on the first device only, by design.
+    logger.info("running on platform=%s device_kind=%s devices=%d "
+                "(scoring uses one)", device["platform"], device["kind"],
+                device["count"])
     t0, e0 = _time.perf_counter(), _time.time_ns()
     model, vocabs, boot_meta = load_model(args)
     _phase("boot.map", t0, e0)

@@ -9,7 +9,9 @@ ladder babysit replicas it cannot ``Popen``:
 
 - ``LocalTransport`` — today's subprocess spawn, verbatim: ``spawn``
   -style children, output to FILES never pipes, generation-named
-  ready-file handshake, ``proc.poll()`` liveness, SIGKILL + reap.
+  ready-file handshake, ``proc.poll()`` liveness, SIGKILL + reap. On a
+  TPU host replica *i* is given chip *i* and nothing else: a chip
+  belongs to one process, and an unpinned child takes every chip.
 - ``RemoteTransport`` — replicas owned by per-machine agents
   (fabric/agent.py), addressed by host:port. Spawn/kill/liveness go
   through the agent's HTTP control plane (every call a finite timeout —
@@ -34,6 +36,7 @@ assuming a shared filesystem.
 
 from __future__ import annotations
 
+import glob
 import http.server
 import json
 import logging
@@ -54,6 +57,18 @@ logger = logging.getLogger("photon_ml_tpu.serving.fleet")
 
 class ReplicaStartupError(RuntimeError):
     """A replica did not reach ready/healthy within its deadline."""
+
+
+def local_tpu_chips() -> list[str]:
+    """This host's TPU chip device nodes, found without JAX — the fleet
+    parent must never initialise a backend, or it would hold the chips
+    its replicas need. Empty when the replicas are not headed for a TPU
+    (no nodes, or ``JAX_PLATFORMS`` names other platforms only)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    return sorted(glob.glob("/dev/vfio/[0-9]*")
+                  + glob.glob("/dev/accel[0-9]*"))
 
 
 def _get_json(url: str, timeout_s: float) -> dict:
@@ -88,6 +103,11 @@ class Transport:
         ``(host, port)``. Raises ReplicaStartupError on child exit or
         deadline (``time.monotonic()`` instant)."""
         raise NotImplementedError
+
+    def check_capacity(self, num_replicas: int) -> None:
+        """Raise ``ReplicaStartupError`` when this transport cannot
+        place that many replicas at once (default: no limit). Asked
+        BEFORE anything is spawned, so a refusal starts no process."""
 
     def alive(self, handle) -> Optional[bool]:
         """Process-layer liveness: True = running, False = POSITIVELY
@@ -124,6 +144,15 @@ class LocalTransport(Transport):
         return os.path.join(self.workdir,
                             f"replica-{rid}.g{generation}.ready")
 
+    def check_capacity(self, num_replicas: int) -> None:
+        chips = local_tpu_chips()
+        if chips and num_replicas > len(chips):
+            raise ReplicaStartupError(
+                f"{num_replicas} replica(s) cannot be placed: this host "
+                f"has {len(chips)} TPU chip(s) ({', '.join(chips)}), "
+                f"replica i runs on chip i, and a chip belongs to one "
+                f"process — ask for at most {len(chips)}")
+
     def spawn(self, handle) -> None:
         rid = handle.replica_id
         ready = self._ready_file(rid, handle.generation)
@@ -141,6 +170,14 @@ class LocalTransport(Transport):
         env = dict(os.environ)
         env["PYTHONPATH"] = (pkg_root + os.pathsep + env["PYTHONPATH"]
                              if env.get("PYTHONPATH") else pkg_root)
+        self.check_capacity(rid + 1)
+        if local_tpu_chips():
+            # libtpu's own placement variables: one visible chip, and a
+            # 1x1x1 process/chip grid so it does not wait for the
+            # host's other chips.
+            env.update({"TPU_VISIBLE_CHIPS": str(rid),
+                        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                        "TPU_PROCESS_BOUNDS": "1,1,1"})
         log_f = open(handle.log_path, "ab")
         try:
             handle.proc = subprocess.Popen(
@@ -392,7 +429,7 @@ class DeltaArtifactServer:
     """Serves a publish directory's delta artifacts over HTTP (read-
     only, traversal-fenced). The CRC fence stays with the ARTIFACT:
     the fetching replica re-verifies via ``read_delta``, so a torn or
-    bit-flipped transfer lands in the same ``DeltaCorrupt`` taxonomy
+    bit-flipped transfer lands in the same ``DeltaCorrupt`` class
     as a torn shared-filesystem write."""
 
     def __init__(self, publish_dir: str, host: str = "127.0.0.1",

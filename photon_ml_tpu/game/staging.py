@@ -1,9 +1,8 @@
 """Parallel, pipelined host staging for projected random effects.
 
-BENCH_r05 put the per-entity projection pass at ~40 s of the ~42 s cold
-staging time for 10M rows / 1M entities — the dominant end-to-end cost of
-a cold GAME fit, while the vmapped coordinate fits it feeds finish in
-under a second. The structure of the fix is the one Snap ML
+The per-entity projection pass is serial host work in front of the
+vmapped coordinate fits it feeds; its share of a cold GAME fit is not
+measured on the current chip's host. The structure is the one Snap ML
 (arXiv:1803.06333) and "Large-Scale Stochastic Learning using GPUs"
 (arXiv:1702.07005) use: partition the host-side data-preparation work and
 OVERLAP it with accelerator compute instead of serializing

@@ -1,8 +1,8 @@
 """Block-parallel, pipelined Avro decode with a deterministic merge.
 
-BENCH_r05 pinned native Avro decode at ~123k records/s — ~81 s of
-SERIAL work in front of the 10M-row cold fit, nearly 2x the entire
-parallelized staging pass it feeds (docs/STAGING.md). This module is
+Native Avro decode is SERIAL host work in front of a cold fit and of
+the parallelized staging pass it feeds (docs/STAGING.md); its rate is
+not measured on the current chip's host. This module is
 the staging pipeline's structure applied one layer upstream: the input
 splits at Avro sync-marker block boundaries (ingest/blocks.py), native
 decode workers fan over the resulting chunks — a thread pool by

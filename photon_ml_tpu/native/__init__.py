@@ -2,11 +2,13 @@
 
 The compute path is JAX/XLA; these are host-side runtime pieces where the
 reference uses native-adjacent code (PalDB). Shared objects build on first
-use with g++ and are cached under ``_build/``.
+use with g++ and are cached under ``_build/``, each object named by a
+hash of the source it was built from.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,14 +19,18 @@ _LOCK = threading.Lock()
 
 
 def build_library(name: str, link: tuple[str, ...] = ()) -> str:
-    """Compile ``<name>.cc`` into ``_build/lib<name>.so`` (once) and return
-    the path. Rebuilds when the source is newer than the cached object.
-    ``link`` appends linker flags (e.g. ``("-lz",)``)."""
+    """Compile ``<name>.cc`` into ``_build/lib<name>-<digest>.so`` (once)
+    and return the path. The digest is of the source bytes and the
+    linker flags, so an object copied in from another checkout or left
+    by an older source is never loaded: a changed source is a new file
+    name. ``link`` appends linker flags (e.g. ``("-lz",)``)."""
     src = os.path.join(_HERE, f"{name}.cc")
-    out = os.path.join(_BUILD_DIR, f"lib{name}.so")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(link).encode())
+    out = os.path.join(_BUILD_DIR,
+                       f"lib{name}-{digest.hexdigest()[:16]}.so")
     with _LOCK:
-        if (not os.path.exists(out)
-                or os.path.getmtime(out) < os.path.getmtime(src)):
+        if not os.path.exists(out):
             os.makedirs(_BUILD_DIR, exist_ok=True)
             # pid-suffixed temp + atomic rename: concurrent builders (e.g.
             # pytest-xdist workers — the threading lock is per-process) each

@@ -6,14 +6,11 @@ in the package — and importing THIS package is what populates it: each
 kernel module pairs a Pallas program with its XLA reference closure, and
 the specs below bind them under a flag.
 
-Flag defaults record the committed ``bench_kernels`` sweep (BENCH.md),
-not hope: ``ell_scatter`` ships ON because BENCH_r05 measured the Pallas
-scatter 4.6× over XLA on TPU at the bench shape (the auto-dispatch
-ops/sparse_aggregators.py has trusted since r05 — the registry keeps
-that decision, it just makes the fallback loud); the remaining five ship
-OFF until a sweep on a TPU box flips them (this tree's committed sweeps
-ran on the CPU host, where Pallas timings are interpret-mode and stamped
-invalid — docs/KERNELS.md "The sweep workflow").
+All six compile on the TPU v5e and meet their parity bands there
+(chip_smoke.py's kernel leg). Which of them is FASTER than its XLA
+closure is not measured on the current chip: ``ell_scatter`` keeps the
+default-on it has always had on the TPU backend, the other five stay
+off, and a sweep on the chip decides each (ROADMAP Design 3).
 """
 
 from __future__ import annotations
@@ -29,7 +26,7 @@ registry().register(KernelSpec(
     xla_fn=ell_scatter.scatter_rowterm_xla,
     doc="ELL scatter-add as one-hot compare+accumulate tiles "
         "(gradient of the sparse GLM pass)",
-    default_on=True,  # BENCH_r05 scatter_pallas_d512_us: 4.6x over XLA
+    default_on=True,  # on the TPU backend; not measured on the current chip
 ))
 
 registry().register(KernelSpec(

@@ -11,9 +11,14 @@ moves over the (num_entities+1, d) coefficient table:
 XLA compiles each into its own gather/scatter program with the moved
 rows staged through HBM between programs. These Pallas programs make
 each move ONE grid schedule: the bucket's row ids ride scalar prefetch,
-so the table BlockSpec's index_map addresses block (rows[i], 0) directly
-— the row id IS the block address, and each row crosses HBM exactly
-once. The scatter aliases the table in place (``input_output_aliases``),
+so the table BlockSpec's index_map addresses block (rows[i], 0, 0)
+directly — the row id IS the block address, and each row crosses HBM
+exactly once. The table is addressed through an (E, 1, d) view: Mosaic
+tiles the LAST TWO dims of a block (8 sublanes x 128 lanes for f32) and
+accepts a block only when those dims are tile multiples or span the
+array, so a one-row block must carry the row id on a leading, untiled
+dim and span the trailing (1, d) whole. The scatter aliases the table
+in place (``input_output_aliases``),
 so untouched rows are preserved without rewriting the table — the same
 donation contract the XLA ``.at[].set`` path gets from
 ``donate_argnums``.
@@ -44,6 +49,11 @@ Array = jax.Array
 _LANE = 128
 
 
+def _row_view(a: Array) -> Array:
+    """(E, d) → (E, 1, d_pad): lanes padded to 128, one row per block."""
+    return _pad_axis(a, _LANE, 1, 0)[:, None, :]
+
+
 def _copy_kernel(rows_ref, src_ref, out_ref):
     del rows_ref  # consumed by the index maps, not the body
     out_ref[...] = src_ref[...]
@@ -65,22 +75,21 @@ def gather_rows_pallas(W: Array, rows: Array,
     read in-bounds)."""
     b = rows.shape[0]
     d = W.shape[1]
-    w_p = _pad_axis(W, _LANE, 1, 0)
+    w3 = _row_view(W)
+    row_block = (None, 1, w3.shape[2])
     rr = jnp.maximum(jnp.asarray(rows, jnp.int32), 0)
     out = pl.pallas_call(
         _copy_kernel,
-        out_shape=jax.ShapeDtypeStruct((b, w_p.shape[1]), W.dtype),
+        out_shape=jax.ShapeDtypeStruct((b,) + w3.shape[1:], W.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b,),
-            in_specs=[pl.BlockSpec((1, w_p.shape[1]),
-                                   lambda i, r: (r[i], 0))],
-            out_specs=pl.BlockSpec((1, w_p.shape[1]),
-                                   lambda i, r: (i, 0)),
+            in_specs=[pl.BlockSpec(row_block, lambda i, r: (r[i], 0, 0))],
+            out_specs=pl.BlockSpec(row_block, lambda i, r: (i, 0, 0)),
         ),
         interpret=interpret,
-    )(rr, w_p)
-    return out[:, :d]
+    )(rr, w3)
+    return out[:, 0, :d]
 
 
 def gather_rows_xla(W: Array, rows: Array) -> Array:
@@ -98,35 +107,35 @@ def scatter_rows_pallas(W: Array, rows: Array, vals: Array,
     whole wave is padding, they instead rewrite row 0 with its own
     current contents — a no-op scatter either way."""
     d = W.shape[1]
-    w_p = _pad_axis(W, _LANE, 1, 0)
-    v_p = _pad_axis(jnp.asarray(vals, W.dtype), _LANE, 1, 0)
+    w3 = _row_view(W)
+    v3 = _row_view(jnp.asarray(vals, W.dtype))
+    row_block = (None, 1, w3.shape[2])
     rows = jnp.asarray(rows, jnp.int32)
     valid = rows >= 0
     i_star = jnp.argmax(rows)  # lane of the largest (hence valid) row id
     row_star = jnp.maximum(rows[i_star], 0)
     any_valid = jnp.any(valid)
-    safe_vals = jnp.where(any_valid, v_p[i_star], w_p[row_star])
+    safe_vals = jnp.where(any_valid, v3[i_star], w3[row_star])
     rows_fix = jnp.where(valid, rows, row_star)
-    vals_fix = jnp.where(valid[:, None], v_p, safe_vals[None, :])
+    vals_fix = jnp.where(valid[:, None, None], v3, safe_vals[None])
     out = pl.pallas_call(
         _scatter_kernel,
-        out_shape=jax.ShapeDtypeStruct(w_p.shape, W.dtype),
+        out_shape=jax.ShapeDtypeStruct(w3.shape, W.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(rows.shape[0],),
             in_specs=[
-                pl.BlockSpec((1, w_p.shape[1]), lambda i, r: (i, 0)),
-                pl.BlockSpec((1, w_p.shape[1]), lambda i, r: (r[i], 0)),
+                pl.BlockSpec(row_block, lambda i, r: (i, 0, 0)),
+                pl.BlockSpec(row_block, lambda i, r: (r[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, w_p.shape[1]),
-                                   lambda i, r: (r[i], 0)),
+            out_specs=pl.BlockSpec(row_block, lambda i, r: (r[i], 0, 0)),
         ),
         # Operand indices count the scalar-prefetch arg: 0=rows_fix,
-        # 1=vals_fix, 2=w_p → alias the TABLE into the output.
+        # 1=vals_fix, 2=w3 → alias the TABLE into the output.
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(rows_fix, vals_fix, w_p)
-    return out[:, :d]
+    )(rows_fix, vals_fix, w3)
+    return out[:, 0, :d]
 
 
 def scatter_rows_xla(W: Array, rows: Array, vals: Array) -> Array:

@@ -23,10 +23,13 @@ package registers here with
   program builds by backend;
 * a **loud failure ladder** — the fault site ``kernel.launch`` fires at
   the moment the registry commits to the Pallas backend; a fault there
-  (or a non-TPU backend without interpret mode) degrades to the XLA
-  closure and emits :class:`~photon_ml_tpu.utils.events.KernelFallback`
-  + ``photon_kernel_fallbacks_total`` — the ingest native-fallback
-  discipline, applied to kernels.
+  degrades to the XLA closure and emits
+  :class:`~photon_ml_tpu.utils.events.KernelFallback` +
+  ``photon_kernel_fallbacks_total`` — the ingest native-fallback
+  discipline, applied to kernels. A kernel switched on where nothing
+  can run it (no TPU, interpret mode not forced) is an error, not a
+  degradation: the run would otherwise finish on another program than
+  the one it was told to use.
 
 Resolution happens at program-BUILD time (service init, streamed-kernel
 cache fill, bucket-program build), never per launch: the resolved
@@ -128,7 +131,10 @@ class KernelRegistry:
 
     def enabled(self, name: str) -> bool:
         """Override > environment (``PHOTON_KERNEL_<NAME>``) > the
-        registered sweep default."""
+        registered sweep default. The default records a sweep on the
+        TPU and holds there only: elsewhere a default-on kernel is off
+        by policy (the silent XLA rung), so only an explicit override
+        or variable can ask for a program the backend cannot run."""
         spec = self.get(name)
         with self._lock:
             ov = self._overrides.get(name)
@@ -137,13 +143,12 @@ class KernelRegistry:
         env = os.environ.get(f"PHOTON_KERNEL_{name.upper()}")
         if env is not None:
             return env not in ("0", "false", "off", "")
-        return spec.default_on
+        return spec.default_on and jax.default_backend() == "tpu"
 
     def force_interpret(self, value: bool = True) -> None:
         """Run Pallas programs through the interpreter on non-TPU
-        backends instead of falling back — the tier-1 CPU smoke/test
-        mode. Parity-grade only; bench stamps interpret timings
-        invalid."""
+        backends instead of refusing — the tier-1 CPU smoke/test
+        mode. Parity-grade only, never timed."""
         with self._lock:
             self._force_interpret = value
 
@@ -167,8 +172,8 @@ class KernelRegistry:
         The decision ladder, in order: flag off → XLA (policy, silent);
         injected ``kernel.launch`` fault → XLA (loud KernelFallback);
         TPU backend → Pallas; interpret forced → Pallas interpreter;
-        anything else → XLA (loud KernelFallback — a flag asked for a
-        fused program this box cannot run)."""
+        anything else raises — a flag asked for a fused program this
+        box cannot run."""
         spec = self.get(name)
         if not self.enabled(name):
             return self._done(spec, dtype, spec.xla_fn, "xla")
@@ -185,9 +190,10 @@ class KernelRegistry:
                 return _fn(*args, interpret=True, **kw)
             return self._done(spec, dtype, interp, "pallas",
                               interpret=True)
-        return self._fallback(
-            spec, dtype,
-            f"no TPU backend (backend={jax.default_backend()})")
+        raise RuntimeError(
+            f"kernel {name!r} is switched on but cannot run here: no TPU "
+            f"backend (backend={jax.default_backend()}) and interpret "
+            f"mode is not forced")
 
     # -- internals ---------------------------------------------------------
 

@@ -19,7 +19,12 @@ f32 scalar write per example.
 
 Grid: one step per batch row. ``slots`` rides
 ``PrefetchScalarGridSpec``, so the cache BlockSpec's index_map addresses
-block (slots[i], 0) — the gather IS the block schedule, not an op.
+block (slots[i], 0, 0) — the gather IS the block schedule, not an op.
+Every operand is addressed through an (rows, 1, d) view, for the reason
+ops/kernels/re_rows.py gives: Mosaic tiles a block's last two dims, so a
+one-row block carries its row id on a leading dim. The per-row dequant
+scale is gathered by XLA beforehand — (n,) floats — and rides scalar
+prefetch beside the slots, so the kernel reads it as one SMEM scalar.
 """
 
 from __future__ import annotations
@@ -29,17 +34,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from photon_ml_tpu.ops.kernels.ell_scatter import _pad_axis
+from photon_ml_tpu.ops.kernels.re_rows import _row_view
 
 Array = jax.Array
 
-_LANE = 128
 
-
-def _score_kernel(slots_ref, mat_ref, row_ref, sc_ref, out_ref):
+def _score_kernel(slots_ref, scale_ref, mat_ref, row_ref, out_ref):
     del slots_ref  # consumed by the index maps, not the body
-    acc = jnp.sum(mat_ref[...] * row_ref[...].astype(jnp.float32))
-    out_ref[0, 0] = acc * sc_ref[0, 0]
+    acc = jnp.sum(mat_ref[...] * row_ref[...].astype(jnp.float32),
+                  axis=1, keepdims=True)
+    out_ref[...] = acc * scale_ref[pl.program_id(0)]
 
 
 def score_rows_pallas(mat: Array, slots: Array, cache: Array,
@@ -53,33 +57,34 @@ def score_rows_pallas(mat: Array, slots: Array, cache: Array,
     (E,) f32 per-row dequant scales, or None for f32 caches (the
     fallback slot's scale is 0, so it dequantizes to exactly zero — same
     contract as the XLA chain)."""
-    n, d = mat.shape
-    mat_p = _pad_axis(jnp.asarray(mat, jnp.float32), _LANE, 1, 0.0)
-    cache_p = _pad_axis(cache, _LANE, 1, 0)
-    d_pad = mat_p.shape[1]
+    n = mat.shape[0]
+    mat3 = _row_view(jnp.asarray(mat, jnp.float32))
+    cache3 = _row_view(cache)
+    row_block = (None, 1, mat3.shape[2])
     slots = jnp.clip(jnp.asarray(slots, jnp.int32), 0,
                      cache.shape[0] - 1)
     if scale is None:
         # f32 cache: fold a unit scale so both modes share one program
-        # (×1.0 is bit-exact, and (E,) f32 is noise next to the table).
-        scale = jnp.ones((cache.shape[0],), jnp.float32)
-    scale_2d = jnp.asarray(scale, jnp.float32).reshape(-1, 1)
+        # (×1.0 is bit-exact).
+        row_scale = jnp.ones((n,), jnp.float32)
+    else:
+        row_scale = jnp.asarray(scale, jnp.float32)[slots]
     out = pl.pallas_call(
         _score_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, 1, 1), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, d_pad), lambda i, s: (i, 0)),
-                pl.BlockSpec((1, d_pad), lambda i, s: (s[i], 0)),
-                pl.BlockSpec((1, 1), lambda i, s: (s[i], 0)),
+                pl.BlockSpec(row_block, lambda i, s, sc: (i, 0, 0)),
+                pl.BlockSpec(row_block, lambda i, s, sc: (s[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1), lambda i, s: (i, 0)),
+            out_specs=pl.BlockSpec((None, 1, 1),
+                                   lambda i, s, sc: (i, 0, 0)),
         ),
         interpret=interpret,
-    )(slots, mat_p, cache_p, scale_2d)
-    return out[:, 0]
+    )(slots, row_scale, mat3, cache3)
+    return out[:, 0, 0]
 
 
 def score_rows_xla(mat: Array, slots: Array, cache: Array,

@@ -18,10 +18,10 @@ moderate coefficient dimensions the Pallas compare+accumulate kernel
 (ops/kernels/ell_scatter.py, registry name ``ell_scatter``) wins — it is
 O(d·nnz), so XLA's scatter takes over for large d. The dimension policy
 below picks the CANDIDATE; whether the Pallas program actually runs is
-the kernel registry's call (flag + backend), and a registry-level
-degradation — flag on but no TPU, or an injected ``kernel.launch`` fault
-— is LOUD (KernelFallback event + counter), unlike the silent
-TPU-backend guard this module shipped with. Set ``USE_PALLAS`` to force
+the kernel registry's call (flag + backend): the flag's default holds on
+the TPU backend only, a flag forced on where nothing can run the program
+raises, and a registry-level degradation — an injected ``kernel.launch``
+fault — is LOUD (KernelFallback event + counter). Set ``USE_PALLAS`` to force
 either path past the dimension policy (tests, benchmarks).
 """
 

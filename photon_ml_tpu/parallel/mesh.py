@@ -47,14 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 logger = logging.getLogger("photon_ml_tpu.parallel")
 
-# ``shard_map`` moved to the jax top level (jax >= 0.4.38); earlier
-# releases only ship it under jax.experimental. Resolve once here so every
-# call site (parallel/objective.py, parallel/sparse_objective.py, tests)
-# stays version-agnostic.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
@@ -135,6 +128,15 @@ def make_mesh(
         devices = devices[: num_data * num_model]
     arr = np.asarray(devices).reshape(num_data, num_model)
     return Mesh(arr, (DATA_AXIS, MODEL_AXIS))
+
+
+def device_summary() -> dict:
+    """What this process computes on, as JAX reports it. Every driver logs
+    it once at start, so a run that landed on the CPU cannot pass for a
+    run on the chip."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -789,7 +789,7 @@ class ServingFleet:
                       probe_objs: Optional[list] = None,
                       probe_max_abs: Optional[float] = None) -> dict:
         """The publication ladder: canary-apply → bake/judge → roll
-        fleet-wide or auto-roll-back. Raises the defined taxonomy —
+        fleet-wide or auto-roll-back. Raises the defined error classes —
         ``DeltaCorrupt``/``BadDelta`` (nothing applied anywhere),
         ``CanaryRejected`` (canary rolled back, no other replica ever
         saw the delta), ``PublishError`` (a fleet-wide swap leg failed;
@@ -918,7 +918,7 @@ class ServingFleet:
         """Route one /score body through the fleet; returns the merged
         response payload. Raises the router's defined errors — the HTTP
         front end maps them to status codes; programmatic callers get
-        the same exception taxonomy."""
+        the same exception classes."""
         counts: dict[int, int] = {}
         shards: list[Optional[int]] = []
         for obj in request_objs:

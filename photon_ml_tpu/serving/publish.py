@@ -35,7 +35,7 @@ Versions are MONOTONE: ``write`` always commits ``latest + 1`` and
 stamps the parent, so a reader can tell a gap (missing/torn version)
 from a clean chain and the fleet can refuse to apply out of order.
 
-Failure taxonomy (docs/ROBUSTNESS.md publication ladder):
+Failure classes (docs/ROBUSTNESS.md publication ladder):
 
 * :class:`DeltaCorrupt` — the artifact's bytes cannot be trusted
   (CRC mismatch, unparseable marker, missing payload);
@@ -327,7 +327,7 @@ def fetch_delta(url: str, dest_root: str, timeout_s: float = 30.0) -> str:
     fetch (connection cut, ``fabric.delta_fetch`` injection) leaves a
     marker-less local directory that :func:`read_delta` refuses, and
     the previously applied version stays servable. Every transfer
-    failure lands in the same :class:`DeltaCorrupt` taxonomy as a torn
+    failure lands in the same :class:`DeltaCorrupt` class as a torn
     shared-filesystem write; CRC verification happens in
     :func:`read_delta` exactly as for a local artifact.
     """

@@ -179,10 +179,10 @@ class ScoringService:
         # (docs/KERNELS.md): the backend choice is baked into the jitted
         # program, so steady state never re-decides — a flag flip needs
         # a service rebuild, same contract as every other config knob.
-        # Flag off = no registry traffic at all; flag on but no Pallas
-        # (no TPU, injected kernel.launch fault) already emitted its
-        # loud KernelFallback inside resolve, and the inline XLA chain
-        # below runs exactly as before.
+        # Flag off = no registry traffic at all; flag on under an
+        # injected kernel.launch fault already emitted its loud
+        # KernelFallback inside resolve, and the inline XLA chain below
+        # runs exactly as before (flag on with no backend raises).
         from photon_ml_tpu.ops import kernels
         reg = kernels.registry()
         fused = None

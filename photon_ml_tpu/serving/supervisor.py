@@ -197,9 +197,10 @@ class ReplicaSupervisor:
     def start(self) -> None:
         """Spawn every replica and wait until all answer /healthz."""
         os.makedirs(self.workdir, exist_ok=True)
-        for handle in self.replicas:
-            self._spawn(handle)
+        self.transport.check_capacity(len(self.replicas))
         try:
+            for handle in self.replicas:
+                self._spawn(handle)
             for handle in self.replicas:
                 self._await_ready(handle)
         except ReplicaStartupError:

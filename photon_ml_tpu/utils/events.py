@@ -168,9 +168,8 @@ class IngestFinish(Event):
 
 @dataclasses.dataclass(frozen=True)
 class IngestFallback(Event):
-    """Avro ingestion degraded to the pure-Python codec (~20x slower
-    than the native block decoder per BENCH_r05) instead of the
-    parallel native path; ``reason`` says why (no toolchain,
+    """Avro ingestion degraded to the pure-Python codec (far slower
+    than the native block decoder) instead of the parallel native path; ``reason`` says why (no toolchain,
     unsupported schema, ...)."""
 
     reason: str
@@ -182,8 +181,8 @@ class KernelFallback(Event):
     fallback closure instead of the Pallas program the flag asked for —
     the kernel-registry analog of IngestFallback's loud-degradation
     discipline. ``kernel`` is the registry name, ``backend`` the backend
-    the resolve actually landed on ("xla"), ``reason`` why (no TPU,
-    injected kernel.launch fault, ...). The obs bridge turns this into
+    the resolve actually landed on ("xla"), ``reason`` why (an
+    injected kernel.launch fault). The obs bridge turns this into
     ``photon_kernel_fallbacks_total{kernel=...}`` + a timeline instant;
     a silent fallback would let a flagged perf win quietly evaporate."""
 

@@ -480,6 +480,37 @@ def _kill_machine(proc):
         pass
 
 
+def test_local_transport_gives_replica_i_chip_i_or_refuses(
+        tmp_path, monkeypatch):
+    """On a TPU host a chip belongs to one process: replica i is pinned
+    to chip i through the child environment, and a replica with no chip
+    left is refused at spawn with the counts in the message (no child
+    is started to fail on its own minutes later)."""
+    from photon_ml_tpu.fabric import transport as tr
+    from photon_ml_tpu.serving.supervisor import ReplicaHandle
+
+    # This suite runs with JAX_PLATFORMS=cpu: no placement, no limit.
+    assert tr.local_tpu_chips() == []
+    monkeypatch.setattr(tr, "local_tpu_chips",
+                        lambda: ["/dev/vfio/0", "/dev/vfio/1"])
+    t = tr.LocalTransport(lambda rid, rf: [
+        sys.executable, "-c",
+        "import os; print(os.environ['TPU_VISIBLE_CHIPS'], "
+        "os.environ['TPU_PROCESS_BOUNDS'])"], str(tmp_path))
+    handle = ReplicaHandle(replica_id=1, generation=1)
+    t.spawn(handle)
+    assert handle.proc.wait(timeout=30) == 0
+    with open(handle.log_path) as f:
+        assert f.read().split() == ["1", "1,1,1"]
+    with pytest.raises(tr.ReplicaStartupError,
+                       match=r"3 replica\(s\) cannot be placed.*2 TPU chip"):
+        t.spawn(ReplicaHandle(replica_id=2, generation=1))
+    # The supervisor asks before it spawns anything.
+    t.check_capacity(2)
+    with pytest.raises(tr.ReplicaStartupError, match="at most 2"):
+        t.check_capacity(5)
+
+
 def test_remote_transport_adopts_running_replica(tmp_path):
     """First contact with a replica already up under an agent ADOPTS it
     (same pid, no respawn) — restarting a serving replica just to learn
@@ -719,7 +750,7 @@ def test_delayed_heartbeat_is_unknown_not_death(remote_fleet):
 
 def test_publish_delta_over_the_wire(remote_fleet):
     """The canary ladder with replicas PULLING the delta by URL: same
-    taxonomy, same committed chain, and served bits flip to the delta'd
+    error classes, same committed chain, and served bits flip to the delta'd
     model on both replicas."""
     from photon_ml_tpu.serving.publish import DeltaStore
 

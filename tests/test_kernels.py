@@ -3,9 +3,10 @@
 The contract under test: every fused Pallas program lives behind the
 registry seam — a per-kernel flag, an XLA reference closure with the same
 signature, an interpret-mode CPU path, backend-tagged compile counters,
-and a loud degradation ladder (injected ``kernel.launch`` faults and
-flag-on-without-a-backend both land on the XLA closure with a
-:class:`~photon_ml_tpu.utils.events.KernelFallback`). Flag flips change
+and a loud degradation ladder (an injected ``kernel.launch`` fault lands
+on the XLA closure with a
+:class:`~photon_ml_tpu.utils.events.KernelFallback`; a flag switched on
+where no backend can run the program raises). Flag flips change
 WHERE the math runs, never what it computes: the parity fixtures here pin
 fused == reference down to bit-exactness where the algebra is exact
 (int8 folding, power-of-two scales, row gather/scatter).
@@ -63,11 +64,13 @@ def _fallbacks(seen):
 
 def test_registry_catalog(clean_registry):
     assert clean_registry.names() == ALL_KERNELS
-    # The only committed default flip is the moderate-d ELL scatter
-    # (BENCH_r05's 4.6x win); every other kernel waits for its sweep.
+    # The only committed default flip is the moderate-d ELL scatter;
+    # every other kernel waits for its sweep. The default holds on the
+    # TPU backend only — on this CPU box it resolves off, silently.
     for name in ALL_KERNELS:
         assert clean_registry.get(name).default_on == (
             name == "ell_scatter")
+        assert not clean_registry.enabled(name)
 
 
 def test_flag_resolution_order(clean_registry, monkeypatch):
@@ -94,13 +97,11 @@ def test_flag_off_resolves_xla_silently(clean_registry, fallback_events):
     assert _fallbacks(fallback_events) == []  # policy, not degradation
 
 
-def test_enabled_without_backend_falls_back_loud(clean_registry,
-                                                 fallback_events):
+def test_enabled_without_backend_raises(clean_registry, fallback_events):
     clean_registry.set_enabled("serving_score", True)
-    resolved = clean_registry.resolve("serving_score")
-    assert resolved.backend == "xla"
-    (fb,) = _fallbacks(fallback_events)
-    assert fb.kernel == "serving_score" and "no TPU" in fb.reason
+    with pytest.raises(RuntimeError, match="no TPU backend"):
+        clean_registry.resolve("serving_score")
+    assert _fallbacks(fallback_events) == []  # refused, not degraded
 
 
 def test_force_interpret_resolves_pallas(clean_registry, fallback_events):

@@ -166,17 +166,27 @@ def test_staged_shards_bit_identical_to_serial(workers):
     _assert_bytes_equal(merged, want)
 
 
-def test_process_mode_bit_identical_to_thread():
+def test_process_mode_bit_identical_to_thread(monkeypatch):
     """The process-pool fallback ships work by pickle yet produces the
-    same bytes (content never depends on the pool)."""
+    same bytes (content never depends on the pool) — and its workers
+    never initialise a JAX backend: on the chip a worker that did would
+    ask for a device its parent already holds. The workers inherit a
+    platform name no backend answers to, so one that tried would fail
+    its task and show up as a retry."""
     ds = _skewed_dataset(n_entities=16, seed=3)
     _, t_stager = _stager(ds, stg.StagingConfig(workers=2,
                                                 shard_entities=8))
     t_shards = _drain(t_stager)
+    monkeypatch.setenv("JAX_PLATFORMS", "no-such-platform")
+    emitter = ev.EventEmitter()
+    seen = []
+    emitter.register(seen.append)
     _, p_stager = _stager(ds, stg.StagingConfig(workers=2, mode="process",
-                                                shard_entities=8))
+                                                shard_entities=8),
+                          emitter=emitter)
     p_shards = _drain(p_stager)
     _assert_bytes_equal(t_shards, p_shards)
+    assert not [e for e in seen if isinstance(e, ev.StagingRetry)]
 
 
 def test_dense_shard_with_normalization_parity():
