@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from photon_ml_tpu import obs
 from photon_ml_tpu.api.configs import (CoordinateConfiguration,
                                        FactoredRandomEffectDataConfiguration,
                                        FixedEffectDataConfiguration,
@@ -297,8 +298,6 @@ class GameEstimator:
         the convergence watchdogs for the duration
         (docs/OBSERVABILITY.md "The run ledger").
         """
-        from photon_ml_tpu import obs
-
         with contextlib.ExitStack() as stack:
             if self.watchdog is not None:
                 prev_wd = obs.set_watchdog(self.watchdog)
@@ -325,6 +324,10 @@ class GameEstimator:
                         return False
 
                     stack.push(_close)
+            if obs.ledger() is not None:
+                # This fit's or the driver's: its ``program.load`` rows
+                # come from one process-wide listener.
+                obs.record_program_loads()
             if self.trace is None:
                 return self._fit(data, validation_data, initial_models,
                                  locked_coordinates, checkpoint_dir)
@@ -478,7 +481,9 @@ class GameEstimator:
                         .with_optimization_config(opt_configs[cid])
                         for cid in cids}
                 else:
-                    base_coords = self._build_coordinates(data, opt_configs)
+                    with obs.phase("fit.coordinates"):
+                        base_coords = self._build_coordinates(data,
+                                                              opt_configs)
                 self._coord_cache["last"] = (cache_key, base_coords)
                 coords = base_coords
             else:
@@ -491,7 +496,6 @@ class GameEstimator:
             manager = (CheckpointManager(
                 os.path.join(checkpoint_dir, f"grid-{grid_index}"))
                 if checkpoint_dir else None)
-            from photon_ml_tpu import obs
             led = obs.ledger()
             bound = (led.bound(grid=grid_index) if led is not None
                      else contextlib.nullcontext())

@@ -628,6 +628,22 @@ def tail_ledger(directory: str) -> dict:
     trials = [r for r in rows if r.get("kind") == "tuning_trial"]
     if trials:
         out["tuning_trials"] = len(trials)
+    waves = [r for r in rows if r.get("kind") == "re_fit_wave"]
+    if waves:
+        # The newest random-effect update's waves, with what each counted
+        # inside its program (absent on ledgers older than the counters).
+        key = (waves[-1].get("coordinate"), waves[-1].get("outer_iteration"))
+        out["fit_waves"] = {
+            "coordinate": key[0], "outer_iteration": key[1],
+            "waves": [
+                {"wave": w.get("wave"), "cap": w.get("cap"),
+                 "entities_fit": w.get("entities_fit"),
+                 "iters_max": w.get("iters_max"),
+                 "iters_mean": (round(w["iters_sum"] / w["entities_fit"], 2)
+                                if w.get("iters_sum") is not None
+                                and w.get("entities_fit") else None)}
+                for w in waves
+                if (w.get("coordinate"), w.get("outer_iteration")) == key]}
     if not iters:
         return out
     last = iters[-1]
@@ -695,6 +711,18 @@ def render_tail(tail: dict) -> str:
         if cur.get("transfer_fraction_of_wall") is not None:
             out.append(f"  transfer "
                        f"{cur['transfer_fraction_of_wall']:.1%} of wall")
+    fw = tail.get("fit_waves")
+    if fw:
+        out.append(f"  fit waves of {fw['coordinate'] or '(run)'}, outer "
+                   f"{fw['outer_iteration']}: wave cap lanes "
+                   f"iters_max iters_mean")
+
+        def cell(v):
+            return "-" if v is None else str(v)
+
+        out += [f"    {cell(w['wave']):>3} {cell(w['cap']):>6} "
+                f"{cell(w['entities_fit']):>7} {cell(w['iters_max']):>4} "
+                f"{cell(w['iters_mean']):>6}" for w in fw["waves"]]
     for a in tail.get("watchdog_alerts", []):
         out.append(f"  WATCHDOG[{a['kind']}/{a['action']}]: {a['detail']}")
     pub = tail.get("publish")

@@ -22,7 +22,9 @@ from photon_ml_tpu.game.models import FixedEffectModel
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.obs.ledger import spill_history
+from photon_ml_tpu.ops.aggregators import scores as agg_scores
 from photon_ml_tpu.ops.losses import PointwiseLoss
+from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          variances_from_diagonal,
@@ -76,11 +78,14 @@ class FixedEffectCoordinate:
         # reuses the staged features — no second device copy of X.
         # feature_dtype="bfloat16" stores X at half width (see
         # ops/aggregators._matvec for the f32-accumulation contract).
-        self._staged = shard_batch(
-            LabeledBatch.build(dataset.feature_shards[shard_id],
-                               dataset.response, dataset.weights,
-                               feature_dtype=feature_dtype),
-            mesh)
+        with obs.phase("fe.transfer") as ph:
+            self._staged = shard_batch(
+                LabeledBatch.build(dataset.feature_shards[shard_id],
+                                   dataset.response, dataset.weights,
+                                   feature_dtype=feature_dtype),
+                mesh)
+            ph["bytes"] = sum(int(a.nbytes) for a in
+                              jax.tree.leaves(self._staged))
         self._build_fits()
 
     def _padded_offsets(self, offsets: Array) -> Array:
@@ -98,17 +103,23 @@ class FixedEffectCoordinate:
         loss, mesh, norm = self.loss, self.mesh, self.norm
         ii = self.intercept_index
 
-        def fit(staged: LabeledBatch, offsets: Array, w0: Array):
-            batch = dataclasses.replace(staged,
-                                        offsets=self._padded_offsets(offsets))
+        def solve(batch: LabeledBatch, w0: Array):
             coef, res = dist_problem.run(
                 loss, batch, mesh, cfg, initial=Coefficients(w0), norm=norm,
                 intercept_index=ii, already_sharded=True)
-            # Histories ride along for the run ledger's post-fit spill
-            # (tiny (max_it+1,) vectors; they stay on device — and cost
-            # nothing — unless a ledger is active).
-            return coef.means, res.value_history, res.grad_norm_history
+            # Histories and the evaluation count ride along for the run
+            # ledger's post-fit spill (tiny (max_it+1,) vectors and one
+            # integer; they stay on device — and cost nothing — unless a
+            # ledger is active).
+            return (coef.means, res.value_history, res.grad_norm_history,
+                    res.evaluations)
 
+        @scoped("fe.fit")
+        def fit(staged: LabeledBatch, offsets: Array, w0: Array):
+            return solve(dataclasses.replace(
+                staged, offsets=self._padded_offsets(offsets)), w0)
+
+        @scoped("fe.fit")
         def fit_sampled(staged: LabeledBatch, idx: Array, mult: Array,
                         offsets: Array, w0: Array):
             # Down-sampled pass: gather the subsample on device, rescale
@@ -120,13 +131,17 @@ class FixedEffectCoordinate:
                 weights=staged.weights[idx] * mult,
                 offsets=offsets[idx],
             ).pad_to(pad_to_multiple(idx.shape[0], mesh.shape[DATA_AXIS]))
-            coef, res = dist_problem.run(
-                loss, sub, mesh, cfg, initial=Coefficients(w0), norm=norm,
-                intercept_index=ii, already_sharded=True)
-            return coef.means, res.value_history, res.grad_norm_history
+            return solve(sub, w0)
+
+        n = self.dataset.num_rows
+
+        @scoped("fe.score")
+        def score(features: Array, means: Array):
+            return agg_scores(features, means)[:n]
 
         self._fit = jax.jit(fit)
         self._fit_sampled = jax.jit(fit_sampled)
+        self._score = jax.jit(score)
 
     @property
     def dim(self) -> int:
@@ -168,20 +183,24 @@ class FixedEffectCoordinate:
             # draw is host-side (cheap, label metadata only); the data
             # gather happens on device.
             idx, mult = draw_down_sample(self, rate)
-            w_t, vals, gns = self._fit_sampled(self._staged,
-                                               jnp.asarray(idx),
-                                               jnp.asarray(mult),
-                                               offsets, w0)
+            with obs.annotated("fe.fit", cat="train"):
+                w_t, vals, gns, evals = self._fit_sampled(
+                    self._staged, jnp.asarray(idx), jnp.asarray(mult),
+                    offsets, w0)
         else:
-            w_t, vals, gns = self._fit(self._staged, offsets, w0)
+            with obs.annotated("fe.fit", cat="train"):
+                w_t, vals, gns, evals = self._fit(self._staged, offsets, w0)
         led = obs.ledger()
         if led is not None:
             # Post-fit spill of the compiled optimizer's NaN-padded
             # histories — the run ledger's view of a solve that lives
             # inside one XLA program (one host read, once per update).
+            # The update's evaluation count rides on the last row.
+            vals, gns, evals = jax.device_get((vals, gns, evals))
             spill_history(
-                led, np.asarray(vals), np.asarray(gns),
-                opt=self.config.optimizer.optimizer_type.value.lower())
+                led, vals, gns,
+                opt=self.config.optimizer.optimizer_type.value.lower(),
+                evaluations=int(evals))
         raw = Coefficients(self.norm.model_to_original_space(w_t))
         return FixedEffectModel(shard_id=self.shard_id, coefficients=raw)
 
@@ -216,11 +235,9 @@ class FixedEffectCoordinate:
 
     def score(self, model: FixedEffectModel) -> Array:
         """Raw-space score (identical to the training margins by algebra)."""
-        from photon_ml_tpu.ops.aggregators import scores as agg_scores
-
-        n = self.dataset.num_rows
-        return agg_scores(self._staged.features,
-                          model.coefficients.means)[:n]
+        with obs.annotated("fe.score", cat="train"):
+            return self._score(self._staged.features,
+                               model.coefficients.means)
 
     def initial_model(self) -> FixedEffectModel:
         return FixedEffectModel(

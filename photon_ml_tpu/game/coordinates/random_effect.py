@@ -8,6 +8,7 @@ the residency discipline shared by all coordinate types.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -28,6 +29,7 @@ from photon_ml_tpu.game.models import (RandomEffectModel,
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim import optimize
+from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          compute_variances, make_objective,
@@ -47,6 +49,31 @@ _UNSET = object()
 # staging pipeline so staged shards == device dispatch chunks (one
 # host→device put per produced shard, no re-slicing).
 _LANE_CHUNK = stg.LANE_CHUNK
+
+# What a fit wave counts inside its program, reduced over live lanes: the
+# solver fields of its ``re_fit_wave`` ledger row (docs/OBSERVABILITY.md).
+_WAVE_STATS = ("iters_sum", "iters_max", "evals_sum", "lanes_at_cap")
+
+
+def _wave_stats(rows, iterations, evaluations, max_iterations: int):
+    """(4,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
+    entity (``rows >= 0``; padding lanes solve a benign problem of their
+    own). Stays on the device until the update's ledger drain."""
+    live = rows >= 0
+    its = jnp.where(live, iterations, 0)
+    return jnp.stack([its.sum(), its.max(),
+                      jnp.where(live, evaluations, 0).sum(),
+                      (its >= max_iterations).sum()]).astype(jnp.int32)
+
+
+def _wave_rows(pending):
+    """The deferred spill of one update's fit waves (``RunLedger.defer``):
+    one device read for all of them, made in the ledger's drain."""
+    stats = iter(jax.device_get([st for _, st in pending if st is not None]))
+    for fields, st in pending:
+        if st is not None:
+            fields.update(zip(_WAVE_STATS, map(int, next(stats))))
+        yield "re_fit_wave", fields
 
 
 @jax.jit
@@ -78,7 +105,25 @@ def _gram_block(Xb, wb):
     return jnp.einsum("eck,ec,ecm->ekm", Xf, wb, Xf)
 
 
+# The dense table's score, x_i · W[e_i], as the two programs it always was
+# (a row gather, a rowwise dot), now jitted so that they carry their scope.
+# NOT one program: at 10M x 8 rows the fused gather-and-dot reads 36.3 ms
+# on the v5e against 22.4 ms for the pair, bit for bit the same scores
+# (PERF.md §6, PR 26).
 @jax.jit
+@scoped("re.score")
+def _entity_rows(W, ids):
+    return W[ids]
+
+
+@jax.jit
+@scoped("re.score")
+def _rowwise_dot(X, R):
+    return jnp.einsum("nd,nd->n", X, R)
+
+
+@jax.jit
+@scoped("re.score")
 def _subspace_sparse_scores(W_flat, flatpos, values):
     """Σ_k values[i,k] · W_flat[flatpos[i,k]] with misses (flatpos ≥ |W|)
     contributing zero — one 1-D gather per ELL slot.
@@ -166,20 +211,28 @@ class RandomEffectCoordinate:
         self.norm = norm
         self.num_entities = dataset.num_entities[re_type]
         self.intercept_index = dataset.intercept_index.get(shard_id)
-        self.bucketing = bkt.build_bucketing(
-            dataset.entity_ids[re_type], self.num_entities,
-            lower_bound=lower_bound, upper_bound=upper_bound,
-            entity_pad_multiple=max(8, int(np.prod(list(mesh.shape.values())))),
-            rng=np.random.default_rng(seed),
-            counts_all=dataset.entity_counts.get(re_type))
-        if self.is_sparse:
-            shard = dataset.feature_shards[shard_id]
-            self._sp_indices = jnp.asarray(shard.indices)
-            self._sp_values = jnp.asarray(shard.values)
-            self._X = None
-        else:
-            self._X = jnp.asarray(dataset.feature_shards[shard_id])
-        self._ids = jnp.asarray(dataset.entity_ids[re_type])
+        with obs.phase("re.bucketing", re_type=re_type):
+            self.bucketing = bkt.build_bucketing(
+                dataset.entity_ids[re_type], self.num_entities,
+                lower_bound=lower_bound, upper_bound=upper_bound,
+                entity_pad_multiple=max(
+                    8, int(np.prod(list(mesh.shape.values())))),
+                rng=np.random.default_rng(seed),
+                counts_all=dataset.entity_counts.get(re_type))
+        with obs.phase("re.transfer", re_type=re_type) as ph:
+            # The score-side copies: every row's features and entity id.
+            if self.is_sparse:
+                shard = dataset.feature_shards[shard_id]
+                self._sp_indices = jnp.asarray(shard.indices)
+                self._sp_values = jnp.asarray(shard.values)
+                self._X = None
+                ph["bytes"] = int(self._sp_indices.nbytes
+                                  + self._sp_values.nbytes)
+            else:
+                self._X = jnp.asarray(dataset.feature_shards[shard_id])
+                ph["bytes"] = int(self._X.nbytes)
+            self._ids = jnp.asarray(dataset.entity_ids[re_type])
+            ph["bytes"] += int(self._ids.nbytes)
         # Pearson feature filtering selects per-entity columns, which is
         # exactly what the projection machinery stages — a ratio implies
         # projection (reference: filterFeaturesByPearsonCorrelationScore
@@ -215,6 +268,9 @@ class RandomEffectCoordinate:
         # fit-stream order: the gated sweep path (train_model_gated)
         # selects dirty lanes on host to build compacted active waves.
         self._host_rows: list[np.ndarray] = []
+        # Beside them, each lane's true row count (the rest of its
+        # ``cap`` slots is padding): the wave rows' ``rows_useful``.
+        self._host_counts: list[np.ndarray] = []
         self._gram_cache: dict[int, Array] = {}
         # Lazy gating-support caches (see _bucket_census): per-entity row
         # counts, the trained-entity mask, and whether segment rescoring
@@ -322,12 +378,13 @@ class RandomEffectCoordinate:
             # Unprojected path: dense gathers, cheap relative to the
             # projection wall — staged eagerly as before.
             host_buckets: list[tuple] = []
-            for b in self.bucketing.buckets:
-                wb = bkt.bucket_weights(b, ds.weights)
-                ex = b.example_idx.astype(np.int32)  # (E_b, cap); -1 pad
-                rows = b.entity_rows  # (E_b,) int32; -1 padding
-                Xb, yb = bkt.gather_bucket_arrays(b, X, ds.response)
-                host_buckets.append((Xb, yb, wb, ex, rows))
+            with obs.phase("re.host_stage", re_type=re_type):
+                for b in self.bucketing.buckets:
+                    wb = bkt.bucket_weights(b, ds.weights)
+                    ex = b.example_idx.astype(np.int32)  # (E_b, cap); -1 pad
+                    rows = b.entity_rows  # (E_b,) int32; -1 padding
+                    Xb, yb = bkt.gather_bucket_arrays(b, X, ds.response)
+                    host_buckets.append((Xb, yb, wb, ex, rows))
             sub = {}
             for arrays in host_buckets:
                 self._stage_host_tuple(arrays)
@@ -394,17 +451,22 @@ class RandomEffectCoordinate:
         pad = self.bucketing.entity_pad_multiple
         chunk = ((_LANE_CHUNK + pad - 1) // pad) * pad
         E_b = arrays[4].shape[0]
-        for lo in range(0, E_b, chunk):
-            hi = min(lo + chunk, E_b)
-            tup = []
-            for ai, a in enumerate(arrays):
-                a = np.asarray(a)[lo:hi]
-                if ai == 0 and feat_cast is not None:  # Xb block
-                    a = a.astype(feat_cast)
-                if ai == 4:  # entity rows: keep a host copy for gating
-                    self._host_rows.append(np.array(a, copy=True))
-                tup.append(self._put(a))
-            self._bucket_data.append(tuple(tup))
+        with obs.phase("re.transfer", re_type=self.re_type, bytes=0) as ph:
+            for lo in range(0, E_b, chunk):
+                hi = min(lo + chunk, E_b)
+                tup = []
+                for ai, a in enumerate(arrays):
+                    a = np.asarray(a)[lo:hi]
+                    if ai == 0 and feat_cast is not None:  # Xb block
+                        a = a.astype(feat_cast)
+                    if ai == 3:  # example map: rows each lane really has
+                        self._host_counts.append(
+                            (a >= 0).sum(axis=1).astype(np.int64))
+                    if ai == 4:  # entity rows: a host copy for gating
+                        self._host_rows.append(np.array(a, copy=True))
+                    tup.append(self._put(a))
+                    ph["bytes"] += int(a.nbytes)
+                self._bucket_data.append(tuple(tup))
 
     def _iter_bucket_data(self):
         """The fit stream: already-staged device tuples first, then — on
@@ -444,11 +506,14 @@ class RandomEffectCoordinate:
         """(Re)build the cached jitted per-bucket fit/variance programs.
 
         ``fit_bucket`` keeps the whole inner step on device: gather each
-        entity's offsets and warm start, run the vmapped masked-lane solve,
-        scatter trained rows back into the (E, d) table. Padding lanes
-        (rows == -1) are redirected to an out-of-bounds index and dropped by
-        the scatter. One executable per bucket SHAPE, cached by jit across
-        buckets and coordinate-descent iterations.
+        entity's offsets and warm start (scope ``re.gather``), run the
+        vmapped masked-lane solve (``re.solve``), scatter trained rows back
+        into the (E, d) table (``re.scatter``). Padding lanes (rows == -1)
+        are redirected to an out-of-bounds index and dropped by the
+        scatter. Beside the table it returns the wave's solver counts
+        (``_wave_stats``), which nobody reads unless a run ledger is open.
+        One executable per bucket SHAPE, cached by jit across buckets and
+        coordinate-descent iterations.
 
         Projected variant: warm starts are gathered through each entity's
         column map (original space, since transforms are per-entity), solved
@@ -467,12 +532,17 @@ class RandomEffectCoordinate:
         solve = jax.vmap(self._solve_one)
         var_one = jax.vmap(self._variance_one)
         _gather_rows, _scatter_rows = self._row_movers()
+        max_it = self.config.optimizer.max_iterations
 
         def fit_bucket(W, offsets, Xb, yb, wb, ex, rows):
-            ob = offsets[jnp.maximum(ex, 0)]
-            w0 = _gather_rows(W, rows)
-            w_fit = solve(Xb, yb, wb, ob, w0)
-            return _scatter_rows(W, rows, w_fit)
+            with jax.named_scope("re.gather"):
+                ob = offsets[jnp.maximum(ex, 0)]
+                w0 = _gather_rows(W, rows)
+            with jax.named_scope("re.solve"):
+                w_fit, its, evals = solve(Xb, yb, wb, ob, w0)
+                stats = _wave_stats(rows, its, evals, max_it)
+            with jax.named_scope("re.scatter"):
+                return _scatter_rows(W, rows, w_fit), stats
 
         def var_bucket(W, V, offsets, Xb, yb, wb, ex, rows):
             ob = offsets[jnp.maximum(ex, 0)]
@@ -541,9 +611,9 @@ class RandomEffectCoordinate:
             """One entity's projected solve; original space in and out."""
             ctx = ctx_for(f, s)
             w0 = ctx.model_to_transformed_space(w0_orig)
-            w_t = self._solve_one(X, y, w, o, w0, norm=ctx,
-                                  intercept_index=ii_proj)
-            return ctx.model_to_original_space(w_t)
+            w_t, its, evals = self._solve_one(X, y, w, o, w0, norm=ctx,
+                                              intercept_index=ii_proj)
+            return ctx.model_to_original_space(w_t), its, evals
 
         def var_one(X, y, w, o, w_orig, f, s):
             ctx = ctx_for(f, s)
@@ -574,6 +644,7 @@ class RandomEffectCoordinate:
             return ob, w0, safe_rows, safe_cols
 
         subspace = self.subspace
+        max_it = self.config.optimizer.max_iterations
 
         def sub_gathers(W, offsets, ex, rows, da):
             """Subspace-table layout: the entity's model row IS its bucket
@@ -584,22 +655,34 @@ class RandomEffectCoordinate:
             safe_rows = jnp.where(rows >= 0, rows, num_entities)
             return ob, w0, safe_rows
 
+        def solve(rows, *args):
+            with jax.named_scope("re.solve"):
+                w_fit, its, evals = vsolve(*args)
+                return w_fit, _wave_stats(rows, its, evals, max_it)
+
         def fit_bucket(W, offsets, Xb, yb, wb, ex, rows, *extra):
             cols, f, s = unpack(extra)
             if subspace:
                 da = cols.shape[1]
-                ob, w0, safe_rows = sub_gathers(W, offsets, ex, rows, da)
-                w_fit = vsolve(Xb, yb, wb, ob, w0, f, s)
-                # Whole-row set: the padding tail past d_active stays zero.
-                w_pad = jnp.pad(w_fit, ((0, 0), (0, W.shape[1] - da)))
-                return W.at[safe_rows].set(w_pad, mode="drop")
-            ob, w0, safe_rows, safe_cols = gathers(W, offsets, ex, rows, cols)
-            w_fit = vsolve(Xb, yb, wb, ob, w0, f, s)
-            # projectBackward semantics: a trained entity's FULL row is
-            # rewritten — zero it first so inactive-column mass from an
-            # external (e.g. unprojected) warm start cannot survive.
-            W = W.at[safe_rows].set(0.0, mode="drop")
-            return W.at[safe_rows[:, None], safe_cols].set(w_fit, mode="drop")
+                with jax.named_scope("re.gather"):
+                    ob, w0, safe_rows = sub_gathers(W, offsets, ex, rows, da)
+                w_fit, stats = solve(rows, Xb, yb, wb, ob, w0, f, s)
+                with jax.named_scope("re.scatter"):
+                    # Whole-row set: the padding tail past d_active stays
+                    # zero.
+                    w_pad = jnp.pad(w_fit, ((0, 0), (0, W.shape[1] - da)))
+                    return W.at[safe_rows].set(w_pad, mode="drop"), stats
+            with jax.named_scope("re.gather"):
+                ob, w0, safe_rows, safe_cols = gathers(W, offsets, ex, rows,
+                                                       cols)
+            w_fit, stats = solve(rows, Xb, yb, wb, ob, w0, f, s)
+            with jax.named_scope("re.scatter"):
+                # projectBackward semantics: a trained entity's FULL row is
+                # rewritten — zero it first so inactive-column mass from an
+                # external (e.g. unprojected) warm start cannot survive.
+                W = W.at[safe_rows].set(0.0, mode="drop")
+                return W.at[safe_rows[:, None], safe_cols].set(
+                    w_fit, mode="drop"), stats
 
         def var_bucket(W, V, offsets, Xb, yb, wb, ex, rows, *extra):
             cols, f, s = unpack(extra)
@@ -618,7 +701,9 @@ class RandomEffectCoordinate:
                 jax.jit(var_bucket, donate_argnums=(1,)))
 
     def _solve_one(self, X, y, w, o, w0, norm=None, intercept_index=_UNSET):
-        """One entity's GLM solve in transformed space (vmapped per bucket).
+        """One entity's GLM solve in transformed space (vmapped per bucket):
+        the fitted row, and the iterations and objective evaluations the
+        solver took for it.
 
         The projected path passes a per-entity NormalizationContext and the
         projected intercept slot; the unprojected path uses the coordinate's
@@ -634,7 +719,7 @@ class RandomEffectCoordinate:
         opt_cfg = resolve_optimizer_config(
             self.config.optimizer, l1w is not None)
         result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w)
-        return result.w
+        return result.w, result.iterations, result.evaluations
 
     def _variance_one(self, X, y, w, o, w_opt, norm=None,
                       intercept_index=_UNSET):
@@ -778,27 +863,50 @@ class RandomEffectCoordinate:
         offsets = jnp.asarray(offsets)
         led = obs.ledger()
         mx = obs.metrics()
+        pending = []  # this update's wave rows, for the ledger's drain
         for wave, arrays in enumerate(self._iter_bucket_data()):
             t_wave = time.perf_counter()
             # One span per vmapped entity-fit wave (the dispatch unit the
             # lane bound exists for). Dispatch is async: the span times
             # the submission + any blocking the runtime imposes, not the
-            # device execution — the device side belongs to jax.profiler.
-            with obs.span("re.fit_wave", cat="train", wave=wave,
-                          re_type=self.re_type):
-                W = self._fit_bucket(W, offsets, *arrays)
-            lanes = int((self._host_rows[wave] >= 0).sum())
+            # device execution. The device side is the wave's ledger row
+            # (iterations, evaluations, padded against useful rows) and
+            # the program's scopes (re.gather / re.solve / re.scatter) in
+            # a profiler trace.
+            with obs.annotated("re.fit_wave", cat="train", wave=wave,
+                               re_type=self.re_type):
+                W, stats = self._fit_bucket(W, offsets, *arrays)
+            live = self._host_rows[wave] >= 0
+            lanes = int(live.sum())
             if mx is not None:
                 mx.counter("photon_re_entities_refit_total",
                            re_type=self.re_type).inc(lanes)
             if led is not None:
-                # Wave-level aggregate (per-entity rows would be 1M-deep
-                # noise); seconds are dispatch-side, same caveat as the
-                # span above.
-                led.record("re_fit_wave", re_type=self.re_type, wave=wave,
-                           seconds=round(time.perf_counter() - t_wave, 6),
-                           entities_fit=lanes, entities_skipped=0)
+                pending.append((self._wave_fields(
+                    wave, time.perf_counter() - t_wave, arrays, live), stats))
+        if led is not None:
+            led.defer(functools.partial(_wave_rows, pending))
         return self._finish_model(W)
+
+    def _wave_fields(self, wave: int, seconds: float, arrays, fit,
+                     skipped: int = 0, drift_p99=None) -> dict:
+        """The host's half of one wave's ``re_fit_wave`` row (per-entity
+        rows would be 1M-deep noise): the dispatch's shape and lane counts.
+        ``arrays`` is the dispatched tuple (None: nothing dispatched),
+        ``fit`` masks the staged lanes that were fit. ``seconds`` times
+        the ENQUEUE, not the device. The solver's half is counted inside
+        the program and joins at the ledger's drain (``_wave_rows``)."""
+        cap = int(self._bucket_data[wave][3].shape[1])
+        lanes = 0 if arrays is None else int(arrays[4].shape[0])
+        fields = dict(
+            re_type=self.re_type, wave=wave, seconds=round(seconds, 6),
+            entities_fit=int(fit.sum()), entities_skipped=skipped,
+            cap=cap, lanes=lanes,
+            rows_useful=int(self._host_counts[wave][fit].sum()),
+            rows_padded=lanes * cap)
+        if drift_p99 is not None:
+            fields["drift_p99"] = round(drift_p99, 9)
+        return fields
 
     # -- dirty-gated sweeps (game/sweep.py; docs/SWEEPS.md) ------------------
 
@@ -874,6 +982,7 @@ class RandomEffectCoordinate:
         num_entities = self.num_entities
         n = int(self.dataset.num_rows)
         seg_ok = bool(self._segment_rescore_ok)
+        max_it = self.config.optimizer.max_iterations
 
         def seg_scatter(delta, Xb, ex, d_orig):
             if not seg_ok:
@@ -900,26 +1009,31 @@ class RandomEffectCoordinate:
                     cfg.optimizer, l1w is not None)
                 result = optimize(vg, w0, opt_cfg, hvp=hvp,
                                   l1_weights=l1w)
-                return result.w, result.grad_norm
+                return (result.w, result.grad_norm, result.iterations,
+                        result.evaluations)
 
             vsolve = jax.vmap(solve_gn)
             _gather_rows, _scatter_rows = self._row_movers()
 
             def fit_gated(W, delta, gnorms, offsets, Xb, yb, wb, ex,
                           rows):
-                ob = offsets[jnp.maximum(ex, 0)]
-                w0 = _gather_rows(W, rows)
-                w_fit, gn = vsolve(Xb, yb, wb, ob, w0)
-                W = _scatter_rows(W, rows, w_fit)
-                safe = jnp.where(rows >= 0, rows, num_entities)
-                gnorms = gnorms.at[safe].set(gn, mode="drop")
-                # Score delta in ORIGINAL space: the staged Xb are raw
-                # features, so x·Δw_orig is exactly the per-example
-                # score movement score() would report.
-                d_orig = (norm.model_to_original_space(w_fit)
-                          - norm.model_to_original_space(w0))
-                delta = seg_scatter(delta, Xb, ex, d_orig)
-                return W, delta, gnorms
+                with jax.named_scope("re.gather"):
+                    ob = offsets[jnp.maximum(ex, 0)]
+                    w0 = _gather_rows(W, rows)
+                with jax.named_scope("re.solve"):
+                    w_fit, gn, its, evals = vsolve(Xb, yb, wb, ob, w0)
+                    stats = _wave_stats(rows, its, evals, max_it)
+                with jax.named_scope("re.scatter"):
+                    W = _scatter_rows(W, rows, w_fit)
+                    safe = jnp.where(rows >= 0, rows, num_entities)
+                    gnorms = gnorms.at[safe].set(gn, mode="drop")
+                    # Score delta in ORIGINAL space: the staged Xb are raw
+                    # features, so x·Δw_orig is exactly the per-example
+                    # score movement score() would report.
+                    d_orig = (norm.model_to_original_space(w_fit)
+                              - norm.model_to_original_space(w0))
+                    delta = seg_scatter(delta, Xb, ex, d_orig)
+                return W, delta, gnorms, stats
 
             self._fit_bucket_gated = jax.jit(fit_gated,
                                              donate_argnums=(0, 1, 2))
@@ -948,7 +1062,8 @@ class RandomEffectCoordinate:
             opt_cfg = resolve_optimizer_config(
                 self.config.optimizer, l1w is not None)
             result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w)
-            return ctx.model_to_original_space(result.w), result.grad_norm
+            return (ctx.model_to_original_space(result.w), result.grad_norm,
+                    result.iterations, result.evaluations)
 
         norm_axes = (0 if has_f else None, 0 if has_s else None)
         vsolve = jax.vmap(solve_one_gn,
@@ -964,30 +1079,35 @@ class RandomEffectCoordinate:
         def fit_gated(W, delta, gnorms, offsets, Xb, yb, wb, ex, rows,
                       *extra):
             cols, f, s = unpack(extra)
-            ob = offsets[jnp.maximum(ex, 0)]
-            safe_rows = jnp.where(rows >= 0, rows, num_entities)
-            if subspace:
-                da = cols.shape[1]
-                w0 = W[jnp.maximum(rows, 0)][:, :da]
-                w_fit, gn = vsolve(Xb, yb, wb, ob, w0, f, s)
-                w_pad = jnp.pad(w_fit, ((0, 0), (0, W.shape[1] - da)))
-                W = W.at[safe_rows].set(w_pad, mode="drop")
-            else:
-                valid = (cols >= 0).astype(W.dtype)
-                w0 = W[jnp.maximum(rows, 0)[:, None],
-                       jnp.maximum(cols, 0)] * valid
-                safe_cols = jnp.where(cols >= 0, cols, dim)
-                w_fit, gn = vsolve(Xb, yb, wb, ob, w0, f, s)
-                W = W.at[safe_rows].set(0.0, mode="drop")
-                W = W.at[safe_rows[:, None], safe_cols].set(
-                    w_fit, mode="drop")
-            gnorms = gnorms.at[safe_rows].set(gn, mode="drop")
-            # Active-column delta: exact vs the full-row difference
-            # because gated waves always follow >= 1 full sweep
-            # (min_sweeps_full), which leaves no inactive-column mass
-            # (projectBackward).
-            delta = seg_scatter(delta, Xb, ex, w_fit - w0)
-            return W, delta, gnorms
+            with jax.named_scope("re.gather"):
+                ob = offsets[jnp.maximum(ex, 0)]
+                safe_rows = jnp.where(rows >= 0, rows, num_entities)
+                if subspace:
+                    da = cols.shape[1]
+                    w0 = W[jnp.maximum(rows, 0)][:, :da]
+                else:
+                    valid = (cols >= 0).astype(W.dtype)
+                    w0 = W[jnp.maximum(rows, 0)[:, None],
+                           jnp.maximum(cols, 0)] * valid
+                    safe_cols = jnp.where(cols >= 0, cols, dim)
+            with jax.named_scope("re.solve"):
+                w_fit, gn, its, evals = vsolve(Xb, yb, wb, ob, w0, f, s)
+                stats = _wave_stats(rows, its, evals, max_it)
+            with jax.named_scope("re.scatter"):
+                if subspace:
+                    w_pad = jnp.pad(w_fit, ((0, 0), (0, W.shape[1] - da)))
+                    W = W.at[safe_rows].set(w_pad, mode="drop")
+                else:
+                    W = W.at[safe_rows].set(0.0, mode="drop")
+                    W = W.at[safe_rows[:, None], safe_cols].set(
+                        w_fit, mode="drop")
+                gnorms = gnorms.at[safe_rows].set(gn, mode="drop")
+                # Active-column delta: exact vs the full-row difference
+                # because gated waves always follow >= 1 full sweep
+                # (min_sweeps_full), which leaves no inactive-column mass
+                # (projectBackward).
+                delta = seg_scatter(delta, Xb, ex, w_fit - w0)
+            return W, delta, gnorms, stats
 
         self._fit_bucket_gated = jax.jit(fit_gated,
                                          donate_argnums=(0, 1, 2))
@@ -1020,14 +1140,19 @@ class RandomEffectCoordinate:
         vsolve = jax.vmap(gram_solve_one)
 
         def fit_gram(W, delta, gnorms, offsets, G, Xb, yb, wb, ex, rows):
-            ob = offsets[jnp.maximum(ex, 0)]
-            w0 = _gather_rows(W, rows)
-            w_fit, gn = vsolve(G, Xb, yb, wb, ob, w0)
-            W = _scatter_rows(W, rows, w_fit)
-            safe = jnp.where(rows >= 0, rows, num_entities)
-            gnorms = gnorms.at[safe].set(gn, mode="drop")
-            delta = seg_scatter(delta, Xb, ex, w_fit - w0)
-            return W, delta, gnorms
+            with jax.named_scope("re.gather"):
+                ob = offsets[jnp.maximum(ex, 0)]
+                w0 = _gather_rows(W, rows)
+            with jax.named_scope("re.solve"):
+                w_fit, gn = vsolve(G, Xb, yb, wb, ob, w0)
+            with jax.named_scope("re.scatter"):
+                W = _scatter_rows(W, rows, w_fit)
+                safe = jnp.where(rows >= 0, rows, num_entities)
+                gnorms = gnorms.at[safe].set(gn, mode="drop")
+                delta = seg_scatter(delta, Xb, ex, w_fit - w0)
+            # A closed form: no iterations to count, so no solver fields
+            # on this wave's ledger row.
+            return W, delta, gnorms, None
 
         return jax.jit(fit_gram, donate_argnums=(0, 1, 2))
 
@@ -1068,6 +1193,7 @@ class RandomEffectCoordinate:
         pad = self.bucketing.entity_pad_multiple
         led = obs.ledger()
         mx = obs.metrics()
+        pending = []  # this update's wave rows, for the ledger's drain
         total_fit = total_skip = 0
         for wave, arrays in enumerate(self._iter_bucket_data()):
             rows_host = self._host_rows[wave]
@@ -1077,6 +1203,7 @@ class RandomEffectCoordinate:
             if dirty_host is None:
                 fit_lanes, skip_lanes = live_n, 0
                 args = arrays
+                lane_dirty = live
             else:
                 lane_dirty = live & dirty_host[np.maximum(rows_host, 0)]
                 fit_lanes = int(lane_dirty.sum())
@@ -1093,14 +1220,12 @@ class RandomEffectCoordinate:
             if dirty_host is not None and fit_lanes == 0:
                 # Fully-converged wave: nothing dispatches at all.
                 if led is not None:
-                    led.record("re_fit_wave", re_type=self.re_type,
-                               wave=wave, seconds=0.0, entities_fit=0,
-                               entities_skipped=skip_lanes,
-                               drift_p99=round(p99, 9))
+                    pending.append((self._wave_fields(
+                        wave, 0.0, None, lane_dirty, skip_lanes, p99), None))
                 continue
             t_wave = time.perf_counter()
-            with obs.span("re.fit_wave", cat="train", wave=wave,
-                          re_type=self.re_type):
+            with obs.annotated("re.fit_wave", cat="train", wave=wave,
+                               re_type=self.re_type):
                 if dirty_host is not None:
                     idx = np.flatnonzero(lane_dirty)
                     L = swp.compact_lanes(idx.size, pad, rows_host.size)
@@ -1112,17 +1237,17 @@ class RandomEffectCoordinate:
                     G = self._gram_for_wave(wave, arrays)
                     if sel_dev is not None:
                         G = jnp.take(G, jnp.maximum(sel_dev, 0), axis=0)
-                    W, delta, gnorms = self._fit_bucket_gram(
+                    W, delta, gnorms, stats = self._fit_bucket_gram(
                         W, delta, gnorms, offsets, G, *args)
                 else:
-                    W, delta, gnorms = self._fit_bucket_gated(
+                    W, delta, gnorms, stats = self._fit_bucket_gated(
                         W, delta, gnorms, offsets, *args)
             if led is not None:
-                led.record("re_fit_wave", re_type=self.re_type, wave=wave,
-                           seconds=round(time.perf_counter() - t_wave, 6),
-                           entities_fit=fit_lanes,
-                           entities_skipped=skip_lanes,
-                           drift_p99=round(p99, 9))
+                pending.append((self._wave_fields(
+                    wave, time.perf_counter() - t_wave, args, lane_dirty,
+                    skip_lanes, p99), stats))
+        if led is not None:
+            led.defer(functools.partial(_wave_rows, pending))
         state.grad_norms = gnorms
         state.advance(offsets, None if dirty_host is None else dirty)
         stats = {"entities_fit": total_fit,
@@ -1162,6 +1287,10 @@ class RandomEffectCoordinate:
         return dataclasses.replace(model, variances=V)
 
     def score(self, model) -> Array:
+        with obs.annotated("re.score", cat="train"):
+            return self._score(model)
+
+    def _score(self, model) -> Array:
         if self.subspace:
             W_flat = jnp.asarray(model.means).reshape(-1)
             if self.is_sparse:
@@ -1183,7 +1312,8 @@ class RandomEffectCoordinate:
             idx = jnp.minimum(self._sp_indices, W.shape[1] - 1)
             return jnp.sum(
                 self._sp_values * W[self._ids[:, None], idx], axis=-1)
-        return jnp.einsum("nd,nd->n", self._X, model.means[self._ids])
+        return _rowwise_dot(
+            self._X, _entity_rows(jnp.asarray(model.means), self._ids))
 
     def initial_model(self):
         if self.subspace:

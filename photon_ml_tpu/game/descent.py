@@ -274,9 +274,9 @@ def run(
                 # One span per coordinate update — the descent
                 # waterfall's unit; the coordinate's own spans (streamed
                 # passes, fit waves, checkpoint writes) nest under it.
-                with bound, obs.span("descent.update", cat="train",
-                                     iteration=it, coordinate=cid,
-                                     step=step):
+                with bound, obs.annotated("descent.update", cat="train",
+                                          iteration=it, coordinate=cid,
+                                          step=step):
                     if checkpoint_manager is not None:
                         # Streamed coordinates checkpoint INSIDE the
                         # update too (their fit is the multi-hour unit at
@@ -328,6 +328,12 @@ def run(
                         scores[cid] = new_scores
                     models[cid] = model
                     _sync(total)
+                    if led is not None:
+                        # What the coordinate counted inside its programs
+                        # (fit-wave solver counters) is read here, once,
+                        # behind the barrier: never between dispatches.
+                        with obs.annotated("ledger.drain", cat="train"):
+                            led.drain()
                 elapsed = time.monotonic() - t0
                 rec = {"iteration": it, "coordinate": cid,
                        "train_seconds": elapsed}
@@ -397,17 +403,18 @@ def _dataset_digest(ds) -> str:
     def _feed(arr):
         _feed_array(h, arr)
 
-    for arr in (ds.response, ds.offsets, ds.weights):
-        _feed(arr)
-    for sid in sorted(ds.feature_shards):
-        shard = ds.feature_shards[sid]
-        if hasattr(shard, "indices"):  # SparseShard
-            _feed(shard.indices)
-            _feed(shard.values)
-        else:
-            _feed(shard)
-    for re_type in sorted(ds.entity_ids):
-        _feed(ds.entity_ids[re_type])
+    with obs.phase("fit.digest"):
+        for arr in (ds.response, ds.offsets, ds.weights):
+            _feed(arr)
+        for sid in sorted(ds.feature_shards):
+            shard = ds.feature_shards[sid]
+            if hasattr(shard, "indices"):  # SparseShard
+                _feed(shard.indices)
+                _feed(shard.values)
+            else:
+                _feed(shard)
+        for re_type in sorted(ds.entity_ids):
+            _feed(ds.entity_ids[re_type])
     digest = h.hexdigest()
     try:
         ds._content_digest = digest

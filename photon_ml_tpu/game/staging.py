@@ -77,6 +77,7 @@ from typing import Optional
 import numpy as np
 
 from photon_ml_tpu import faults as flt
+from photon_ml_tpu import obs
 from photon_ml_tpu.utils import workers as pools
 
 logger = logging.getLogger("photon_ml_tpu.game")
@@ -841,10 +842,18 @@ class ProjectionStager:
             self._done_count += 1
             last = self._done_count == self.num_shards
         if last:
+            wall = time.monotonic() - self._t0
             self._emitter.emit(ev_mod.StagingFinish(
                 label=self._label, num_shards=self.num_shards,
-                cached_shards=len(self._cached),
-                wall_seconds=time.monotonic() - self._t0))
+                cached_shards=len(self._cached), wall_seconds=wall))
+            led = obs.ledger()
+            if led is not None:
+                # The pipelined stager's host pass as one set-up phase
+                # (it overlaps the first fits, so it has no parent).
+                led.record("phase", name="re.host_stage", parent=None,
+                           seconds=round(wall, 6), label=self._label,
+                           shards=self.num_shards,
+                           cached_shards=len(self._cached))
             self._maybe_finalize()
 
     def _maybe_finalize(self):

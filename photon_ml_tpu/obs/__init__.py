@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from typing import Optional
 
 from photon_ml_tpu.obs.bridge import (EventSpanBridge, install_bridge,
@@ -34,6 +35,7 @@ from photon_ml_tpu.obs.ledger import RunLedger
 from photon_ml_tpu.obs.metrics import (Counter, Gauge, Histogram,
                                        MetricsRegistry, metric_value,
                                        parse_prometheus_text)
+from photon_ml_tpu.obs.programs import ProgramLoads
 from photon_ml_tpu.obs.trace import Span, Tracer, WorkerTracer
 from photon_ml_tpu.obs.watchdog import (ConvergenceWatchdog, WatchdogConfig,
                                         WatchdogError,
@@ -41,13 +43,15 @@ from photon_ml_tpu.obs.watchdog import (ConvergenceWatchdog, WatchdogConfig,
 
 __all__ = [
     "ConvergenceWatchdog", "Counter", "EventSpanBridge", "Gauge",
-    "Histogram", "MetricsRegistry", "RunLedger", "Span", "Tracer",
-    "WatchdogConfig", "WatchdogError", "WorkerTracer", "activated",
-    "adopt_worker_context", "disable", "dump_trace", "enable",
-    "install_bridge", "installed_bridge", "instant", "ledger",
-    "metric_value", "metrics", "parse_prometheus_text",
-    "parse_watchdog_config", "set_ledger", "set_watchdog", "span",
-    "tracer", "uninstall_bridge", "watchdog_config", "worker_context",
+    "Histogram", "MetricsRegistry", "ProgramLoads", "RunLedger",
+    "Span", "Tracer", "WatchdogConfig", "WatchdogError", "WorkerTracer",
+    "activated", "adopt_worker_context", "annotated", "current_phase",
+    "disable",
+    "dump_trace", "enable", "install_bridge", "installed_bridge",
+    "instant", "ledger", "metric_value", "metrics",
+    "parse_prometheus_text", "parse_watchdog_config", "phase",
+    "record_program_loads", "set_ledger", "set_watchdog", "span", "tracer",
+    "uninstall_bridge", "watchdog_config", "worker_context",
 ]
 
 _LOCK = threading.Lock()
@@ -165,6 +169,79 @@ def span(name: str, cat: str = "app", **args):
     if t is None:
         return _NULL_CM
     return t.span(name, cat=cat, **args)
+
+
+@contextlib.contextmanager
+def annotated(name: str, cat: str = "app", **args):
+    """:func:`span` plus a ``jax.profiler.TraceAnnotation`` of the same
+    name: the training path's span sites show on the host plane of any
+    device trace (``game_train --profile-dir``, a benchmark's), so an
+    idle gap on the device can be laid against what the host was doing.
+    Nearly free with no profiler session. JAX is imported here, lazily:
+    only call sites that already run JAX programs use this."""
+    import jax
+
+    with jax.profiler.TraceAnnotation(name), span(name, cat=cat, **args):
+        yield
+
+
+_PHASES = threading.local()  # the open phases of this thread, outermost first
+
+
+def current_phase() -> Optional[str]:
+    """The innermost :func:`phase` open on this thread, or None."""
+    stack = _PHASES.__dict__.get("stack")
+    return stack[-1] if stack else None
+
+
+@contextlib.contextmanager
+def phase(name: str, **fields):
+    """One set-up phase on the run ledger: a ``phase`` row (``name``,
+    ``seconds``, ``parent`` = the phase open around it on this thread)
+    written as the block ends. Yields the row's extra fields, so a site
+    can add what it learns inside (``bytes`` of a transfer). With no
+    ledger open: the one None check."""
+    led = _LEDGER
+    if led is None:
+        yield fields
+        return
+    parent = current_phase()
+    stack = _PHASES.__dict__.setdefault("stack", [])
+    stack.append(name)
+    t0 = time.perf_counter()
+    try:
+        yield fields
+    finally:
+        stack.pop()
+        led.record("phase", name=name, parent=parent,
+                   seconds=round(time.perf_counter() - t0, 6), **fields)
+
+
+_PROGRAM_LOADS: Optional[ProgramLoads] = None
+
+
+def record_program_loads() -> None:
+    """Register, once per process, the ``jax.monitoring`` listener that
+    writes a ``program.load`` phase row (obs/programs.py) for every program
+    the process traces, lowers, compiles or fetches from the persistent
+    cache WHILE a run ledger is open. Rows carry the ledger's bound
+    context, so one inside a steady window says which update recompiled."""
+    global _PROGRAM_LOADS
+    with _LOCK:
+        if _PROGRAM_LOADS is not None:
+            return
+        _PROGRAM_LOADS = loads = ProgramLoads()
+    import jax
+
+    def on_duration(event, duration_secs, **kw):
+        rows = loads.rows(event, duration_secs, **kw)  # always: it pairs
+        led = _LEDGER
+        if led is not None:
+            for fields in rows:
+                led.record("phase", name="program.load",
+                           parent=current_phase(), **fields)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def instant(name: str, cat: str = "app", **args) -> None:

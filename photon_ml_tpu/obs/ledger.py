@@ -14,7 +14,12 @@ writes, under one directory,
   sequence, dataset digest — everything that makes a ``--resume`` run
   THE SAME run). Committed under the repo's atomic-marker/CRC discipline
   (utils/diskio.py): the ``.ok`` marker carries the manifest's CRC32 and
-  is written last.
+  is written last. Its ``clock`` list holds, for every process that
+  opened the ledger, the ``t`` it began at with ``time.monotonic()`` and
+  ``time.time_ns()`` read at that instant: a row's ``t`` maps onto the
+  host's monotonic clock (``CoordinateUpdate`` listeners, a benchmark
+  harness) and onto the wall clock a profiler trace is stamped with
+  (:func:`monotonic_of`).
 * ``telemetry.jsonl`` — append-as-produced rows, one JSON object per
   line, each carrying a contiguous ``seq`` and its own CRC32. A crashed
   or SIGKILL'd run keeps its curve: the reader validates row CRCs and
@@ -36,9 +41,20 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
 * ``coordinate_update`` — one descent step: coordinate, seconds,
   validation metrics.
 * ``re_fit_wave`` — one vmapped random-effect fit-wave dispatch:
-  re_type, wave index, seconds, ``entities_fit``/``entities_skipped``
-  lane counts, and (gated sweeps, docs/SWEEPS.md) ``drift_p99`` — the
-  p99 per-entity residual-offset drift the gate saw this sweep.
+  re_type, wave index, ``seconds`` (the ENQUEUE's: dispatch is
+  asynchronous), ``entities_fit``/``entities_skipped`` lane counts, the
+  dispatch's shape (``cap``, ``lanes``, ``rows_useful``,
+  ``rows_padded``), the solver's own counts reduced on the device over
+  live lanes (``iters_sum``, ``iters_max``, ``evals_sum``,
+  ``lanes_at_cap``), and (gated sweeps, docs/SWEEPS.md) ``drift_p99`` —
+  the p99 per-entity residual-offset drift the gate saw this sweep.
+  Written through :meth:`RunLedger.defer`: the counts are read once per
+  update, after the descent loop's barrier.
+* ``phase`` — one set-up phase, written as it ends: ``name``
+  (``fit.digest``, ``re.bucketing``, ``re.host_stage``,
+  ``re.transfer``/``fe.transfer`` with ``bytes``, ``program.load`` with
+  ``event`` and ``program``), ``seconds``, ``parent``
+  (docs/OBSERVABILITY.md "The run ledger").
 * ``tuning_trial`` — one hyperparameter trial: sampled point, expected
   improvement (GP search), objective, wall seconds.
 * ``watchdog`` — a convergence-watchdog alert (obs/watchdog.py).
@@ -179,16 +195,18 @@ class RunLedger:
         self.manifest = manifest
         self._seq = seq
         self._t_base = t_base
-        self._anchor = time.perf_counter()
         self._fh = fh
         self._lock = threading.Lock()
         self._ctx: dict = {}
         self._buf: list[str] = []
+        # Rows waiting for a device read (see defer()).
+        self._deferred: list = []
         # Rows buffered before an fsync-free append. 1 = append-as-
         # produced (the per-iteration default: one line per seconds-long
         # optimizer iteration); raise it for high-rate producers.
         self.flush_rows = max(1, int(flush_rows))
         self.closed = False
+        self._anchor_clock()
 
     # -- construction --------------------------------------------------------
 
@@ -229,6 +247,7 @@ class RunLedger:
                   seq=(int(last["seq"]) + 1) if last else 0,
                   t_base=float(last["t"]) if last else 0.0,
                   fh=fh)
+        led._commit_manifest()  # this process's clock anchors
         return led
 
     def _commit_manifest(self) -> None:
@@ -290,7 +309,8 @@ class RunLedger:
         self._fh = open(os.path.join(self.directory, _TELEMETRY), "w")  # pml: allow[PML013] identity reset starts a FRESH append-as-produced stream (row CRCs, not atomic_write)
         self._seq = 0
         self._t_base = 0.0
-        self._anchor = time.perf_counter()
+        self.manifest.pop("clock", None)
+        self._anchor_clock()
 
     # -- writing -------------------------------------------------------------
 
@@ -312,23 +332,60 @@ class RunLedger:
                     else:
                         self._ctx[k] = v
 
+    def _anchor_clock(self) -> None:
+        """Anchor ``t`` and note in the manifest (committed by the
+        caller) the other two clocks' readings at that instant."""
+        self._anchor = time.perf_counter()
+        self.manifest.setdefault("clock", []).append(
+            {"t": self._t_base, "monotonic": time.monotonic(),
+             "time_ns": time.time_ns()})
+
+    def _append_locked(self, kind: str, fields: dict) -> None:
+        row = dict(self._ctx)
+        row.update({k: _coerce(v) for k, v in fields.items()})
+        row["seq"] = self._seq
+        row["t"] = round(
+            self._t_base + time.perf_counter() - self._anchor, 6)
+        row["kind"] = kind
+        row["crc"] = row_crc(row)
+        self._seq += 1
+        self._buf.append(_canonical(row))
+
     def record(self, kind: str, **fields) -> None:
         """Append one telemetry row (buffered; see ``flush_rows``).
         THE write API for optimizer/descent loops — PML010."""
         with self._lock:
             if self.closed:
                 return
-            row = dict(self._ctx)
-            row.update({k: _coerce(v) for k, v in fields.items()})
-            row["seq"] = self._seq
-            row["t"] = round(
-                self._t_base + time.perf_counter() - self._anchor, 6)
-            row["kind"] = kind
-            row["crc"] = row_crc(row)
-            self._seq += 1
-            self._buf.append(_canonical(row))
+            self._append_locked(kind, fields)
             if len(self._buf) >= self.flush_rows:
                 self._flush_locked()
+
+    def defer(self, rows) -> None:
+        """Queue rows whose fields still sit on the device. ``rows`` is
+        a callable returning ``(kind, fields)`` pairs; it runs — and does
+        its device-to-host read — only in :meth:`drain`, which the
+        descent loop calls once per update AFTER its barrier, so a
+        coordinate can count inside its jitted programs without a round
+        trip between dispatches. Rows take the drain's ``t`` and bound
+        context."""
+        with self._lock:
+            if not self.closed:
+                self._deferred.append(rows)
+
+    def drain(self) -> int:
+        """Write every deferred row, with one flush; returns how many."""
+        with self._lock:
+            pending, self._deferred = self._deferred, []
+        # the device reads happen outside the lock
+        rows = [row for deferred in pending for row in deferred()]
+        with self._lock:
+            if self.closed or not rows:
+                return 0
+            for kind, fields in rows:
+                self._append_locked(kind, fields)
+            self._flush_locked()
+        return len(rows)
 
     def _flush_locked(self) -> None:
         if self._buf and self._fh is not None:
@@ -343,18 +400,11 @@ class RunLedger:
     def close(self, status: str = "ok") -> None:
         """Flush and close; records a ``run_end`` marker so ``tail`` can
         tell a finished run from a killed one. Safe to call twice."""
+        self.drain()  # rows of a train call made outside descent.run
         with self._lock:
             if self.closed:
                 return
-            # Inline run_end (record() would deadlock on the held lock).
-            row = dict(self._ctx)
-            row.update({"seq": self._seq, "kind": "run_end",
-                        "status": status,
-                        "t": round(self._t_base + time.perf_counter()
-                                   - self._anchor, 6)})
-            row["crc"] = row_crc(row)
-            self._seq += 1
-            self._buf.append(_canonical(row))
+            self._append_locked("run_end", {"status": status})
             self._flush_locked()
             self._fh.close()
             self.closed = True
@@ -416,20 +466,39 @@ def fabric_totals() -> dict:
 
 
 def spill_history(led: "RunLedger", values, grad_norms,
-                  opt: str = "compiled") -> int:
+                  opt: str = "compiled",
+                  evaluations: Optional[int] = None) -> int:
     """Spill a compiled optimizer's NaN-padded value/grad-norm histories
     as post-fit ``opt_iter`` rows (``clock: "post_fit"`` — row ``t`` is
-    the spill time, so wall resolution is the coordinate update).
-    Returns the number of rows written."""
-    n = 0
+    the spill time, so wall resolution is the coordinate update). The
+    solve's ``evaluations`` (objective evaluations, line-search trials
+    included), when given, ride on the last row. Returns the number of
+    rows written."""
+    rows = []
     for i, (v, g) in enumerate(zip(values, grad_norms)):
         v, g = float(v), float(g)
         if v != v:  # NaN padding past the executed iterations
             continue
-        led.record("opt_iter", opt=opt, clock="post_fit", iteration=i,
-                   value=v, grad_norm=(None if g != g else g))
-        n += 1
-    return n
+        rows.append({"iteration": i, "value": v,
+                     "grad_norm": None if g != g else g})
+    if rows and evaluations is not None:
+        rows[-1]["evaluations"] = int(evaluations)
+    for row in rows:
+        led.record("opt_iter", opt=opt, clock="post_fit", **row)
+    return len(rows)
+
+
+def monotonic_of(manifest: dict, t: float) -> Optional[float]:
+    """A row's ``t`` on the host's ``time.monotonic()`` clock, through the
+    manifest's ``clock`` anchors: the last process to have opened the
+    ledger by then wrote the row. None for a manifest without anchors."""
+    anchor = None
+    for a in manifest.get("clock") or ():
+        if float(a["t"]) <= t:
+            anchor = a
+    if anchor is None:
+        return None
+    return float(anchor["monotonic"]) + t - float(anchor["t"])
 
 
 # -- reading ----------------------------------------------------------------
@@ -620,7 +689,7 @@ def config_delta(manifest_a: dict, manifest_b: dict) -> list[dict]:
     """Flattened key-by-key differences of the two manifests' config +
     identity-adjacent fields (run_id/created/versions excluded — two
     runs of the same config should diff empty)."""
-    skip = {"run_id", "created_unix", "fingerprints"}
+    skip = {"run_id", "created_unix", "fingerprints", "clock"}
     fa = _flatten({k: v for k, v in manifest_a.items() if k not in skip})
     fb = _flatten({k: v for k, v in manifest_b.items() if k not in skip})
     out = []

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -77,9 +78,27 @@ class OptResult:
     value: Array
     grad_norm: Array
     iterations: Array  # int32, iterations actually executed
+    # int32: objective evaluations actually executed (value-and-gradient
+    # calls, the starting one and every line-search trial included)
+    evaluations: Array
     converged: Array  # bool
     value_history: Array  # (max_iterations + 1,), NaN past the end
     grad_norm_history: Array  # (max_iterations + 1,), NaN past the end
+
+
+def scoped(name: str, fn: Optional[Callable] = None) -> Callable:
+    """``fn`` with every operation it traces under ``jax.named_scope(name)``
+    — the device-side names of docs/OBSERVABILITY.md's scope vocabulary;
+    with ``fn`` left out, a decorator. Compile-time metadata only: the
+    program, its name and its results are unchanged."""
+    if fn is None:
+        return functools.partial(scoped, name)
+
+    @functools.wraps(fn)
+    def wrapped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return wrapped
 
 
 def masked_update(converged: Array, new, old):

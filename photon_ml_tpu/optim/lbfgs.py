@@ -56,6 +56,7 @@ class _LBFGSState:
     head: Array  # int32: slot of newest pair
     count: Array  # int32: number of valid pairs
     it: Array  # int32
+    evals: Array  # int32: value_and_grad calls so far, trials included
     converged: Array  # bool
     failed: Array  # bool: line search stalled
     g0_norm: Array
@@ -152,6 +153,7 @@ def minimize(
         rho=jnp.zeros((m,), dtype),
         head=jnp.asarray(0, jnp.int32), count=jnp.asarray(0, jnp.int32),
         it=jnp.asarray(0, jnp.int32),
+        evals=jnp.asarray(1, jnp.int32),  # the evaluation at w0
         converged=g0_norm <= config.tolerance,
         failed=jnp.asarray(False),
         g0_norm=g0_norm,
@@ -159,7 +161,8 @@ def minimize(
     )
 
     def line_search_owlqn(w, ft, sg, direction):
-        """Backtracking Armijo on the TOTAL objective; returns new point.
+        """Backtracking Armijo on the TOTAL objective; returns the new
+        point and the trials it took.
 
         OWL-QN only: the trial point is projected onto the orthant defined
         by sign(w) (or sign(−pg) at zeros) before evaluation, which makes
@@ -188,8 +191,10 @@ def minimize(
         init_alpha = jnp.asarray(1.0, dtype)
         st = (init_alpha, jnp.asarray(0, jnp.int32), jnp.asarray(False),
               w, jnp.asarray(jnp.inf, dtype), sg)
-        _, steps, ok, new_w, new_f, new_g = lax.while_loop(ls_cond, ls_body, st)
-        return ok, new_w, new_f, new_g
+        with jax.named_scope("lbfgs.line_search"):
+            _, steps, ok, new_w, new_f, new_g = lax.while_loop(
+                ls_cond, ls_body, st)
+        return ok, new_w, new_f, new_g, steps
 
     def line_search_wolfe(w, ft, sg, direction):
         """Strong-Wolfe line search as a bounded bisection-with-expansion.
@@ -209,7 +214,8 @@ def minimize(
         Guarantees sᵀy > 0 for accepted points, so every step yields a
         valid curvature pair. On budget exhaustion falls back to the best
         Armijo-satisfying point seen (the sy > eps gate below discards its
-        pair if curvature is bad).
+        pair if curvature is bad). Also returns the trials it took: one
+        objective evaluation each.
         """
         c1 = config.wolfe_c1
         c2 = config.wolfe_c2
@@ -246,50 +252,57 @@ def minimize(
         st = (jnp.asarray(0.0, dtype), inf, jnp.asarray(1.0, dtype),
               jnp.asarray(0, jnp.int32), jnp.asarray(False),
               jnp.asarray(False), w, ft, sg)
-        (_, _, _, _, done, has_pt,
-         new_w, new_f, new_g) = lax.while_loop(ls_cond, ls_body, st)
-        return done | has_pt, new_w, new_f, new_g
+        with jax.named_scope("lbfgs.line_search"):
+            (_, _, _, steps, done, has_pt,
+             new_w, new_f, new_g) = lax.while_loop(ls_cond, ls_body, st)
+        return done | has_pt, new_w, new_f, new_g, steps
 
     line_search = line_search_owlqn if is_owlqn else line_search_wolfe
 
     def body(state: _LBFGSState) -> _LBFGSState:
         sg = search_gradient(state.w, state.g)
-        d_dir = -_two_loop(sg, state.s_hist, state.y_hist, state.rho,
-                           state.head, state.count)
-        if is_owlqn:
-            # Constrain the direction to the descent orthant of −pg.
-            d_dir = jnp.where(d_dir * (-sg) > 0.0, d_dir, 0.0)
-        # Safeguard: fall back to steepest descent on non-descent directions.
-        descent = jnp.dot(sg, d_dir) < 0.0
-        d_dir = jnp.where(descent, d_dir, -sg)
-        # First iteration: scale like Breeze (step ~ 1/‖g‖ effect) to avoid
-        # wild first steps on poorly scaled problems.
-        first = state.count == 0
-        d_dir = jnp.where(
-            first, d_dir / jnp.maximum(jnp.linalg.norm(d_dir), 1.0), d_dir)
+        with jax.named_scope("lbfgs.direction"):
+            d_dir = -_two_loop(sg, state.s_hist, state.y_hist, state.rho,
+                               state.head, state.count)
+            if is_owlqn:
+                # Constrain the direction to the descent orthant of −pg.
+                d_dir = jnp.where(d_dir * (-sg) > 0.0, d_dir, 0.0)
+            # Safeguard: fall back to steepest descent on non-descent
+            # directions.
+            descent = jnp.dot(sg, d_dir) < 0.0
+            d_dir = jnp.where(descent, d_dir, -sg)
+            # First iteration: scale like Breeze (step ~ 1/‖g‖ effect) to
+            # avoid wild first steps on poorly scaled problems.
+            first = state.count == 0
+            d_dir = jnp.where(
+                first, d_dir / jnp.maximum(jnp.linalg.norm(d_dir), 1.0),
+                d_dir)
 
         ft = total_value(state.f, state.w)
-        ok, new_w, new_f, new_g = line_search(state.w, ft, sg, d_dir)
+        ok, new_w, new_f, new_g, trials = line_search(state.w, ft, sg, d_dir)
 
-        s = new_w - state.w
-        y = new_g - state.g
-        sy = jnp.dot(s, y)
-        good_pair = ok & (sy > _EPS)
-        new_head = jnp.where(good_pair, (state.head + 1) % m, state.head)
-        new_count = jnp.where(good_pair, jnp.minimum(state.count + 1, m),
-                              state.count)
+        with jax.named_scope("lbfgs.direction"):  # the history update
+            s = new_w - state.w
+            y = new_g - state.g
+            sy = jnp.dot(s, y)
+            good_pair = ok & (sy > _EPS)
+            new_head = jnp.where(good_pair, (state.head + 1) % m, state.head)
+            new_count = jnp.where(good_pair,
+                                  jnp.minimum(state.count + 1, m),
+                                  state.count)
 
-        def upd(buf, row):
-            return jnp.where(
+            def upd(buf, row):
+                return jnp.where(
+                    good_pair,
+                    buf.at[new_head].set(row),
+                    buf)
+
+            s_hist = upd(state.s_hist, s)
+            y_hist = upd(state.y_hist, y)
+            rho = jnp.where(
                 good_pair,
-                buf.at[new_head].set(row),
-                buf)
-
-        s_hist = upd(state.s_hist, s)
-        y_hist = upd(state.y_hist, y)
-        rho = jnp.where(good_pair,
-                        state.rho.at[new_head].set(1.0 / jnp.maximum(sy, _EPS)),
-                        state.rho)
+                state.rho.at[new_head].set(1.0 / jnp.maximum(sy, _EPS)),
+                state.rho)
 
         new_sg = search_gradient(new_w, new_g)
         new_gnorm = jnp.linalg.norm(new_sg)
@@ -312,6 +325,7 @@ def minimize(
             s_hist=s_hist, y_hist=y_hist, rho=rho,
             head=new_head, count=new_count,
             it=it,
+            evals=state.evals + trials,
             converged=state.converged | conv | failed,
             failed=state.failed | failed,
             g0_norm=state.g0_norm,
@@ -331,6 +345,7 @@ def minimize(
         value=total_value(final.f, final.w),
         grad_norm=jnp.linalg.norm(sg_final),
         iterations=final.it,
+        evaluations=final.evals,
         converged=final.converged & ~final.failed,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,

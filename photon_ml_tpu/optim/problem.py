@@ -28,6 +28,7 @@ from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType, OptResult,
                                  RegularizationContext, l1_weights_vector,
                                  optimize, with_l2, with_l2_hvp)
+from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.regularization import intercept_mask
 
 Array = jax.Array
@@ -94,7 +95,7 @@ def make_objective(
         return agg.hessian_vector(loss, w, v, batch, norm)
 
     l2 = reg.l2_weight()
-    vg = with_l2(vg, l2, mask)
+    vg = scoped("glm.value_grad", with_l2(vg, l2, mask))
     hvp = with_l2_hvp(hvp, l2, mask)
     l1 = reg.l1_weight()
     l1_weights = (l1_weights_vector(l1, dim, intercept_index)

@@ -456,6 +456,10 @@ def minimize_stochastic(
         # it rides the grad_norm slots of the shared result type.
         grad_norm=jnp.asarray(gap, jnp.float32),
         iterations=jnp.asarray(it, jnp.int32),
+        # One full-objective pass per epoch this call ran, and the
+        # starting one unless the call resumed past it.
+        evaluations=jnp.asarray(
+            it - start_it + 1 + (resume_state is None), jnp.int32),
         converged=jnp.asarray(converged),
         value_history=jnp.asarray(vals),
         grad_norm_history=jnp.asarray(gaps),

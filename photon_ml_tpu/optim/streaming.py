@@ -372,6 +372,7 @@ def minimize_streaming(
         grad_norm=jnp.asarray(gns[it] if not np.isnan(gns[it]) else gn_prev,
                               jnp.float32),
         iterations=jnp.asarray(it, jnp.int32),
+        evaluations=jnp.asarray(v_passes + g_passes, jnp.int32),
         converged=jnp.asarray(converged),
         value_history=jnp.asarray(vals),
         grad_norm_history=jnp.asarray(gns),

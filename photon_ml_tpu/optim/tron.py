@@ -177,6 +177,7 @@ def minimize(
         value=final.f,
         grad_norm=jnp.linalg.norm(final.g),
         iterations=final.it,
+        evaluations=final.it + 1,  # the starting point, then one a step
         converged=final.converged & ~final.failed,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,

@@ -23,6 +23,7 @@ from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim import (OptResult, OptimizerType,
                                  l1_weights_vector, optimize, with_l2,
                                  with_l2_hvp)
+from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          resolve_optimizer_config,
@@ -53,7 +54,8 @@ def run(
     reg = config.regularization
     l2 = reg.l2_weight()
 
-    vg = with_l2(dobj.make_value_and_gradient(loss, mesh, batch, norm), l2, mask)
+    vg = scoped("glm.value_grad", with_l2(
+        dobj.make_value_and_gradient(loss, mesh, batch, norm), l2, mask))
     hvp = with_l2_hvp(dobj.make_hvp(loss, mesh, batch, norm), l2, mask)
 
     l1 = reg.l1_weight()

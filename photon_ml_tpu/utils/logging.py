@@ -1,17 +1,16 @@
-"""Logging + timing utilities.
+"""Logging utilities and the profiler scope.
 
 Reference parity: photon-lib ``util/PhotonLogger.scala`` (log4j logger whose
-output is also persisted next to the job output) and ``util/Timer.scala``
-(wall-clock scopes).
+output is also persisted next to the job output). The reference's
+``util/Timer.scala`` wall-clock scopes are ``obs.span`` / ``obs.phase``
+here (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
 
 import contextlib
-import json
 import logging
 import os
-import time
 from typing import Optional
 
 
@@ -37,38 +36,6 @@ def setup_logging(
     return logger
 
 
-class Timer:
-    """Wall-clock scope timer (reference: util/Timer.scala)."""
-
-    def __init__(self):
-        self.durations: dict[str, float] = {}
-
-    @contextlib.contextmanager
-    def scope(self, name: str):
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.durations[name] = self.durations.get(name, 0.0) + (
-                time.monotonic() - t0)
-
-
-class MetricsWriter:
-    """Structured per-step metrics to a JSONL file (the rebuild's
-    OptimizationStatesTracker/EvaluationResults observability sink)."""
-
-    def __init__(self, path: str):
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        self._f = open(path, "a")
-
-    def write(self, record: dict) -> None:
-        self._f.write(json.dumps(record) + "\n")
-        self._f.flush()
-
-    def close(self) -> None:
-        self._f.close()
-
-
 @contextlib.contextmanager
 def profile_trace(trace_dir: Optional[str]):
     """XLA/TPU profiler scope (SURVEY.md §5 tracing row): when ``trace_dir``
@@ -85,9 +52,3 @@ def profile_trace(trace_dir: Optional[str]):
     with jax.profiler.trace(trace_dir):
         yield
 
-
-def annotate(name: str):
-    """Named sub-scope inside a profiler trace (TraceAnnotation)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
