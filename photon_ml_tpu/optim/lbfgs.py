@@ -8,8 +8,15 @@ L1 weights, intercept excluded).
 TPU-first design (SURVEY.md §7 step 2): instead of wrapping a host-side
 optimization library, the whole optimizer is a single compiled state machine:
 
-- fixed-shape circular (m, d) history buffers + ``lax.fori_loop`` two-loop
-  recursion — no Python lists, no dynamic shapes;
+- fixed-shape (m, d) history buffers kept in AGE order (slot 0 the newest
+  pair, slot a the pair of age a; an accepted pair shifts the rest down by
+  one and the oldest falls off) and a two-loop recursion unrolled over the
+  static age — no dynamic index anywhere in the state machine. A circular
+  buffer would need a ``head``, and under vmap the head is per lane (lanes
+  accept and reject pairs on their own), so every ``buf[head - j]`` lowers
+  to a gather and every ``buf.at[head].set`` to a scatter with one index per
+  lane, which the TPU walks lane by lane: 56% of a GLMix sweep (PERF.md §6,
+  PR 27);
 - strong-Wolfe line search (Breeze ``StrongWolfeLineSearch`` parity) as a
   bounded bisection-with-expansion inner ``while_loop`` — each trial costs
   one fused objective evaluation = one psum when the objective is
@@ -53,8 +60,7 @@ class _LBFGSState:
     s_hist: Array  # (m, d)
     y_hist: Array  # (m, d)
     rho: Array  # (m,)
-    head: Array  # int32: slot of newest pair
-    count: Array  # int32: number of valid pairs
+    count: Array  # int32: number of valid pairs (slots 0 … count−1)
     it: Array  # int32
     evals: Array  # int32: value_and_grad calls so far, trials included
     converged: Array  # bool
@@ -64,35 +70,40 @@ class _LBFGSState:
     grad_norm_history: Array
 
 
-def _two_loop(g, s_hist, y_hist, rho, head, count):
-    """Two-loop recursion: returns d ≈ H⁻¹ g (descent dir is −d)."""
+def _two_loop(g, s_hist, y_hist, rho, count):
+    """Two-loop recursion: returns d ≈ H⁻¹ g (descent dir is −d).
+
+    The history is in age order (slot 0 newest) and ``count`` only masks,
+    so every index is a Python int and the 2m steps unroll into slices.
+    A ``fori_loop`` over the (unbatched) age gives the same numbers; on the
+    chip it compiled no sooner, loaded 5 s later and ran the sweep 1%
+    faster (PERF.md §6, PR 27), so the plain form stays.
+    """
     m = s_hist.shape[0]
-    alphas0 = jnp.zeros((m,), dtype=g.dtype)
+    valid = [a < count for a in range(m)]
 
-    def bwd(j, carry):
-        q, alphas = carry
-        idx = (head - j) % m
-        valid = j < count
-        a = jnp.where(valid, rho[idx] * jnp.dot(s_hist[idx], q), 0.0)
-        q = q - a * y_hist[idx]
-        return q, alphas.at[idx].set(a)
+    q = g
+    alphas = []
+    for a in range(m):  # newest → oldest
+        alpha = jnp.where(valid[a], rho[a] * jnp.dot(s_hist[a], q), 0.0)
+        q = q - alpha * y_hist[a]
+        alphas.append(alpha)
 
-    q, alphas = lax.fori_loop(0, m, bwd, (g, alphas0))
-
-    sy = jnp.dot(s_hist[head], y_hist[head])
-    yy = jnp.dot(y_hist[head], y_hist[head])
+    sy = jnp.dot(s_hist[0], y_hist[0])
+    yy = jnp.dot(y_hist[0], y_hist[0])
     gamma = jnp.where(count > 0, sy / jnp.maximum(yy, _EPS), 1.0)
     r = gamma * q
 
-    def fwd(j, r):
-        # oldest → newest
-        idx = (head - (count - 1 - j)) % m
-        valid = j < count
-        b = rho[idx] * jnp.dot(y_hist[idx], r)
-        r = r + jnp.where(valid, alphas[idx] - b, 0.0) * s_hist[idx]
-        return r
+    for a in reversed(range(m)):  # oldest → newest
+        b = rho[a] * jnp.dot(y_hist[a], r)
+        r = r + jnp.where(valid[a], alphas[a] - b, 0.0) * s_hist[a]
+    return r
 
-    return lax.fori_loop(0, m, fwd, r)
+
+def _push(buf: Array, row: Array, good_pair: Array) -> Array:
+    """An accepted pair's ``row`` into slot 0 and every other one a slot
+    older (the oldest falls off); a rejected pair leaves ``buf`` as it is."""
+    return jnp.where(good_pair, jnp.concatenate([row[None], buf[:-1]]), buf)
 
 
 def _project_orthant(x: Array, orthant: Array) -> Array:
@@ -142,16 +153,22 @@ def minimize(
     g0_norm = jnp.linalg.norm(sg0)
 
     hist_shape = (m, d)
-    vh = jnp.full((max_iter + 1,), jnp.nan, jnp.float32).at[0].set(
-        ft0.astype(jnp.float32))
-    gh = jnp.full((max_iter + 1,), jnp.nan, jnp.float32).at[0].set(
-        g0_norm.astype(jnp.float32))
+    steps = jnp.arange(max_iter + 1)
+
+    def record(hist, it, value):
+        """``hist.at[it].set(value)`` as a mask: ``it`` is per lane under
+        vmap, where an indexed write is a scatter."""
+        return jnp.where(steps == it, value.astype(jnp.float32), hist)
+
+    nans = jnp.full((max_iter + 1,), jnp.nan, jnp.float32)
+    vh = record(nans, 0, ft0)
+    gh = record(nans, 0, g0_norm)
 
     init = _LBFGSState(
         w=w0, f=f0, g=g0,
         s_hist=jnp.zeros(hist_shape, dtype), y_hist=jnp.zeros(hist_shape, dtype),
         rho=jnp.zeros((m,), dtype),
-        head=jnp.asarray(0, jnp.int32), count=jnp.asarray(0, jnp.int32),
+        count=jnp.asarray(0, jnp.int32),
         it=jnp.asarray(0, jnp.int32),
         evals=jnp.asarray(1, jnp.int32),  # the evaluation at w0
         converged=g0_norm <= config.tolerance,
@@ -263,7 +280,7 @@ def minimize(
         sg = search_gradient(state.w, state.g)
         with jax.named_scope("lbfgs.direction"):
             d_dir = -_two_loop(sg, state.s_hist, state.y_hist, state.rho,
-                               state.head, state.count)
+                               state.count)
             if is_owlqn:
                 # Constrain the direction to the descent orthant of −pg.
                 d_dir = jnp.where(d_dir * (-sg) > 0.0, d_dir, 0.0)
@@ -286,23 +303,12 @@ def minimize(
             y = new_g - state.g
             sy = jnp.dot(s, y)
             good_pair = ok & (sy > _EPS)
-            new_head = jnp.where(good_pair, (state.head + 1) % m, state.head)
             new_count = jnp.where(good_pair,
                                   jnp.minimum(state.count + 1, m),
                                   state.count)
-
-            def upd(buf, row):
-                return jnp.where(
-                    good_pair,
-                    buf.at[new_head].set(row),
-                    buf)
-
-            s_hist = upd(state.s_hist, s)
-            y_hist = upd(state.y_hist, y)
-            rho = jnp.where(
-                good_pair,
-                state.rho.at[new_head].set(1.0 / jnp.maximum(sy, _EPS)),
-                state.rho)
+            s_hist = _push(state.s_hist, s, good_pair)
+            y_hist = _push(state.y_hist, y, good_pair)
+            rho = _push(state.rho, 1.0 / jnp.maximum(sy, _EPS), good_pair)
 
         new_sg = search_gradient(new_w, new_g)
         new_gnorm = jnp.linalg.norm(new_sg)
@@ -312,18 +318,16 @@ def minimize(
                                       config.tolerance)
         failed = ~ok  # line search exhausted: stop (stalled)
 
-        vh = state.value_history.at[it].set(
-            jnp.where(ok, ft_new, ft).astype(jnp.float32))
-        gh = state.grad_norm_history.at[it].set(
-            jnp.where(ok, new_gnorm,
-                      jnp.linalg.norm(sg)).astype(jnp.float32))
+        vh = record(state.value_history, it, jnp.where(ok, ft_new, ft))
+        gh = record(state.grad_norm_history, it,
+                    jnp.where(ok, new_gnorm, jnp.linalg.norm(sg)))
 
         new_state = _LBFGSState(
             w=jnp.where(ok, new_w, state.w),
             f=jnp.where(ok, new_f, state.f),
             g=jnp.where(ok, new_g, state.g),
             s_hist=s_hist, y_hist=y_hist, rho=rho,
-            head=new_head, count=new_count,
+            count=new_count,
             it=it,
             evals=state.evals + trials,
             converged=state.converged | conv | failed,

@@ -373,6 +373,30 @@ def _paths(text):
     return set(re.findall(r'loc\("(jit\([^"]*)"', text))
 
 
+def test_direction_scope_names_the_unrolled_recursion(game):
+    """The two-loop recursion is unrolled over the history's age (ISSUE 27):
+    its dot products, the slices of the history and the shift that ages it
+    sit under ``lbfgs.direction`` themselves, with no loop and no per-lane
+    index between them and the scope, and the compiled program's operations
+    still carry the scope for the trace to read."""
+    import re
+    _, coords = game
+    coord = coords["per-user"]
+    W = jnp.zeros((coord.num_entities, coord.dim), jnp.float32)
+    offsets = jnp.zeros((coord.dataset.num_rows,), jnp.float32)
+    lowered = coord._fit_bucket.lower(W, offsets, *coord._bucket_data[0])
+    under = {p.split("lbfgs.direction/", 1)[1]
+             for p in _paths(lowered.as_text(debug_info=True))
+             if "lbfgs.direction/" in p}
+    assert {"dot_general", "slice", "concatenate"} <= under, under
+    assert not [p for p in under if re.search(
+        r"while|gather|scatter|dynamic_(update_)?slice", p)], under
+    compiled = re.findall(r'op_name="([^"]*lbfgs\.direction[^"]*)"',
+                          lowered.compile().as_text())
+    assert len(compiled) >= 10  # a rolled loop's body would leave a few
+    assert all("re.solve" in scope_reduce.scopes_of(p) for p in compiled)
+
+
 def test_gated_program_lowers_with_the_same_scopes(game):
     _, coords = game
     coord = coords["per-user"]

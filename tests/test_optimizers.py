@@ -5,6 +5,8 @@ Mirrors photon-lib ``LBFGSTest`` / ``TRONTest`` / ``OWLQNTest`` (SURVEY.md
 cross-checks (LBFGS and TRON reach the same optimum), OWL-QN sparsity.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ import scipy.optimize
 from photon_ml_tpu.data.batch import LabeledBatch
 from photon_ml_tpu.ops import aggregators as agg
 from photon_ml_tpu.ops import losses
+from photon_ml_tpu.optim import lbfgs
 from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType,
                                  l1_weights_vector, minimize_lbfgs,
                                  minimize_owlqn, minimize_tron, optimize,
@@ -319,3 +322,172 @@ def test_wolfe_logistic_fewer_evals_than_tolerance_budget(rng):
         max_iterations=200, tolerance=1e-9))
     np.testing.assert_allclose(out.w, w_ref, rtol=2e-2, atol=2e-2)
     assert int(out.iterations) < 60
+
+
+# -- the age-ordered history (ISSUE 27) ----------------------------------------
+
+def _two_loop_lists(g, pairs):
+    """Nocedal & Wright, Numerical Optimization, Algorithm 7.4, over a plain
+    Python list of (s, y) pairs, oldest first, in float64: the reference
+    ``lbfgs._two_loop`` is held to."""
+    q = np.array(g, np.float64)
+    rhos = [1.0 / (y @ s) for s, y in pairs]
+    alphas = [0.0] * len(pairs)
+    for i in reversed(range(len(pairs))):
+        s, y = pairs[i]
+        alphas[i] = rhos[i] * (s @ q)
+        q = q - alphas[i] * y
+    if pairs:
+        s, y = pairs[-1]
+        q = (s @ y) / (y @ y) * q
+    for i, (s, y) in enumerate(pairs):
+        beta = rhos[i] * (y @ q)
+        q = q + s * (alphas[i] - beta)
+    return q
+
+
+@pytest.mark.parametrize("d", [8, 32])
+@pytest.mark.parametrize("accepted", [0, 1, 3, 9, 10, 14],
+                         ids=lambda k: f"pairs{k}")
+def test_two_loop_matches_nocedal_wright(accepted, d):
+    """``count`` in {0, 1, 3, m−1, m} and eviction (14 accepted pairs, the 4
+    oldest gone), with a rejected pair between every two accepted ones, which
+    must leave the buffers as they are."""
+    m = 10
+    rng = np.random.default_rng(100 * d + accepted)
+    A = rng.normal(size=(d, d))
+    A = A @ A.T / d + np.eye(d)  # SPD: every pair has sᵀy > 0
+    s_hist = jnp.zeros((m, d), jnp.float32)
+    y_hist = jnp.zeros((m, d), jnp.float32)
+    rho = jnp.zeros((m,), jnp.float32)
+    pairs = []
+    for _ in range(accepted):
+        s = rng.normal(size=d).astype(np.float32)
+        y = (A @ s).astype(np.float32)
+        pairs.append((s.astype(np.float64), y.astype(np.float64)))
+        for good, scale in ((True, 1.0), (False, 7.0)):
+            good = jnp.asarray(good)
+            s_hist = lbfgs._push(s_hist, jnp.asarray(scale * s), good)
+            y_hist = lbfgs._push(y_hist, jnp.asarray(scale * y), good)
+            rho = lbfgs._push(rho, jnp.asarray(scale / (s @ y), jnp.float32),
+                              good)
+    count = jnp.asarray(min(accepted, m), jnp.int32)
+    # slot 0 holds the newest pair, slot a the pair of age a
+    for age, (s, _) in enumerate(reversed(pairs[-m:])):
+        np.testing.assert_array_equal(s_hist[age], s.astype(np.float32))
+    g = rng.normal(size=d).astype(np.float32)
+    got = lbfgs._two_loop(jnp.asarray(g), s_hist, y_hist, rho, count)
+    want = _two_loop_lists(g, pairs[-m:])
+    np.testing.assert_allclose(got, want, rtol=2e-4,
+                               atol=2e-5 * np.abs(want).max())
+
+
+def _lane_problems(rng, d):
+    """Six per-entity problems that differ in every way a circular history
+    needed a per-lane index for. Lane k minimizes
+
+        logistic(X_k, y_k; w) + ½·l2_k·|w|² + c_k·w + ½·relu(−c_k·w − T)²
+
+    0, 1: well-scaled logistic fits, which converge in a few iterations,
+       each at its own;
+    2: features scaled over three decades: runs past m = 10 iterations, so
+       it evicts pairs;
+    3: all-zero features and w0 = 0: the gradient is zero, converged at w0;
+    4: nothing but the linear term: y = 0 in every pair, so every pair is
+       rejected (sᵀy ≤ eps) and the lane runs to the iteration cap;
+    5: linear until c·w < −T, curved from there on: rejects its first pairs,
+       accepts the later ones.
+    """
+    E, n = 6, 40
+    X = rng.normal(size=(E, n, d)).astype(np.float32)
+    X[2] *= np.logspace(-1.5, 1.5, d).astype(np.float32)
+    w_true = rng.normal(size=(E, d))
+    y = (rng.uniform(size=(E, n))
+         < 1 / (1 + np.exp(-np.einsum("end,ed->en", X, w_true)))
+         ).astype(np.float32)
+    X[3:] = 0.0
+    l2 = np.array([0.1, 1.0, 1e-3, 1.0, 0.0, 0.0], np.float32)
+    c = np.zeros((E, d), np.float32)
+    c[4] = rng.normal(size=d)
+    c[5] = rng.normal(size=d)
+    c[4:] /= np.linalg.norm(c[4:], axis=1, keepdims=True)
+    bend = np.array([0, 0, 0, 0, 0, 1], np.float32)
+    return tuple(jnp.asarray(a) for a in (X, y, l2, c, bend))
+
+
+def _lane_objective(threshold, X, y, l2, c, bend):
+    batch = LabeledBatch.build(X, y)
+
+    def vg(w):
+        f, g = agg.value_and_gradient(losses.LOGISTIC, w, batch)
+        over = bend * jnp.maximum(-jnp.dot(c, w) - threshold, 0.0)
+        return (f + 0.5 * l2 * jnp.dot(w, w) + jnp.dot(c, w)
+                + 0.5 * over * over,
+                g + l2 * w + c - over * c)
+    return vg
+
+
+@pytest.mark.parametrize("owlqn", [False, True], ids=["lbfgs", "owlqn"])
+def test_vmapped_lanes_that_differ_match_individual_solves(owlqn):
+    """The vmapped machine gives each lane the iterate, the iteration count
+    and the evaluation count of the same problem solved alone (extends
+    ``test_vmapped_lbfgs_matches_individual``). The strong-Wolfe search
+    doubles its step 24 times before it gives up on lane 5's linear stretch,
+    OWL-QN's Armijo search takes unit steps: hence the two thresholds. The
+    tolerance stops every lane well above f32's rounding, where a batched
+    and an unbatched reduction's last bits would decide a trial."""
+    d = 8
+    cfg = OptimizerConfig(max_iterations=30, tolerance=1e-4)
+    data = _lane_problems(np.random.default_rng(2027), d)
+    threshold = 3.5 if owlqn else 2e7
+    l1 = jnp.full((d,), 0.05) if owlqn else None
+
+    def solve(*lane):
+        return lbfgs.minimize(_lane_objective(threshold, *lane),
+                              jnp.zeros((d,), jnp.float32), cfg,
+                              l1_weights=l1)
+
+    outs = jax.jit(jax.vmap(solve))(*data)
+    alone = jax.jit(solve)
+    for k in range(6):
+        one = alone(*(a[k] for a in data))
+        assert int(outs.iterations[k]) == int(one.iterations), k
+        assert int(outs.evaluations[k]) == int(one.evaluations), k
+        assert bool(outs.converged[k]) == bool(one.converged), k
+        np.testing.assert_allclose(outs.w[k], one.w, rtol=1e-4, atol=1e-5,
+                                   err_msg=f"lane {k}")
+    its = [int(i) for i in outs.iterations]
+    evals = [int(e) for e in outs.evaluations]
+    assert its[0] != its[1] and 0 < its[0] < 30 and 0 < its[1] < 30, its
+    assert its[2] > cfg.history_length, its  # evicts
+    assert its[3] == 0 and evals[3] == 1, (its, evals)  # converged at w0
+    assert its[4] == 30, its  # never a curvature pair, never converges
+    if not owlqn:  # every search of the linear lane runs out of trials
+        assert evals[4] == 1 + 30 * cfg.max_line_search_steps, evals
+    assert 1 < its[5] < 30 and bool(outs.converged[5]), its
+
+
+@pytest.mark.parametrize("owlqn", [False, True], ids=["lbfgs", "owlqn"])
+def test_vmapped_solve_compiles_without_gather_or_scatter(owlqn, rng):
+    """A per-lane index (a ring's head, ``history.at[it]``) lowers to a
+    gather or scatter with one index per lane, which a TPU walks lane by
+    lane (PERF.md §6, PR 27). The whole result is kept, the histories
+    included, so nothing is dead code."""
+    E, n, d = 16, 12, 8
+    X = jnp.asarray(rng.normal(size=(E, n, d)), jnp.float32)
+    y = jnp.asarray(rng.uniform(size=(E, n)) < 0.5, jnp.float32)
+    cfg = OptimizerConfig(max_iterations=25, tolerance=1e-7)
+
+    def solve(X, y, w0):
+        vg = with_l2(lambda w: agg.value_and_gradient(
+            losses.LOGISTIC, w, LabeledBatch.build(X, y)), 1.0)
+        return lbfgs.minimize(
+            vg, w0, cfg, l1_weights=jnp.full((d,), 0.1) if owlqn else None)
+
+    lowered = jax.jit(jax.vmap(solve)).lower(X, y, jnp.zeros((E, d)))
+    for text in (lowered.as_text(), lowered.compile().as_text()):
+        found = re.findall(
+            r"(?:stablehlo\.|[\]})] )(gather|scatter|dynamic_slice|"
+            r"dynamic-slice|dynamic_update_slice|dynamic-update-slice)\b",
+            text)
+        assert found == [], found
