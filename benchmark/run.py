@@ -5,6 +5,9 @@ The harness knows no cell, configuration, traffic mix or metric by name. It
 reads ``BENCHMARK.json`` at the root of the checkout and finds by name:
 
 - the cell's configuration file, ``configs[].file`` of its ``config``;
+- the configuration's data schema, ``benchmark/schemas/<schema>.py`` where
+  ``<schema>`` is the configuration file's ``"schema"``: the one module that
+  knows the shape of the data (``SCHEMA`` below lists what it exposes);
 - the traffic mix, ``benchmark/traffic/<traffic>.json``;
 - the cell's own settings, ``benchmark/workloads/<cell>.json`` (optimiser,
   ``max_samples``, ``steady_sweep_s``);
@@ -12,12 +15,13 @@ reads ``BENCHMARK.json`` at the root of the checkout and finds by name:
   ``<stem>`` is the metric's name up to its first ``.``; it exposes
   ``read(name, ctx)`` and returns a number, or ``None`` when it finds nothing.
 
-A run is one process and one ``GameEstimator.fit`` on data made in memory from
-``--seed``. Set-up is process start to the end of descent sweep 2; the window
-is sweeps 3 .. 2+N with N fixed beforehand from ``--seconds`` and the cell's
-``steady_sweep_s``; the output check (``reference.py``) runs after the window
-has closed and the peak memory has been read. ``--rehearsal`` lets the same
-code run off the chip at a tiny size and marks its output as no measurement.
+A run is one process and one ``fit`` of the schema's estimator on data made in
+memory from ``--seed``. Set-up is process start to the end of descent sweep 2;
+the window is sweeps 3 .. 2+N with N fixed beforehand from ``--seconds`` and
+the cell's ``steady_sweep_s``; the output check (the schema's ``check``) runs
+after the window has closed and the peak memory has been read. ``--rehearsal``
+lets the same code run off the chip at a tiny size and marks its output as no
+measurement.
 """
 
 from __future__ import annotations
@@ -41,9 +45,31 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(HERE, "layer_metrics"))
+sys.path.insert(0, os.path.join(HERE, "schemas"))
 
 TRACE_BUDGET_S = 60.0  # the trace reduction's own time budget
 MARK = "bench.mark"  # host-plane marker prefix, one per CoordinateUpdate
+# What a schema module exposes; run.py and the metric readers call nothing
+# else of it:
+#   make(seed, conf)           the seeded data, plain numpy, nothing of the
+#                              program imported
+#   shrink(conf, rows)         the rehearsal's smaller configuration
+#   dataset(data)              the program's dataset of that data
+#   estimator(cell, mesh, sweeps, ledger_dir, feature_dtype)
+#                              the object the window drives, built as
+#                              cli/game_train.main builds it
+#   model_arrays(model, mix)   the trained leaves as numpy
+#   check(data, cell, served, ledger_rows, sweeps)
+#                              the plain reference and the comparison: name ->
+#                              {"value", "limit"}, limits from the
+#                              configuration's check.limits
+#   sweep_flops(ctx)           FLOPs the traced sweep needs, or None
+#   bytes_needed(kernel, ctx)  bytes that kernel's work in the traced sweep
+#                              needs, or None
+#   faults                     name -> a function that returns the context
+#                              manager planting that fault under the timed path
+SCHEMA = ("make", "shrink", "dataset", "estimator", "model_arrays", "check",
+          "sweep_flops", "bytes_needed", "faults")
 
 
 def log(*a):
@@ -87,62 +113,17 @@ def layer_reader(metric_name: str):
     return mod.read
 
 
-def build_estimator(cell: dict, mesh, sweeps: int, ledger_dir: str,
-                    feature_dtype: str):
-    """The object the window drives, built as ``cli/game_train.main`` builds
-    it, from the cell's files alone."""
-    from photon_ml_tpu.api.configs import (CoordinateConfiguration,
-                                           FixedEffectDataConfiguration,
-                                           RandomEffectDataConfiguration)
-    from photon_ml_tpu.api.estimator import GameEstimator
-    from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType,
-                                     RegularizationContext)
-    from photon_ml_tpu.optim.problem import GLMOptimizationConfiguration
-    from photon_ml_tpu.optim.regularization import RegularizationType
-
-    o = cell["settings"]["optimizer"]
-    opt = GLMOptimizationConfiguration(
-        optimizer=OptimizerConfig(
-            optimizer_type=OptimizerType(o["optimizer"]),
-            max_iterations=int(o["max_iterations"])),
-        regularization=RegularizationContext(
-            reg_type=RegularizationType(o["regularization"]),
-            reg_weight=float(o["reg_weight"])))
-    coords = {}
-    for cid, c in cell["mix"]["coordinates"].items():
-        if c["type"] == "fixed":
-            data = FixedEffectDataConfiguration(
-                c["shard"], feature_dtype=feature_dtype)
-        else:
-            data = RandomEffectDataConfiguration(
-                random_effect_type=c["entity"],
-                feature_shard_id="re_" + c["entity"],
-                active_data_upper_bound=cell["settings"].get("max_samples"),
-                feature_dtype=feature_dtype)
-        coords[cid] = CoordinateConfiguration(data=data, optimization=opt)
-    tasks = {"logistic": "LOGISTIC_REGRESSION", "linear": "LINEAR_REGRESSION"}
-    task = cell["configuration"]["task"]
-    if task not in tasks:  # gen.py, reference.py and work.py know these two
-        raise SystemExit(f"unknown task {task!r}: a new task needs its loss "
-                         f"in gen.py, reference.py and work.py")
-    return GameEstimator(
-        task=tasks[task], coordinates=coords,
-        update_sequence=list(cell["mix"]["update_sequence"]), mesh=mesh,
-        descent_iterations=sweeps, validation_evaluators=None,
-        compute_variances_at_end=False, ledger_dir=ledger_dir)
-
-
-def to_dataset(data):
-    from photon_ml_tpu.data.game_data import GameDataset
-    import numpy as np
-
-    n = data.num_rows
-    return GameDataset(
-        response=data.response, offsets=np.zeros(n, np.float32),
-        weights=np.ones(n, np.float32), feature_shards=dict(data.shards),
-        entity_ids=dict(data.entity_ids),
-        num_entities=dict(data.num_entities),
-        intercept_index={k: v.shape[1] - 1 for k, v in data.shards.items()})
+def load_schema(name):
+    """The module ``benchmark/schemas/<name>.py``, held to ``SCHEMA``."""
+    path = os.path.join(HERE, "schemas", f"{name}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"the configuration file's \"schema\" is {name!r}: "
+                         f"there is no {path}")
+    mod = importlib.import_module(name)
+    missing = [k for k in SCHEMA if not hasattr(mod, k)]
+    if missing:
+        raise SystemExit(f"{path} lacks {', '.join(missing)}")
+    return mod
 
 
 class Recorder:
@@ -235,17 +216,6 @@ def count_compiles():
     return counts
 
 
-def model_arrays(model, mix):
-    """The trained model as plain numpy, one leaf per coordinate."""
-    import numpy as np
-    out = {}
-    for cid, c in mix["coordinates"].items():
-        m = model.models[cid]
-        out[cid] = np.asarray(m.coefficients.means if c["type"] == "fixed"
-                              else m.means, np.float32)
-    return out
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload")
@@ -265,23 +235,19 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.selfcheck:
         import selfcheck
-        return selfcheck.main()
+        return selfcheck.main({
+            c["name"]: load_schema(load_json(ROOT, c["file"]).get("schema"))
+            for c in load_json(ROOT, "BENCHMARK.json")["configs"]})
     if not args.workload:
         ap.error("--workload is required")
     cell = load_cell(args.workload)
     conf = cell["configuration"]
     mix = cell["mix"]
+    schema = load_schema(conf.get("schema"))
     if args.rows is not None:
         if not args.rehearsal:
             ap.error("--rows is for --rehearsal only")
-        # fewer entities with the source's activity each, scaled to the rows
-        few = [max(8, min(e["count"], args.rows // 20))
-               for e in conf["entities"]]
-        conf = dict(conf, num_rows=args.rows, entities=[
-            dict(e, count=k, activity=dict(
-                e["activity"], rows=e["activity"]["rows"] * k / e["count"]))
-            for e, k in zip(conf["entities"], few)])
-        cell["configuration"] = conf
+        cell["configuration"] = conf = schema.shrink(conf, args.rows)
 
     from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
     cache_dir = enable_compilation_cache()
@@ -311,18 +277,16 @@ def main(argv=None) -> int:
                              / float(cell["settings"]["steady_sweep_s"])))
     sweeps = setup_sweeps + n_window
 
-    import gen
     t = time.monotonic()
-    data = gen.make(args.seed, conf)
+    data = schema.make(args.seed, conf)
     gen_s = time.monotonic() - t
-    log(f"generated {data.num_rows} rows from seed {args.seed} in "
-        f"{gen_s:.2f} s")
+    log(f"generated the data of seed {args.seed} in {gen_s:.2f} s")
 
     from photon_ml_tpu.parallel.mesh import make_mesh
     from photon_ml_tpu.utils import events
     work = tempfile.mkdtemp(prefix="bench-")
     trace_dir = os.path.join(work, "trace") if args.trace else None
-    est = build_estimator(
+    est = schema.estimator(
         cell, make_mesh(devices=devices[:cell["chips"]]), sweeps,
         os.path.join(work, "ledger"), args.control or conf["storage_dtype"])
     rec = Recorder(mix["update_sequence"], setup_sweeps, sweeps, trace_dir,
@@ -330,7 +294,7 @@ def main(argv=None) -> int:
     events.default_emitter.register(rec)
     t_fit = time.monotonic()
     try:
-        result = est.fit(to_dataset(data),
+        result = est.fit(schema.dataset(data),
                          locked_coordinates=set(mix["locked_coordinates"])
                          or None)[0]
     finally:
@@ -356,7 +320,7 @@ def main(argv=None) -> int:
 
     from photon_ml_tpu.obs.ledger import read_rows
     ledger_rows, _ = read_rows(os.path.join(work, "ledger"))
-    served = model_arrays(result.model, mix)
+    served = schema.model_arrays(result.model, mix)
     # Free the program's state before the reference takes the device.
     del est, result
     gc.collect()
@@ -367,7 +331,7 @@ def main(argv=None) -> int:
         "ledger_rows": ledger_rows, "t_fit": t_fit, "t_open": t_open,
         "t_close": t_close, "n_window": n_window,
         "setup_sweeps": setup_sweeps, "traced_sweep": rec.traced_sweep,
-        "trace": None,
+        "trace": None, "trace_dir": trace_dir, "schema": schema,
     }
     device = {"platform": platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": memory["peak_bytes_in_use"]}
@@ -401,9 +365,8 @@ def main(argv=None) -> int:
             out["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
     shutil.rmtree(work, ignore_errors=True)
 
-    import reference
     t = time.monotonic()
-    numbers = reference.check(data, cell, served, ledger_rows, sweeps)
+    numbers = schema.check(data, cell, served, ledger_rows, sweeps)
     log(f"reference and comparison took {time.monotonic() - t:.2f} s")
     over = [k for k, v in numbers.items() if not v["value"] <= v["limit"]]
     late = in_window["requests"]

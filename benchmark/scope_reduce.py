@@ -30,10 +30,7 @@ annotation open on the host as each began. Log lines, not metrics.
 
 from __future__ import annotations
 
-import glob
-import os
 import sys
-import tempfile
 import time
 
 import trace_reduce
@@ -341,18 +338,6 @@ _RUN = {}  # traced sweep -> what for_run found (layer_reader re-executes a
 #            reader's file per metric; this module is imported once)
 
 
-def find_trace() -> str:
-    """The ``.xplane.pb`` of this process's traced sweep: ``run.py`` keeps
-    it under the newest ``bench-*/trace`` of the temporary directory until
-    the metrics have been read."""
-    dirs = sorted(glob.glob(os.path.join(tempfile.gettempdir(), "bench-*",
-                                         "trace")), key=os.path.getmtime)
-    if not dirs:
-        raise FileNotFoundError("no bench-*/trace under "
-                                + tempfile.gettempdir())
-    return trace_reduce.find_xplane(dirs[-1])
-
-
 def for_run(ctx, budget_s: float = 60.0, mark: str = "bench.mark") -> dict:
     """The reduction of this run's traced sweep, made once; ``{}`` where
     there is no traced sweep or its file cannot be read."""
@@ -368,7 +353,8 @@ def for_run(ctx, budget_s: float = 60.0, mark: str = "bench.mark") -> dict:
                                    f"{budget_s} s")
 
         try:
-            with open(find_trace(), "rb") as f:
+            with open(trace_reduce.find_xplane(ctx["trace_dir"]),
+                      "rb") as f:
                 planes = parse_xspace(f.read(), tick)
             tick()
             _RUN[sweep] = reduce_planes(

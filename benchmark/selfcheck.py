@@ -2,9 +2,10 @@
 
 The trace reduction is held to a hand-made profile whose answers are worked
 out below, and to the small trace recorded on the chip that is kept in
-``benchmark/selfcheck/`` with what it was read as when recorded; the two
-work-count functions are held to shapes worked by hand, and the generator's
-activity curve to its anchors. No chip, no program.
+``benchmark/selfcheck/`` with what it was read as when recorded; every
+configuration's schema resolves and exposes what ``run.SCHEMA`` lists, and runs
+the checks it brings (``selfchecks``: work counts held to shapes worked by
+hand, a generator's curve to its anchors). No chip, no program.
 """
 
 from __future__ import annotations
@@ -15,7 +16,6 @@ import sys
 from types import SimpleNamespace as NS
 
 import trace_reduce
-import work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SEQ = ["fixed", "per-user", "per-item"]
@@ -86,54 +86,17 @@ def check_recorded():
         n for n, _ in want["breakdown"]["device_ops"]]
 
 
-def check_work():
-    # 1,000 rows x 32 features, 4 iterations: 5 evaluations, X read twice
-    assert work.fe_pass_bytes(1000, 32, 4) == 5 * 2 * 1000 * 32 * 4 == 1280000
-    assert work.solve_evaluations("logistic", 8, 25) == 26
-    assert work.solve_evaluations("linear", 8, 25) == 9
-    try:
-        work.solve_evaluations("poisson", 8, 25)
-    except ValueError:
-        pass
-    else:
-        raise AssertionError("an unknown task was counted")
-    # three entities of 2, 5 and 9 rows under a cap of 5 train on 2 + 5 + 5
-    assert work.trained_rows([2, 5, 9], 5) == 12
-    assert work.trained_rows([2, 5, 9], None) == 16
-    # fixed: 5 evaluations x 4 x 1000 x 32 = 640,000, rescoring 64,000; a
-    # table training on all 1000 rows: 26 x 4 x 1000 x 8 = 832,000, one on
-    # 900 of them: 748,800; each rescoring all rows, 16,000
-    want = 640000 + 64000 + 832000 + 748800 + 2 * 16000
-    assert work.sweep_flops("logistic", 1000, 32, 4,
-                            [(8, 1000), (8, 900)], 25) == want
-    want = 640000 + 64000 + 9 * 4 * 8 * (1000 + 900) + 2 * 16000
-    assert work.sweep_flops("linear", 1000, 32, 4,
-                            [(8, 1000), (8, 900)], 25) == want
-
-
-def check_activity():
-    """The activity curve gives back the anchors it is laid through, sums to
-    the rows asked for, and leaves no entity without a row."""
-    import numpy as np
-    import gen
-    anchors = {"rows": 20000263, "min": 20, "q1": 35, "median": 68,
-               "q3": 155, "max": 9254}
-    full = gen.activity_counts(20000263, 138493, anchors)
-    assert full.sum() == 20000263 and np.all(np.diff(full) >= 0)
-    assert (full[0], full[-1]) == (20, 9254), (full[0], full[-1])
-    assert list(np.quantile(full, [0.25, 0.5, 0.75])) == [35, 68, 155]
-    half = gen.activity_counts(10000000, 138493, anchors)
-    assert half.sum() == 10000000 and (half[0], half[-1]) == (10, 4627)
-    # a long tail of one-row entities survives the cut with a row each
-    tail = gen.activity_counts(5000, 1000, dict(
-        anchors, rows=20000, min=1, q1=2, median=5, q3=15, max=900))
-    assert tail.sum() == 5000 and tail.min() == 1
-
-
-def main() -> int:
-    for check in (check_hand_made, check_work, check_activity,
-                  check_recorded):
+def main(schemas: dict) -> int:
+    """``schemas``: configuration name -> its schema module, which ``run.py``
+    has loaded and so held to its contract; the checks a schema brings
+    (``selfchecks``) run once."""
+    checks = [check_hand_made, check_recorded]
+    for mod in {m.__name__: m for m in schemas.values()}.values():
+        checks += getattr(mod, "selfchecks", ())
+    for check in checks:
         check()
         print(f"selfcheck {check.__name__}: ok", file=sys.stderr)
+    for conf, mod in schemas.items():
+        print(f"selfcheck {conf}: {mod.__name__} ok", file=sys.stderr)
     print(json.dumps({"selfcheck": "ok"}))
     return 0

@@ -54,7 +54,7 @@ def test_control_bfloat16_is_not_correct(run, capsys, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_unchanged_state_is_not_correct(run, capsys, cell):
-    with faults.planted("unchanged", run):
+    with faults.planted("unchanged", run, cell):
         out = result(run, capsys, cell)
     assert out["correct"] is False, out["compared"]
     assert out["compared"]["coef.per-user"]["value"] > 0.99
@@ -62,7 +62,7 @@ def test_unchanged_state_is_not_correct(run, capsys, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_half_the_batch_is_not_correct(run, capsys, cell):
-    with faults.planted("half-batch", run):
+    with faults.planted("half-batch", run, cell):
         out = result(run, capsys, cell)
     assert out["correct"] is False, out["compared"]
     assert out["compared"]["loss_1"]["value"] > 0.3
@@ -70,7 +70,7 @@ def test_half_the_batch_is_not_correct(run, capsys, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_stale_small_waves_are_not_correct(run, capsys, cell):
-    with faults.planted("stale-small-waves", run):
+    with faults.planted("stale-small-waves", run, cell):
         out = result(run, capsys, cell)
     assert out["correct"] is False, out["compared"]
     over = [k for k, v in out["compared"].items() if v["value"] > v["limit"]]
