@@ -21,6 +21,7 @@ from photon_ml_tpu.game.models import FixedEffectModel
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.obs.ledger import spill_history
 from photon_ml_tpu.ops.losses import PointwiseLoss
+from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          variances_from_diagonal)
@@ -28,6 +29,10 @@ from photon_ml_tpu.optim.regularization import intercept_mask
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple
 
 Array = jax.Array
+
+
+def _leaf_bytes(tree) -> int:
+    return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
 
 
 class SparseFixedEffectCoordinate:
@@ -50,7 +55,10 @@ class SparseFixedEffectCoordinate:
       cold-class layout of ops/hybrid_sparse.py — the Zipf head of the
       feature space rides the MXU as a dense block and the cold tail's
       random crossings shrink to ~15% of the volume (measured ~4-10× the
-      ELL step at d=1M on one v5e chip). Exact, not approximate: the
+      ELL step at d=1M and n=131072 on one v5e chip; at 2M rows, where
+      the block is sized from bytes and 30% of the non-zeros stay cold,
+      an evaluation takes 0.55 s against ~1.1 s for a plain ELL pass:
+      2×, PERF.md section 6, PR 29). Exact, not approximate: the
       solve happens in a statically permuted feature space and maps back.
       On a multi-data-shard mesh the rows split contiguously into
       per-shard hybrid layouts under one GLOBAL permutation
@@ -119,30 +127,52 @@ class SparseFixedEffectCoordinate:
             weights=np.asarray(dataset.weights),
             offsets=np.zeros(dataset.num_rows, np.float32),
             num_features=self._dim)
-        if self.hybrid:
-            import jax.numpy as _jnp
+        from jax.sharding import NamedSharding, PartitionSpec
 
+        # The warm start and, on one data shard, the offsets are placed on
+        # the mesh like the staged batch before every fit: an input whose
+        # sharding differs from the last call's is another program to the
+        # compiler, and the first sweeps hand over both kinds (zeros that
+        # sit on no mesh, then the other coordinates' scores and the last
+        # fit's own output, which sit on it).
+        self._replicated = NamedSharding(mesh, PartitionSpec())
+        if self.hybrid:
             from photon_ml_tpu.ops import hybrid_sparse as hybrid_mod
 
-            dt = (_jnp.bfloat16 if feature_dtype == "bfloat16"
-                  else _jnp.float32)
-            if self._hybrid_sharded:
-                shb = hybrid_mod.build_hybrid_shards(
-                    batch, mesh.shape[DATA_AXIS], feature_dtype=dt)
-                self._staged = sp.shard_hybrid(shb, mesh)
-            else:
-                self._staged = hybrid_mod.build_hybrid(
-                    batch, feature_dtype=dt)
+            dt = (jnp.bfloat16 if feature_dtype == "bfloat16"
+                  else jnp.float32)
+            with obs.phase("fe.host_stage"):
+                if self._hybrid_sharded:
+                    host = hybrid_mod.build_hybrid_shards(
+                        batch, mesh.shape[DATA_AXIS], feature_dtype=dt)
+                else:
+                    host = hybrid_mod.build_hybrid(
+                        batch, feature_dtype=dt, device=False)
+            with obs.phase("fe.transfer") as ph:
+                self._staged = (
+                    sp.shard_hybrid(host, mesh) if self._hybrid_sharded
+                    else jax.device_put(host, self._replicated))
+                ph["bytes"] = _leaf_bytes(self._staged)
             self._ii_perm = (
                 None if self.intercept_index is None else int(
-                    np.asarray(self._staged.inv_perm)[self.intercept_index]))
+                    np.asarray(host.inv_perm)[self.intercept_index]))
+            led = obs.ledger()
+            if led is not None:
+                led.record(
+                    "fe_layout", shard=shard_id, num_hot=host.num_hot,
+                    hot_bytes=int(host.X_hot.nbytes),
+                    hot_entries=host.entries[0],
+                    cold_entries=host.entries[1],
+                    cold_slots=sum(int(r.size) for r in host.cold_rowids))
         else:
             if self.feature_sharded:
                 from photon_ml_tpu.parallel.mesh import MODEL_AXIS
                 batch = sp._pad_features(
                     batch,
                     pad_to_multiple(self._dim, mesh.shape[MODEL_AXIS]))
-            self._staged = sp.shard_sparse_batch(batch, mesh)
+            with obs.phase("fe.transfer") as ph:
+                self._staged = sp.shard_sparse_batch(batch, mesh)
+                ph["bytes"] = _leaf_bytes(self._staged)
         self._build_fits()
 
     # -- jitted programs ---------------------------------------------------
@@ -173,6 +203,7 @@ class SparseFixedEffectCoordinate:
                 return w0
             return jnp.zeros((d_staged,), w0.dtype).at[:d_true].set(w0)
 
+        @scoped("fe.fit")
         def fit(staged, offsets, w0):
             batch = dataclasses.replace(
                 staged, offsets=self._padded_offsets(offsets))
@@ -180,11 +211,13 @@ class SparseFixedEffectCoordinate:
                                initial=Coefficients(lift(w0)),
                                intercept_index=ii,
                                feature_sharded=fs, already_sharded=True)
-            # Histories ride along for the run ledger's post-fit spill
-            # (tiny, device-resident, free when no ledger is active).
+            # Histories and the evaluation count ride along for the run
+            # ledger's post-fit spill (tiny, device-resident, free when no
+            # ledger is active).
             return (coef.means[:d_true], res.value_history,
-                    res.grad_norm_history)
+                    res.grad_norm_history, res.evaluations)
 
+        @scoped("fe.fit")
         def fit_sampled(staged, idx, mult, offsets, w0):
             sub = dataclasses.replace(
                 staged,
@@ -199,8 +232,9 @@ class SparseFixedEffectCoordinate:
                                intercept_index=ii,
                                feature_sharded=fs, already_sharded=True)
             return (coef.means[:d_true], res.value_history,
-                    res.grad_norm_history)
+                    res.grad_norm_history, res.evaluations)
 
+        @scoped("fe.score")
         def score_fn(staged, means):
             # Staged offsets are zeros, so margins == X @ w exactly.
             return sagg.margins(staged, means)
@@ -229,13 +263,16 @@ class SparseFixedEffectCoordinate:
             self._build_hybrid_sharded_fits(cfg, ii_perm)
             return
 
+        @scoped("fe.fit")
         def fit(hb, offsets, w0):
             hbo = dataclasses.replace(hb, offsets=jnp.asarray(offsets))
             coef, res = sp.run_hybrid(loss, hbo, cfg,
                                       initial=Coefficients(w0),
                                       intercept_index_permuted=ii_perm)
-            return coef.means, res.value_history, res.grad_norm_history
+            return (coef.means, res.value_history, res.grad_norm_history,
+                    res.evaluations)
 
+        @scoped("fe.fit")
         def fit_sampled(hb, idx, mult, offsets, w0):
             w_masked = jnp.zeros_like(hb.weights).at[idx].set(
                 hb.weights[idx] * mult)
@@ -244,8 +281,10 @@ class SparseFixedEffectCoordinate:
             coef, res = sp.run_hybrid(loss, hbo, cfg,
                                       initial=Coefficients(w0),
                                       intercept_index_permuted=ii_perm)
-            return coef.means, res.value_history, res.grad_norm_history
+            return (coef.means, res.value_history, res.grad_norm_history,
+                    res.evaluations)
 
+        @scoped("fe.score")
         def score_fn(hb, means):
             # Staged offsets are zeros, so margins == X @ w exactly.
             return hybrid_mod.margins(
@@ -286,13 +325,16 @@ class SparseFixedEffectCoordinate:
                     else self._padded_offsets(offsets))
             return flat.reshape(S, n_l)
 
+        @scoped("fe.fit")
         def fit(shb, offsets, w0):
             shbo = dataclasses.replace(shb, offsets=grid(offsets))
             coef, res = sp.run_hybrid_sharded(
                 loss, shbo, mesh, cfg, initial=Coefficients(w0),
                 intercept_index_permuted=ii_perm)
-            return coef.means, res.value_history, res.grad_norm_history
+            return (coef.means, res.value_history, res.grad_norm_history,
+                    res.evaluations)
 
+        @scoped("fe.fit")
         def fit_sampled(shb, idx, mult, offsets, w0):
             wf = shb.weights.reshape(-1)
             w_masked = jnp.zeros_like(wf).at[idx].set(
@@ -302,8 +344,10 @@ class SparseFixedEffectCoordinate:
             coef, res = sp.run_hybrid_sharded(
                 loss, shbo, mesh, cfg, initial=Coefficients(w0),
                 intercept_index_permuted=ii_perm)
-            return coef.means, res.value_history, res.grad_norm_history
+            return (coef.means, res.value_history, res.grad_norm_history,
+                    res.evaluations)
 
+        @scoped("fe.score")
         def score_fn(shb, means):
             # Staged offsets are zeros, so margins == X @ w exactly; rows
             # come back in flat padded global order.
@@ -346,24 +390,29 @@ class SparseFixedEffectCoordinate:
             w0 = jnp.asarray(initial.coefficients.means)
         else:
             w0 = jnp.zeros((self.dim,), jnp.float32)
+        w0 = jax.device_put(w0, self._replicated)
         offsets = jnp.asarray(offsets)
+        if self.mesh.shape[DATA_AXIS] == 1:
+            offsets = jax.device_put(offsets, self._replicated)
         rate = self.config.down_sampling_rate
-        if rate < 1.0:
-            idx, mult = draw_down_sample(self, rate)
-            w, vals, gns = self._fit_sampled(self._staged,
-                                             jnp.asarray(idx),
-                                             jnp.asarray(mult),
-                                             self._padded_offsets(offsets),
-                                             w0)
-        else:
-            w, vals, gns = self._fit(self._staged, offsets, w0)
+        with obs.annotated("fe.fit", cat="train"):
+            if rate < 1.0:
+                idx, mult = draw_down_sample(self, rate)
+                w, vals, gns, evals = self._fit_sampled(
+                    self._staged, jnp.asarray(idx), jnp.asarray(mult),
+                    self._padded_offsets(offsets), w0)
+            else:
+                w, vals, gns, evals = self._fit(self._staged, offsets, w0)
         led = obs.ledger()
         if led is not None:
             # Post-fit spill of the compiled histories (one host read,
-            # once per coordinate update) — docs/OBSERVABILITY.md.
+            # once per coordinate update) — docs/OBSERVABILITY.md. The
+            # update's evaluation count rides on the last row.
+            vals, gns, evals = jax.device_get((vals, gns, evals))
             spill_history(
-                led, np.asarray(vals), np.asarray(gns),
-                opt=self.config.optimizer.optimizer_type.value.lower())
+                led, vals, gns,
+                opt=self.config.optimizer.optimizer_type.value.lower(),
+                evaluations=int(evals))
         return FixedEffectModel(shard_id=self.shard_id,
                                 coefficients=Coefficients(w))
 
@@ -380,9 +429,10 @@ class SparseFixedEffectCoordinate:
                 "FULL variance needs the dense d×d Hessian — use SIMPLE at "
                 "sparse scale (as the reference does)")
         if self.hybrid:
-            diag = self._hess_diag(self._staged,
-                                   self._padded_offsets(offsets),
-                                   jnp.asarray(model.coefficients.means))
+            diag = self._hess_diag(
+                self._staged, self._padded_offsets(offsets),
+                jax.device_put(jnp.asarray(model.coefficients.means),
+                               self._replicated))
             var = variances_from_diagonal(
                 diag, self.config.regularization.l2_weight(),
                 jnp.asarray(intercept_mask(self.dim, self.intercept_index)))
@@ -412,7 +462,9 @@ class SparseFixedEffectCoordinate:
         if d_staged != self.dim:
             means = jnp.zeros((d_staged,), means.dtype
                               ).at[:self.dim].set(means)
-        return self._score(self._staged, means)[:n]
+        means = jax.device_put(means, self._replicated)
+        with obs.annotated("fe.score", cat="train"):
+            return self._score(self._staged, means)[:n]
 
     def initial_model(self) -> FixedEffectModel:
         return FixedEffectModel(

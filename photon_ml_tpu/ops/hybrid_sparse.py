@@ -5,7 +5,10 @@ Reference parity: the same ``ValueAndGradientAggregator`` /
 restructured around how a TPU actually moves data.
 
 Why: measured on one v5e chip, XLA's random 4M-element gather runs at
-~0.14 Gelem/s and its scatter-add at ~0.16 G-updates/s, and a Mosaic
+~0.14 Gelem/s and its scatter-add at ~0.16 G-updates/s (at a deployment's
+size the same: 33.4M cold slots cross twice an evaluation in 0.51 s, 0.13
+G slots/s, and a plain ELL pass over 97.5M entries takes 0.75 s for its
+gather and 0.67 s for its segment-sum; PERF.md section 6, PR 29), and a Mosaic
 (8, 128)-window vector shuffle tops out at ~0.84 Gelem/s — so ANY exact
 ELL step at d=1e6 pays two ~26 ms random crossings (expand w→entries,
 reduce entries→gradient) and lands near 60 ms regardless of formulation
@@ -15,7 +18,9 @@ see docs/PARITY.md "sparse wall" notes). The only real lever is moving
 fewer elements through the random path.
 
 CTR feature spaces are Zipf-distributed: on the benchmark's zipf(1.3)
-synthetic, the hottest ~1–2k of 1M columns carry ~85% of all nonzeros.
+synthetic, the hottest ~1–2k of 1M columns carry ~85% of all nonzeros
+(at 2M rows of 39 hashed click-log fields, where a quarter of a v5e's
+memory holds 512 columns, they carry 70%).
 The hybrid split exploits that:
 
 - **Hot columns** (count ≥ ``hot_threshold``, at most ``max_hot``) are
@@ -34,6 +39,10 @@ The hybrid split exploits that:
   * gradient: one gather r[rowids] (second crossing, same reduced
     volume), then padded row-sums per class and CONTIGUOUS writes into
     the permuted gradient — no scatter at all.
+
+The two halves carry the scopes ``fe.hot`` (the dense block's two passes)
+and ``fe.cold`` (the scatter-add of margins, the gather of the gradient)
+in a profiler trace (docs/OBSERVABILITY.md).
 
 Pad slots carry rowid == n (a zero sentinel lane) and value 0, so they
 are inert in every pass without masks. All layout arrays are static
@@ -70,7 +79,8 @@ class HybridSparseBatch:
 
     X_hot: Array  # (n, k) dense hot block (k may be 0)
     cold_rowids: tuple[Array, ...]  # per class: (C, L) int32, pad == n
-    cold_vals: tuple[Array, ...]  # per class: (C, L) f32, pad == 0
+    cold_vals: tuple[Array, ...]  # per class: (C, L) f32, pad == 0;
+    #                               both (L, C) where L < 128
     labels: Array  # (n,)
     weights: Array  # (n,)
     offsets: Array  # (n,)
@@ -81,6 +91,15 @@ class HybridSparseBatch:
     # Per class: first permuted column id (hot block excluded) and count.
     class_starts: tuple[int, ...] = dataclasses.field(
         metadata=dict(static=True))
+    # Per class: its slot count L. A class of L < 128 is held lane-major,
+    # (L, C), so that its minor dimension is the long one (see
+    # ``_class_block``).
+    class_lens: tuple[int, ...] = dataclasses.field(
+        metadata=dict(static=True))
+    # Live entries the hot block and the cold classes serve (the run
+    # ledger's ``fe_layout`` row).
+    entries: tuple[int, int] = dataclasses.field(
+        default=(0, 0), metadata=dict(static=True))
 
     @property
     def num_rows(self) -> int:
@@ -92,7 +111,102 @@ class HybridSparseBatch:
 
     @property
     def num_cold_present(self) -> int:
-        return sum(int(r.shape[0]) for r in self.cold_rowids)
+        return sum(int(r.size) // L
+                   for r, L in zip(self.cold_rowids, self.class_lens))
+
+
+# Bytes per element of each hot-block storage dtype. int8 (the streamed
+# path's) additionally carries one f32 scale per column (the symmetric-
+# quantization dequant vector), so the HBM plan charges it per column — at
+# streaming chunk_rows the 4 bytes per column are noise, but a plan that
+# ignores them would overshoot a tight budget on many-column/few-row configs.
+FEATURE_ITEMSIZE = {"float32": 4, "bfloat16": 2, "int8": 1}
+_SCALE_BYTES_PER_COLUMN = {"float32": 0, "bfloat16": 0, "int8": 4}
+# The resident hot block may take this share of one device's memory; where
+# the backend reports no limit (the CPU), a v5e chip's quarter.
+_HOT_SHARE_OF_DEVICE = 4
+_HOT_BYTES_UNKNOWN_DEVICE = 4 << 30
+_LANES = 128  # a wide block keeps whole lane tiles: the device pads the rest
+_HOT_BLOCK_ROWS = 1 << 17  # rows densified at a time on the host
+
+
+def feature_dtype_name(feature_dtype) -> str:
+    """Canonical name of a hot-block storage dtype spec (string, numpy/jax
+    dtype, or None = float32). Unknown dtypes raise — a silent f32
+    fallback would quietly quadruple a stream someone sized for int8."""
+    if feature_dtype is None:
+        return "float32"
+    if isinstance(feature_dtype, str):
+        name = feature_dtype.lower()
+    else:
+        try:
+            name = np.dtype(feature_dtype).name
+        except TypeError:
+            name = str(feature_dtype)
+    if name not in FEATURE_ITEMSIZE:
+        raise ValueError(
+            f"unsupported streaming feature_dtype {feature_dtype!r}; "
+            f"expected one of {sorted(FEATURE_ITEMSIZE)}")
+    return name
+
+
+def plan_num_hot(chunk_rows: int, hot_block_bytes: int,
+                 feature_dtype) -> int:
+    """Hot-block width that fits the byte budget: at scale the binding
+    constraint is HBM (block bytes = rows × H × itemsize, plus the
+    per-column scale under int8), not the throughput-optimal split of
+    ``_default_hot_threshold``. One planner for the streamed chunks
+    (ops/streaming_sparse.py) and the resident block (``plan_resident_hot``).
+    """
+    name = feature_dtype_name(feature_dtype)
+    per_column = (chunk_rows * FEATURE_ITEMSIZE[name]
+                  + _SCALE_BYTES_PER_COLUMN[name])
+    return max(8, int(hot_block_bytes) // per_column)
+
+
+def resident_hot_budget_bytes() -> int:
+    """Bytes one device gives the resident hot block: a quarter of the
+    memory the backend reports for it, so that the cold store, the other
+    coordinates' buckets and the optimizer's vectors keep the rest."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 0))
+    return (limit // _HOT_SHARE_OF_DEVICE if limit
+            else _HOT_BYTES_UNKNOWN_DEVICE)
+
+
+def plan_resident_hot(counts: np.ndarray, rows_per_device: int,
+                      feature_dtype=jnp.float32,
+                      hot_threshold: Optional[int] = None,
+                      max_hot: int = 4096,
+                      hot_block_bytes: Optional[int] = None) -> int:
+    """Columns the resident layouts densify: those of count ≥
+    ``hot_threshold`` (the throughput-optimal split, at most ``max_hot``),
+    as far as ``hot_block_bytes`` of one device hold them at
+    ``rows_per_device`` rows. Where the bytes bind, a block wider than a
+    lane tile keeps whole tiles."""
+    if hot_threshold is None:
+        hot_threshold = _default_hot_threshold(rows_per_device,
+                                               feature_dtype)
+    if hot_block_bytes is None:
+        hot_block_bytes = resident_hot_budget_bytes()
+    k = int(min(max_hot, (np.asarray(counts) >= hot_threshold).sum()))
+    fits = plan_num_hot(max(rows_per_device, 1), hot_block_bytes,
+                        feature_dtype)
+    if k > fits:
+        k = fits - fits % _LANES if fits > _LANES else fits
+    return k
+
+
+def _class_block(block: np.ndarray, L: int) -> np.ndarray:
+    """A cold class's (..., C, L) block as it is held. The device tiles the
+    two minor dimensions to (8, 128): a class of many columns with 1 to 64
+    slots each would be padded up to 128-fold in memory, and flattening it
+    costs the compiler minutes (88 s for the sixteen classes of a 2M-row
+    click log, 5 s without the eight narrow ones). Such a class is held
+    lane-major, (..., L, C): the long dimension is the minor one."""
+    if L >= _LANES:
+        return block
+    return np.ascontiguousarray(np.swapaxes(block, -1, -2))
 
 
 def _default_hot_threshold(n: int, feature_dtype) -> int:
@@ -107,6 +221,7 @@ def build_hybrid(
     max_hot: int = 4096,
     feature_dtype=jnp.float32,
     device: bool = True,
+    hot_block_bytes: Optional[int] = None,
 ) -> HybridSparseBatch:
     """Stage an ELL SparseBatch into the hybrid layout (host-side, once).
 
@@ -116,15 +231,16 @@ def build_hybrid(
     dominates, so the optimum sits at max(8, n/2048) (~1.8k hot columns,
     16.0 M samples/s vs 12.0 at n/4096); under bf16 the block streams at
     half the bytes and the optimum flattens across n/4096–n/8192 (~18.8 M
-    samples/s) — n/4096 is kept. ``max_hot`` caps the dense block's
-    memory (4096 f32 columns at n=131072 is ~2 GB HBM).
+    samples/s) — n/4096 is kept. That split was swept at n=131072, where
+    ``max_hot`` columns are ~2 GB; at a deployment's rows the block is
+    sized from BYTES (``plan_resident_hot``: ``hot_block_bytes``, by
+    default a quarter of the device's memory) and the columns past it stay
+    cold, so neither the host nor the device ever holds more than that.
     """
     indices = np.asarray(batch.indices)
     values = np.asarray(batch.values)
     n = indices.shape[0]
     d = int(batch.num_features)
-    if hot_threshold is None:
-        hot_threshold = _default_hot_threshold(n, feature_dtype)
 
     flat_col = indices.reshape(-1)
     flat_row = np.repeat(np.arange(n, dtype=np.int32),
@@ -135,19 +251,16 @@ def build_hybrid(
 
     # Permuted order: count-descending (stable → ties break on column id).
     order_desc = np.argsort(-counts, kind="stable").astype(np.int32)
-    num_hot = int(min(max_hot, (counts >= hot_threshold).sum()))
-    k = num_hot
+    k = plan_resident_hot(counts, n, feature_dtype, hot_threshold, max_hot,
+                          hot_block_bytes)
 
     inv_perm = np.empty(d, np.int32)
     inv_perm[order_desc] = np.arange(d, dtype=np.int32)
 
-    # Hot block: dense (n, k) via one scatter into the new column ids.
-    X_hot = np.zeros((n, max(k, 1)), np.float32)
-    new_col = inv_perm[np.minimum(flat_col, d - 1)]
-    hot_sel = live & (new_col < k)
-    if k:
-        X_hot[flat_row[hot_sel], new_col[hot_sel]] = flat_val[hot_sel]
-    X_hot = X_hot[:, :k]
+    # Permuted column of every entry; a dead one gets d, past every block.
+    new_col = np.where(live, inv_perm[np.minimum(flat_col, d - 1)], d)
+    X_hot = _dense_hot(new_col.reshape(indices.shape), values, k, n,
+                       feature_dtype)
 
     # Cold entries, column-contiguous in permuted order.
     cold_sel = live & (new_col >= k)
@@ -166,6 +279,7 @@ def build_hybrid(
     rowids_cls: list[np.ndarray] = []
     vals_cls: list[np.ndarray] = []
     class_starts: list[int] = []
+    class_lens: list[int] = []
     if present:
         # Counts are descending, so equal-class columns are contiguous and
         # padding is < 2x within each power-of-two class.
@@ -189,15 +303,11 @@ def build_hybrid(
             crow = np.repeat(np.arange(C, dtype=np.int64), cnts)
             rp[crow, colpos] = c_row[src]
             vp[crow, colpos] = c_val[src]
-            rowids_cls.append(rp)
-            vals_cls.append(vp)
+            rowids_cls.append(_class_block(rp, L))
+            vals_cls.append(_class_block(vp, L))
             class_starts.append(int(sel[0]))
+            class_lens.append(L)
 
-    if feature_dtype == jnp.bfloat16:
-        # Cast on host: halves the host→device transfer.
-        import ml_dtypes
-
-        X_hot = X_hot.astype(ml_dtypes.bfloat16)
     # device=False keeps the leaves as host numpy (a valid pytree): the
     # row-streaming path (ops/streaming_sparse.py) holds many chunks on
     # host and device_puts them per objective pass instead of pinning
@@ -215,7 +325,37 @@ def build_hybrid(
         num_features=d,
         num_hot=k,
         class_starts=tuple(class_starts),
+        class_lens=tuple(class_lens),
+        entries=(int(counts[order_desc[:k]].sum()), int(c_new.size)),
     )
+
+
+def _dense_hot(new_col: np.ndarray, values: np.ndarray, k: int,
+               rows_out: int, feature_dtype) -> np.ndarray:
+    """The (rows_out, k) dense block of the entries whose permuted column
+    is under ``k``, in its storage dtype (cast on the host: bf16 halves the
+    host→device transfer). Filled slot by slot, so two slots of one row
+    that meet in one column add up as they do on the cold side, and in row
+    blocks, so the host holds the block itself and one f32 slice of it."""
+    import ml_dtypes
+
+    n, slots = new_col.shape
+    dtype = (ml_dtypes.bfloat16 if feature_dtype == jnp.bfloat16
+             else np.float32)
+    X = np.zeros((rows_out, k), dtype)
+    if not k:
+        return X
+    in_place = dtype == np.float32
+    for a in range(0, n, _HOT_BLOCK_ROWS):
+        b = min(a + _HOT_BLOCK_ROWS, n)
+        blk = X[a:b] if in_place else np.zeros((b - a, k), np.float32)
+        for j in range(slots):
+            col = new_col[a:b, j]
+            sel = np.flatnonzero(col < k)
+            blk[sel, col[sel]] += values[a:b, j][sel]
+        if not in_place:
+            X[a:b] = blk
+    return X
 
 
 @jax.tree_util.register_dataclass
@@ -251,6 +391,10 @@ class HybridShards:
     num_hot: int = dataclasses.field(metadata=dict(static=True))
     class_starts: tuple[int, ...] = dataclasses.field(
         metadata=dict(static=True))
+    class_lens: tuple[int, ...] = dataclasses.field(
+        metadata=dict(static=True))
+    entries: tuple[int, int] = dataclasses.field(
+        default=(0, 0), metadata=dict(static=True))
 
     @property
     def num_shards(self) -> int:
@@ -286,7 +430,7 @@ def local_shard(shb: HybridShards, X_hot: Array,
         cold_vals=tuple(v[0] for v in cold_vals), labels=labels[0],
         weights=weights[0], offsets=offsets[0], perm=empty, inv_perm=empty,
         num_features=shb.num_features, num_hot=shb.num_hot,
-        class_starts=shb.class_starts)
+        class_starts=shb.class_starts, class_lens=shb.class_lens)
 
 
 def build_hybrid_shards(
@@ -295,6 +439,7 @@ def build_hybrid_shards(
     hot_threshold: Optional[int] = None,
     max_hot: int = 4096,
     feature_dtype=jnp.float32,
+    hot_block_bytes: Optional[int] = None,
 ) -> HybridShards:
     """Stage an ELL SparseBatch into S per-shard hybrid layouts (host-side,
     once). Same hot/cold policy as ``build_hybrid`` — global counts decide
@@ -317,17 +462,16 @@ def build_hybrid_shards(
     counts = np.bincount(flat_col[live], minlength=d)
 
     order_desc = np.argsort(-counts, kind="stable").astype(np.int32)
-    k = int(min(max_hot, int((counts >= hot_threshold).sum())))
+    # A device holds n_l rows of the block, so its bytes are planned there.
+    k = plan_resident_hot(counts, n_l, feature_dtype, hot_threshold,
+                          max_hot, hot_block_bytes)
     inv_perm = np.empty(d, np.int32)
     inv_perm[order_desc] = np.arange(d, dtype=np.int32)
 
-    # Hot blocks: one global dense scatter, then the contiguous row split.
-    X_hot = np.zeros((n_pad, max(k, 1)), np.float32)
-    new_col = inv_perm[np.minimum(flat_col, d - 1)]
-    hot_sel = live & (new_col < k)
-    if k:
-        X_hot[flat_row[hot_sel], new_col[hot_sel]] = flat_val[hot_sel]
-    X_hot = X_hot[:, :k].reshape(S, n_l, k)
+    # Hot blocks: one global dense block, then the contiguous row split.
+    new_col = np.where(live, inv_perm[np.minimum(flat_col, d - 1)], d)
+    X_hot = _dense_hot(new_col.reshape(indices.shape), values, k, n_pad,
+                       feature_dtype).reshape(S, n_l, k)
 
     # Cold entries keyed by (shard, permuted column).
     cold_sel = live & (new_col >= k)
@@ -343,6 +487,7 @@ def build_hybrid_shards(
     rowids_cls: list[np.ndarray] = []
     vals_cls: list[np.ndarray] = []
     class_starts: list[int] = []
+    class_lens: list[int] = []
     if present:
         key = c_shard * present + c_new
         order = np.argsort(key, kind="stable")
@@ -374,18 +519,15 @@ def build_hybrid_shards(
             co = c_new_s[e] - c0
             rp[sh, co, pos[e]] = loc_s[e]
             vp[sh, co, pos[e]] = val_s[e]
-            rowids_cls.append(rp)
-            vals_cls.append(vp)
+            rowids_cls.append(_class_block(rp, L))
+            vals_cls.append(_class_block(vp, L))
             class_starts.append(c0)
+            class_lens.append(L)
 
     def pad1(a):
         return np.concatenate(
             [np.asarray(a, np.float32), np.zeros(n_pad - n, np.float32)])
 
-    if feature_dtype == jnp.bfloat16:
-        import ml_dtypes
-
-        X_hot = X_hot.astype(ml_dtypes.bfloat16)
     # Leaves stay HOST numpy: materializing the global hot block on the
     # default device first would allocate the UNSHARDED array there (the
     # exact OOM this composition avoids) and transfer everything twice.
@@ -403,6 +545,8 @@ def build_hybrid_shards(
         num_features=d,
         num_hot=k,
         class_starts=tuple(class_starts),
+        class_lens=tuple(class_lens),
+        entries=(int(counts[order_desc[:k]].sum()), int(c_new.size)),
     )
 
 
@@ -442,11 +586,11 @@ def _cold_products(hb: HybridSparseBatch, w_perm: Array,
     each class's columns are one run of the permuted space.
     """
     parts = []
-    for start, rows, vals in zip(hb.class_starts, hb.cold_rowids,
-                                 cold_vals):
-        C = rows.shape[0]
+    for start, L, vals in zip(hb.class_starts, hb.class_lens, cold_vals):
+        C = vals.size // L
         w_c = w_perm[hb.num_hot + start: hb.num_hot + start + C]
-        parts.append((w_c[:, None] * vals).reshape(-1))
+        w_c = w_c[None, :] if L < _LANES else w_c[:, None]
+        parts.append((w_c * vals).reshape(-1))
     return jnp.concatenate(parts)
 
 
@@ -461,12 +605,14 @@ def margins(hb: HybridSparseBatch, w_perm: Array) -> Array:
     n = hb.labels.shape[0]
     z = hb.offsets
     if hb.num_hot:
-        z = z + _hot_matvec(hb.X_hot, w_perm[:hb.num_hot])
+        with jax.named_scope("fe.hot"):
+            z = z + _hot_matvec(hb.X_hot, w_perm[:hb.num_hot])
     if hb.cold_rowids:
-        prods = _cold_products(hb, w_perm, hb.cold_vals)
-        acc = jnp.zeros((n + 1,), jnp.float32).at[
-            _cold_flat_rowids(hb)].add(prods)
-        z = z + acc[:n]
+        with jax.named_scope("fe.cold"):
+            prods = _cold_products(hb, w_perm, hb.cold_vals)
+            acc = jnp.zeros((n + 1,), jnp.float32).at[
+                _cold_flat_rowids(hb)].add(prods)
+            z = z + acc[:n]
     return z
 
 
@@ -480,15 +626,15 @@ def _cold_grad(hb: HybridSparseBatch, r: Array,
     second random crossing), then padded row-sums and contiguous writes."""
     if not hb.cold_rowids:
         return []
-    r_pad = jnp.concatenate([r, jnp.zeros((1,), r.dtype)])
-    gathered = r_pad[_cold_flat_rowids(hb)]
-    out = []
-    off = 0
-    for rows, vals in zip(hb.cold_rowids, cold_vals):
-        C, L = rows.shape
-        ru = gathered[off: off + C * L].reshape(C, L)
-        out.append(jnp.sum(ru * vals, axis=1))
-        off += C * L
+    with jax.named_scope("fe.cold"):
+        r_pad = jnp.concatenate([r, jnp.zeros((1,), r.dtype)])
+        gathered = r_pad[_cold_flat_rowids(hb)]
+        out = []
+        off = 0
+        for L, vals in zip(hb.class_lens, cold_vals):
+            ru = gathered[off: off + vals.size].reshape(vals.shape)
+            out.append(jnp.sum(ru * vals, axis=0 if L < _LANES else 1))
+            off += vals.size
     return out
 
 
@@ -512,7 +658,8 @@ def _rowterm_gradient(hb: HybridSparseBatch, r: Array) -> Array:
     """Σ_i r_i·x_i in PERMUTED space: hot matvec + cold class sums."""
     g_hot = None
     if hb.num_hot:
-        g_hot = _hot_rmatvec(hb.X_hot, r)
+        with jax.named_scope("fe.hot"):
+            g_hot = _hot_rmatvec(hb.X_hot, r)
     return _assemble_grad(hb, g_hot, _cold_grad(hb, r, hb.cold_vals))
 
 

@@ -64,7 +64,10 @@ import numpy as np
 
 from photon_ml_tpu import faults as flt
 from photon_ml_tpu import obs
-from photon_ml_tpu.ops.hybrid_sparse import _hot_matvec, _hot_rmatvec
+# The hot block's dtype table and byte planner live with the resident
+# layout, which sizes its block through the same plan_num_hot.
+from photon_ml_tpu.ops.hybrid_sparse import (  # noqa: F401
+    _hot_matvec, _hot_rmatvec, feature_dtype_name, plan_num_hot)
 from photon_ml_tpu.ops.losses import PointwiseLoss
 
 Array = jax.Array
@@ -146,34 +149,7 @@ class ChunkedHybrid:
         return len(self.chunks)
 
 
-# Chunk-storage dtype → per-value payload bytes. int8 columns also carry
-# one f32 scale each (the symmetric-quantization dequant vector), so the
-# HBM plan charges it per column — at streaming chunk_rows the 4 bytes
-# per column are noise, but a plan that ignores them would overshoot a
-# tight budget on many-column/few-row configs.
-FEATURE_ITEMSIZE = {"float32": 4, "bfloat16": 2, "int8": 1}
-_SCALE_BYTES_PER_COLUMN = {"float32": 0, "bfloat16": 0, "int8": 4}
 INT8_QMAX = 127.0  # symmetric: codes span [-127, 127], zero-point 0
-
-
-def feature_dtype_name(feature_dtype) -> str:
-    """Canonical name of a chunk-storage dtype spec (string, numpy/jax
-    dtype, or None = float32). Unknown dtypes raise — a silent f32
-    fallback would quietly quadruple a stream someone sized for int8."""
-    if feature_dtype is None:
-        return "float32"
-    if isinstance(feature_dtype, str):
-        name = feature_dtype.lower()
-    else:
-        try:
-            name = np.dtype(feature_dtype).name
-        except TypeError:
-            name = str(feature_dtype)
-    if name not in FEATURE_ITEMSIZE:
-        raise ValueError(
-            f"unsupported streaming feature_dtype {feature_dtype!r}; "
-            f"expected one of {sorted(FEATURE_ITEMSIZE)}")
-    return name
 
 
 def chunk_dtype(ch: "CanonicalChunk") -> str:
@@ -183,18 +159,6 @@ def chunk_dtype(ch: "CanonicalChunk") -> str:
     if np.dtype(ch.X_hot.dtype) == np.dtype(jnp.bfloat16):
         return "bfloat16"
     return "float32"
-
-
-def plan_num_hot(chunk_rows: int, hot_block_bytes: int,
-                 feature_dtype) -> int:
-    """Hot-block width that fits the byte budget: at streaming scale the
-    binding constraint is HBM (block bytes = chunk_rows × H × itemsize,
-    plus the per-column scale under int8), not the throughput-optimal
-    split of hybrid_sparse."""
-    name = feature_dtype_name(feature_dtype)
-    per_column = (chunk_rows * FEATURE_ITEMSIZE[name]
-                  + _SCALE_BYTES_PER_COLUMN[name])
-    return max(8, int(hot_block_bytes) // per_column)
 
 
 def quantize_rows_int8(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:

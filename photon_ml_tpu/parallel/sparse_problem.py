@@ -24,6 +24,7 @@ from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim import (OptResult, l1_weights_vector, optimize,
                                  with_l2, with_l2_hvp)
+from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          resolve_optimizer_config,
@@ -91,8 +92,8 @@ def run_hybrid(
     reg = config.regularization
     l2 = reg.l2_weight()
 
-    vg = with_l2(
-        lambda w: hybrid.value_and_gradient(loss, w, hb), l2, mask)
+    vg = scoped("glm.value_grad", with_l2(
+        lambda w: hybrid.value_and_gradient(loss, w, hb), l2, mask))
     hvp = with_l2_hvp(
         lambda w, v: hybrid.hessian_vector(loss, w, v, hb), l2, mask)
 
@@ -170,8 +171,8 @@ def run_hybrid_sharded(
     reg = config.regularization
     l2 = reg.l2_weight()
 
-    vg = with_l2(
-        sobj_mod.make_hybrid_value_and_gradient(loss, mesh, shb), l2, mask)
+    vg = scoped("glm.value_grad", with_l2(
+        sobj_mod.make_hybrid_value_and_gradient(loss, mesh, shb), l2, mask))
     hvp = with_l2_hvp(
         sobj_mod.make_hybrid_hvp(loss, mesh, shb), l2, mask)
 
@@ -228,9 +229,9 @@ def run(
     reg = config.regularization
     l2 = reg.l2_weight()
 
-    vg = with_l2(
+    vg = scoped("glm.value_grad", with_l2(
         sobj.make_value_and_gradient(loss, mesh, batch, feature_sharded),
-        l2, mask)
+        l2, mask))
     hvp = with_l2_hvp(
         sobj.make_hvp(loss, mesh, batch, feature_sharded), l2, mask)
 

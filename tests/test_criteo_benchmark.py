@@ -1,0 +1,237 @@
+"""The new cell of ISSUE 29, benchmark side, on the CPU: the cell rehearsed
+through ``benchmark/run.py`` reads what it read when recorded, its control and
+faults read ``correct`` false, the selfcheck holds the new schema to the
+contract, the new readers read a hand-made trace, the guard refuses a program
+whose resident layout would not fit, and the benchmark's own test files
+(``benchmark/test_schema.py``, ``benchmark/test_check.py``) run with the
+tier-1 tests."""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
+BENCH = os.path.join(REPO, "benchmark")
+for _p in (BENCH, os.path.join(BENCH, "layer_metrics"),
+           os.path.join(BENCH, "schemas")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import faults  # noqa: E402  (benchmark/faults.py)
+import game_criteo  # noqa: E402  (benchmark/schemas/game_criteo.py)
+from record_scoped import field, plane  # noqa: E402  (its xplane encoder)
+
+CELL = "criteo-1m-logistic.steady"
+EXPECTED = os.path.join(BENCH, "selfcheck", "criteo.rehearsal.expected.json")
+NEW_METRICS = {"update_s.per-c10", "re_iters.per-c10", "lane_util.per-c10",
+               "pad_share.per-c10", "ls_evals.per-c10", "sparse_s.hot",
+               "sparse_s.cold", "hot_entry_share", "fe_hot_roofline",
+               "fe_cold_roofline"}
+
+
+@pytest.fixture(scope="module")
+def run():
+    return faults.load_run()
+
+
+def result(run, capsys, *extra):
+    with open(EXPECTED) as f:
+        argv = json.load(f)["argv"]
+    assert run.main([*argv, *extra]) == 0
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_the_rehearsal_reads_what_it_read(run, capsys):
+    with open(EXPECTED) as f:
+        want = json.load(f)
+    out = result(run, capsys)
+    assert out["correct"] is want["correct"] is True, out["compared"]
+    assert (out["attempted"], out["failed"]) == (want["attempted"], 0)
+    assert out["window"]["sweeps"] == want["window_sweeps"]
+    assert out["window"]["asked_in_window"] == 0  # ``setup_sweeps: 2`` holds
+    assert sorted(out["metrics"]) == want["metrics"] == ["setup_s", "sweep_s"]
+    assert out["compared"].keys() == want["compared"].keys()
+    for name, v in want["compared"].items():
+        assert out["compared"][name]["limit"] == v["limit"], name
+        assert out["compared"][name]["value"] == pytest.approx(
+            v["value"], rel=1e-6, abs=1e-12), name
+
+
+def test_control_bfloat16_is_not_correct(run, capsys):
+    out = result(run, capsys, "--control", "bfloat16")
+    assert out["correct"] is False, out["compared"]
+    # bf16 rounds 1/sqrt(39) up by 1.75e-4, which the first gradient reads
+    assert 1.5e-4 < out["compared"]["grad0"]["value"] < 2e-4
+
+
+def test_half_the_batch_is_not_correct(run, capsys):
+    with faults.planted("half-batch", run, CELL):
+        out = result(run, capsys)
+    assert out["correct"] is False, out["compared"]
+    assert out["compared"]["loss_1"]["value"] > 0.3
+    assert out["compared"]["grad0"]["value"] > 0.3
+
+
+def test_the_cold_part_left_out_of_the_gradient_is_not_correct(run, capsys):
+    with faults.planted("cold-dropped", run, CELL):
+        out = result(run, capsys)
+    assert out["correct"] is False, out["compared"]
+    over = {k for k, v in out["compared"].items() if v["value"] > v["limit"]}
+    # at 20,000 rows the cold part is 4% of the non-zeros (30% at the
+    # cell's size, where coef.fixed and loss_* read it too)
+    assert {"grad0", "small.fixed"} <= over, out["compared"]
+
+
+def test_the_selfcheck_holds_the_new_schema_to_the_contract(run, capsys):
+    assert run.main(["--selfcheck"]) == 0
+    err = capsys.readouterr().err
+    for line in ("glmix-criteo-1m-logistic: game_criteo ok",
+                 "glmix-ml20m-logistic: game_dense ok",
+                 "selfcheck check_generator: ok", "selfcheck check_work: ok"):
+        assert line in err, line
+
+
+def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
+    """One configuration, one cell, the cell's name appended to the lists
+    the issue names, ten new metrics; every reader is found by name."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert [c["name"] for c in bench["configs"]][-1] == (
+        "glmix-criteo-1m-logistic")
+    assert [w["name"] for w in bench["workloads"]] == [
+        "ml20m-logistic.steady", CELL]
+    cell = run.load_cell(CELL)
+    assert {m["name"] for m in cell["end_to_end"]} == {"sweep_s", "setup_s"}
+    mine = {m["name"] for m in cell["per_layer"]}
+    assert NEW_METRICS <= mine and len(mine) == 27
+    old = {m["name"] for m in run.load_cell("ml20m-logistic.steady")[
+        "per_layer"]}
+    assert len(old) == 27 and not old & NEW_METRICS
+    for m in cell["per_layer"]:
+        assert callable(run.layer_reader(m["name"])), m["name"]
+        assert m["workloads"][-1] == CELL
+    conf = cell["configuration"]
+    assert (conf["hashed_features"], conf["entity"]["count"],
+            conf["entity"]["features"], conf["nonzeros_per_row"]) == (
+        1 << 20, 93145, 14, 39)
+    assert len(conf["categorical_cardinalities"]) == 26
+    assert conf["categorical_cardinalities"][
+        conf["entity"]["categorical_index"]] == conf["entity"]["count"]
+    assert conf["num_rows"] == 1_000_000  # PERF.md section 4: the probe
+    assert cell["mix"]["setup_sweeps"] == 2
+    assert set(conf["check"]["limits"]) == {
+        "loss_1", "loss_2", "loss_3", "grad0", "coef.fixed", "coef.per-c10",
+        "small.fixed", "small.per-c10"}
+
+
+def test_the_guard_refuses_a_layout_that_does_not_fit(monkeypatch):
+    """The parent's column cap would allocate 4096 columns x the rows; the
+    guard reckons that from the program's planner and exits before anything
+    is allocated. With the byte planner the block fits."""
+    import jax
+
+    from photon_ml_tpu.ops import hybrid_sparse as hs
+
+    n = 2_500_000
+    counts = np.zeros(1 << 20, np.int64)
+    counts[:6000] = n
+    monkeypatch.setitem(game_criteo._MADE, "counts", counts)
+    monkeypatch.setitem(game_criteo._MADE, "rows", n)
+
+    class Chip:
+        def memory_stats(self):
+            return {"bytes_limit": 16 << 30}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()])
+    plan = game_criteo.resident_plan("float32")
+    assert plan["num_hot"] == 384 and plan["hot_bytes"] == 384 * n * 4
+    assert plan["hot_bytes"] <= (16 << 30) // 4
+    monkeypatch.delattr(hs, "plan_resident_hot")  # the parent's program
+    plan = game_criteo.resident_plan("float32")
+    assert plan["num_hot"] == 4096 and plan["hot_bytes"] > 40e9
+    with pytest.raises(SystemExit) as e:
+        game_criteo.estimator({"configuration": {"num_rows": n}}, None, 5,
+                              "unused", "float32")
+    assert "4096 columns" in str(e.value) and "cannot hold" in str(e.value)
+
+
+def hand_made_xspace():
+    """Times in ns. Markers: start 0, sweep 3's fixed 4000, per-c10 5000.
+    Device operations: h1 [100, 600) and h2 [500, 900) under fe.hot (union
+    800); c1 [1000, 2500) under fe.cold inside the line search; c2
+    [3000, 3400) under fe.cold under fe.score; x [3500, 3900) under neither;
+    h3 [5200, 5600) under fe.hot, after the window."""
+    vg = "jit(fit)/fe.fit/while/body/lbfgs.line_search/glm.value_grad/"
+    ops = {1: ("%fusion.h1 = f32[8]", vg + "fe.hot/dot_general:"),
+           2: ("%fusion.h2 = f32[8]", vg + "fe.hot/add:"),
+           3: ("%scatter.c1 = f32[9]", vg + "fe.cold/scatter-add:"),
+           4: ("%scatter.c2 = f32[9]", "jit(score_fn)/fe.score/fe.cold/"
+               "scatter-add:"),
+           5: ("%fusion.x = f32[8]", vg + "logistic:"),
+           6: ("%while.7 = (s32[])", None)}
+    k = 1000  # ns -> ps
+    device = plane("/device:TPU:0", [
+        ("XLA Ops", 0, [(1, 100 * k, 500 * k), (2, 500 * k, 400 * k),
+                        (6, 50 * k, 3000 * k), (3, 1000 * k, 1500 * k),
+                        (4, 3000 * k, 400 * k), (5, 3500 * k, 400 * k),
+                        (1, 5200 * k, 400 * k)])],
+        ops, event_stat=field(1, 9) + field(3, 5))
+    host = plane("/host:CPU", [("python3", 0, [
+        (1, 0, 1), (2, 4000 * k, 1), (3, 5000 * k, 1)])],
+        {1: ("bench.mark.start", None), 2: ("bench.mark.3.fixed", None),
+         3: ("bench.mark.3.per-c10", None)})
+    return field(1, host) + field(1, device)
+
+
+def test_the_new_readers_on_a_hand_made_trace(run, tmp_path):
+    os.makedirs(tmp_path / "plugins" / "profile" / "x")
+    with open(tmp_path / "plugins" / "profile" / "x" / "t.xplane.pb",
+              "wb") as f:
+        f.write(hand_made_xspace())
+    cell = run.load_cell(CELL)
+    rows = [{"kind": "fe_layout", "hot_entries": 3000, "cold_entries": 1000},
+            {"kind": "opt_iter", "coordinate": "fixed", "outer_iteration": 3,
+             "iteration": 0},
+            {"kind": "opt_iter", "coordinate": "fixed", "outer_iteration": 3,
+             "iteration": 2, "evaluations": 4}]
+    ctx = {"cell": cell, "traced_sweep": 3, "trace": {"window_s": 5e-6},
+           "trace_dir": str(tmp_path), "ledger_rows": rows,
+           "schema": game_criteo, "peak": {"hbm_bytes_per_s": 819e9}}
+    read = {m: run.layer_reader(m)(m, ctx) for m in (
+        "sparse_s.hot", "sparse_s.cold", "hot_entry_share",
+        "fe_hot_roofline", "fe_cold_roofline")}
+    assert read["sparse_s.hot"] == pytest.approx(800e-9)
+    assert read["sparse_s.cold"] == pytest.approx(1900e-9)
+    assert read["hot_entry_share"] == 75.0
+    # 4 evaluations x 2 passes x non-zeros x 8 B over 819 GB/s over seconds
+    assert read["fe_hot_roofline"] == pytest.approx(
+        100 * 4 * 2 * 3000 * 8 / 819e9 / 800e-9)
+    assert read["fe_cold_roofline"] == pytest.approx(
+        100 * 4 * 2 * 1000 * 8 / 819e9 / 1900e-9)
+    # a program that writes neither the scopes nor the row: nothing, no raise
+    bare = dict(ctx, trace=None, ledger_rows=rows[1:])
+    bare.pop("_sparse_s", None)
+    for m in read:
+        assert run.layer_reader(m)(m, bare) is None, m
+
+
+@pytest.mark.parametrize("name", ["test_schema.py", "test_check.py"])
+def test_the_benchmark_s_own_tests_pass(name):
+    """``benchmark/test_check.py``'s fifth case was broken unseen from PR 26
+    to PR 28 because the tier-1 command never ran it."""
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", os.path.join("benchmark", name),
+         "-q", "-p", "no:cacheprovider", "-p", "no:xdist", "-p",
+         "no:randomly"],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=1200)
+    tail = (p.stdout + p.stderr)[-3000:]
+    assert p.returncode == 0, tail
+    dots = p.stdout.strip().splitlines()[0].split()[0]
+    assert len(dots) >= 5 and set(dots) == {"."}, tail

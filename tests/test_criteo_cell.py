@@ -1,0 +1,346 @@
+"""The deployment of ISSUE 29, program side, at a size the CPU holds: the
+resident sparse fixed effect's hot block is sized from bytes by the planner
+the streamed path uses, any hot block gives the same model, the new scopes,
+counters and ledger rows are there, nothing is traced again after the set-up
+sweeps, and the program agrees with the schema's plain reference
+(``benchmark/criteo_reference.py``) on seeded data."""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from photon_ml_tpu import obs
+from photon_ml_tpu.data import sparse as sp_data
+from photon_ml_tpu.data.sparse import SparseBatch
+from photon_ml_tpu.game.coordinates import SparseFixedEffectCoordinate
+from photon_ml_tpu.obs.ledger import read_rows
+from photon_ml_tpu.ops import hybrid_sparse as hs
+from photon_ml_tpu.ops import losses
+from photon_ml_tpu.ops import streaming_sparse as ss
+from photon_ml_tpu.optim import OptimizerConfig
+from photon_ml_tpu.optim.problem import GLMOptimizationConfiguration
+from photon_ml_tpu.optim.regularization import (RegularizationContext,
+                                                RegularizationType)
+from photon_ml_tpu.parallel import sparse_problem as sp
+from photon_ml_tpu.parallel.mesh import make_mesh
+from photon_ml_tpu.utils import events
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
+BENCH = os.path.join(REPO, "benchmark")
+for _p in (BENCH, os.path.join(BENCH, "layer_metrics"),
+           os.path.join(BENCH, "schemas")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import criteo_reference  # noqa: E402  (benchmark/criteo_reference.py)
+import game_criteo  # noqa: E402  (benchmark/schemas/game_criteo.py)
+
+CELL = "criteo-1m-logistic.steady"
+SEQ = ["fixed", "per-c10"]
+
+
+@pytest.fixture(autouse=True)
+def _clean_obs_state():
+    yield
+    obs.set_ledger(None)
+    obs.disable()
+
+
+def load(*parts):
+    with open(os.path.join(BENCH, *parts)) as f:
+        return json.load(f)
+
+
+def small_cell(rows: int, d: int, max_iterations: int = 100) -> dict:
+    """The new cell's files at ``rows`` rows and ``d`` hashed columns."""
+    conf = game_criteo.shrink(
+        load("configs", "glmix-criteo-1m-logistic.json"), rows)
+    settings = load("workloads", CELL + ".json")
+    settings["optimizer"]["max_iterations"] = max_iterations
+    return {"configuration": dict(conf, hashed_features=d),
+            "mix": load("traffic", "steady-fixed-c10.json"),
+            "settings": settings}
+
+
+def fit(cell, data, sweeps, ledger_dir, on_update=None):
+    est = game_criteo.estimator(
+        cell, make_mesh(devices=jax.devices()[:1]), sweeps, str(ledger_dir),
+        "float32")
+    if on_update is not None:
+        events.default_emitter.register(on_update)
+    try:
+        model = est.fit(game_criteo.dataset(data))[0].model
+    finally:
+        if on_update is not None:
+            events.default_emitter.unregister(on_update)
+    return model, read_rows(str(ledger_dir))[0]
+
+
+def _opt(max_iterations=200):
+    return GLMOptimizationConfiguration(
+        optimizer=OptimizerConfig(max_iterations=max_iterations,
+                                  tolerance=1e-9),
+        regularization=RegularizationContext(RegularizationType.L2, 1.0))
+
+
+def _batch(data) -> SparseBatch:
+    n = data.response.shape[0]
+    return SparseBatch(indices=data.indices, values=data.values,
+                       labels=data.response, weights=np.ones(n, np.float32),
+                       offsets=np.zeros(n, np.float32),
+                       num_features=data.num_features)
+
+
+# -- the byte-sized hot block -------------------------------------------------
+
+@pytest.mark.parametrize("k", [8, 64, 128, 384])
+def test_a_budget_that_admits_k_columns_yields_k(k):
+    rows = 10_000
+    counts = np.zeros(4096, np.int64)
+    counts[:1000] = rows  # a thousand columns clear any threshold
+    budget = 4 * rows * k + 4 * rows - 1  # k whole columns, not k + 1
+    assert hs.plan_resident_hot(counts, rows, jnp.float32,
+                                hot_block_bytes=budget) == k
+    assert hs.plan_resident_hot(counts, rows, jnp.bfloat16,
+                                hot_block_bytes=budget // 2) == k
+    # the streamed path's planner is the same function
+    assert ss.plan_num_hot is hs.plan_num_hot
+    assert hs.plan_num_hot(rows, budget, "float32") == k
+
+
+def test_where_the_bytes_bind_a_wide_block_keeps_whole_lane_tiles():
+    rows = 2_500_000
+    counts = np.full(1 << 20, rows, np.int64)
+    k = hs.plan_resident_hot(counts, rows, jnp.float32,
+                             hot_block_bytes=4 << 30)
+    assert k == 384 and k * rows * 4 <= 4 << 30  # 429 fit, 3 tiles kept
+    # the old cap alone would have asked for 4096 columns: 41 GB
+    assert min(4096, int((counts >= rows // 2048).sum())) * rows * 4 > 40e9
+
+
+def test_the_split_at_the_old_size_is_what_it_was():
+    """n = 131,072, the size the hot threshold was swept at: the columns of
+    count >= n/2048 (n/4096 under bf16), at most 4096, whatever the bytes."""
+    n = 131072
+    rng = np.random.default_rng(0)
+    counts = np.bincount(rng.zipf(1.3, size=n * 39) % (1 << 20),
+                         minlength=1 << 20)
+    for dt, div in ((jnp.float32, 2048), (jnp.bfloat16, 4096)):
+        want = min(4096, int((counts >= n // div).sum()))
+        assert hs.plan_resident_hot(counts, n, dt) == want, dt
+    many = np.full(1 << 20, n, np.int64)  # every column hot: the cap binds
+    assert hs.plan_resident_hot(many, n, jnp.float32) == 4096
+
+
+def _dense(hb) -> np.ndarray:
+    """The (n, d) matrix a hybrid layout holds, in the original columns."""
+    n, d = int(hb.labels.shape[0]), hb.num_features
+    out = np.zeros((n + 1, d), np.float64)
+    out[:n, :hb.num_hot] = np.asarray(hb.X_hot, np.float64)
+    for start, L, rows, vals in zip(hb.class_starts, hb.class_lens,
+                                    hb.cold_rowids, hb.cold_vals):
+        rows, vals = np.asarray(rows), np.asarray(vals, np.float64)
+        if L < 128:  # a narrow class is held lane-major, (L, C)
+            assert rows.shape[0] == L
+            rows, vals = rows.T, vals.T
+        cols = hb.num_hot + start + np.arange(rows.shape[0])
+        np.add.at(out, (rows, cols[:, None].repeat(rows.shape[1], 1)), vals)
+    return out[:n][:, np.asarray(hb.inv_perm)]
+
+
+def test_every_number_of_the_layout_is_what_it_was():
+    """At a small size the layout holds what the old column-capped build
+    held: the columns of count >= the threshold are hot, in count order, and
+    hot block and cold classes together are the batch's matrix."""
+    batch, _ = sp_data.synthetic_sparse(4096, 512, 8, seed=3)
+    hb = hs.build_hybrid(batch)
+    idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+    live = (idx < 512) & (val != 0)
+    counts = np.bincount(idx[live], minlength=512)
+    assert hb.num_hot == int((counts >= 8).sum())  # max(8, 4096 // 2048)
+    order = np.argsort(-counts, kind="stable")
+    assert np.array_equal(np.asarray(hb.perm), order)
+    want = np.zeros((4096, 513))
+    np.add.at(want, (np.arange(4096)[:, None].repeat(idx.shape[1], 1), idx),
+              np.where(live, val, 0.0))
+    np.testing.assert_array_equal(_dense(hb), want[:, :512])
+    assert hb.entries == (int(counts[order[:hb.num_hot]].sum()),
+                          int(live.sum() - counts[order[:hb.num_hot]].sum()))
+
+
+def test_two_slots_of_a_row_that_meet_in_a_hot_column_add_up():
+    idx = np.array([[0, 0, 1], [0, 2, 2], [1, 0, 3]], np.int32)
+    val = np.array([[1, 2, 4], [8, 16, 32], [64, 128, 256]], np.float32)
+    batch = SparseBatch(indices=idx, values=val,
+                        labels=np.zeros(3, np.float32),
+                        weights=np.ones(3, np.float32),
+                        offsets=np.zeros(3, np.float32), num_features=4)
+    want = np.array([[3, 4, 0, 0], [8, 0, 48, 0], [128, 64, 0, 256.]])
+    for max_hot in (0, 2, 4):
+        hb = hs.build_hybrid(batch, hot_threshold=1, max_hot=max_hot)
+        assert hb.num_hot == max_hot
+        np.testing.assert_array_equal(_dense(hb), want)
+    shb = hs.build_hybrid_shards(batch, 1, hot_threshold=1, max_hot=4)
+    np.testing.assert_array_equal(
+        np.asarray(shb.X_hot[0])[:, np.asarray(shb.inv_perm)], want)
+
+
+@pytest.mark.parametrize("d", [4096, 1 << 20])
+def test_any_hot_block_gives_the_same_model(d):
+    """Hot block of 0, 8 and every present column (at d = 2**20, where that
+    would be a hundred thousand columns, the default split): the split is an
+    execution choice that leaves the objective as it is."""
+    cell = small_cell(3000, d)
+    data = game_criteo.make(20260929, cell["configuration"])
+    batch = _batch(data)
+    everything = dict(hot_threshold=1, max_hot=d) if d == 4096 else {}
+    models = []
+    for kw in (dict(max_hot=0), dict(max_hot=8), everything):
+        hb = hs.build_hybrid(batch, hot_block_bytes=1 << 40, **kw)
+        coef, res = jax.jit(
+            lambda hb: sp.run_hybrid(losses.LOGISTIC, hb, _opt()))(hb)
+        models.append(np.asarray(coef.means))
+        assert sum(hb.entries) == 3000 * 39
+    assert hs.build_hybrid(batch, max_hot=0).num_hot == 0
+    present = np.unique(data.indices).size
+    if d == 4096:
+        assert hb.num_hot == present and not hb.cold_rowids
+    for m in models[1:]:  # to the solver's stopping slack in float32
+        assert np.linalg.norm(m - models[0]) < 1e-3 * np.linalg.norm(
+            models[0])
+        np.testing.assert_allclose(m, models[0], rtol=0, atol=2e-3)
+
+
+# -- the inside view: scopes, counters, rows -----------------------------------
+
+@pytest.fixture(scope="module")
+def run(tmp_path_factory):
+    """One fit of the new cell's files at 4,000 rows and d = 2**20, five
+    sweeps, with a ledger; the compile requests and traces of each update."""
+    cell = small_cell(4000, 1 << 20, max_iterations=25)
+    data = game_criteo.make(1234567891, cell["configuration"])
+    counts = {"requests": 0, "traces": 0}
+
+    def on_event(event, **kw):
+        if event.endswith("/compile_requests_use_cache"):
+            counts["requests"] += 1
+
+    def on_duration(event, duration_secs, **kw):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            counts["traces"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    per_update, last = [], dict(counts)
+
+    def on_update(ev):
+        if isinstance(ev, events.CoordinateUpdate):
+            per_update.append((ev.iteration, ev.coordinate,
+                               {k: counts[k] - last[k] for k in counts}))
+            last.update(counts)
+
+    model, rows = fit(cell, data, 5, tmp_path_factory.mktemp("ledger"),
+                      on_update)
+    obs.set_ledger(None)
+    return {"cell": cell, "data": data, "model": model, "rows": rows,
+            "per_update": per_update}
+
+
+def test_nothing_is_traced_after_the_set_up_sweeps(run):
+    """``setup_sweeps: 2``: from the third sweep on no update asks the
+    compiler for anything (the sparse fit used to be traced once more in
+    sweep 3, because its warm start arrived with another sharding)."""
+    assert [(i, c) for i, c, _ in run["per_update"]] == [
+        (i, c) for i in range(5) for c in SEQ]
+    late = [(i, c, n) for i, c, n in run["per_update"]
+            if i >= 2 and (n["requests"] or n["traces"])]
+    assert not late, late
+    # and the fit program itself is traced once, in the first sweep
+    assert [n["requests"] for i, c, n in run["per_update"]
+            if c == "fixed"][1:] == [0, 0, 0, 0]
+
+
+def test_the_ledger_has_the_layout_the_phases_and_the_evaluations(run):
+    rows = run["rows"]
+    layout = [r for r in rows if r.get("kind") == "fe_layout"]
+    assert len(layout) == 1
+    lay = layout[0]
+    assert lay["hot_entries"] + lay["cold_entries"] == 4000 * 39
+    assert lay["hot_bytes"] == lay["num_hot"] * 4000 * 4
+    assert lay["cold_slots"] >= lay["cold_entries"] > 0 < lay["num_hot"]
+    phases = {r["name"]: r for r in rows if r.get("kind") == "phase"}
+    assert phases["fe.transfer"]["bytes"] > lay["hot_bytes"]
+    assert phases["fe.host_stage"]["parent"] == "fit.coordinates"
+    for sweep in range(5):
+        its = [r for r in rows if r.get("kind") == "opt_iter"
+               and r.get("coordinate") == "fixed"
+               and r.get("outer_iteration") == sweep]
+        assert [r["iteration"] for r in its] == list(range(len(its)))
+        assert [r for r in its if "evaluations" in r] == its[-1:]
+        assert its[-1]["evaluations"] >= its[-1]["iteration"] + 1
+
+
+def test_the_sparse_fit_lowers_with_every_scope(run):
+    data = run["data"]
+    coord = SparseFixedEffectCoordinate(
+        game_criteo.dataset(data), "global", losses.LOGISTIC, _opt(5),
+        make_mesh(devices=jax.devices()[:1]))
+    n = data.response.shape[0]
+    text = coord._fit.lower(coord._staged, jnp.zeros((n,), jnp.float32),
+                            jnp.zeros((coord.dim,), jnp.float32)
+                            ).as_text(debug_info=True)
+    for scope in ("fe.fit", "glm.value_grad", "fe.hot", "fe.cold",
+                  "lbfgs.line_search", "lbfgs.direction"):
+        assert scope in text, scope
+    text = coord._score.lower(coord._staged,
+                              jnp.zeros((coord.dim,), jnp.float32)
+                              ).as_text(debug_info=True)
+    for scope in ("fe.score", "fe.hot", "fe.cold"):
+        assert scope in text, scope
+    assert "glm.value_grad" not in text
+
+
+# -- the program against the plain reference -----------------------------------
+
+def compared(cell, data, model, rows, sweeps):
+    served = game_criteo.model_arrays(model, cell["mix"])
+    return {k: v["value"] for k, v in criteo_reference.check(
+        data, cell, served, rows, sweeps).items()}
+
+
+def test_the_program_agrees_with_the_reference_at_the_full_width(run):
+    got = compared(run["cell"], run["data"], run["model"], run["rows"], 5)
+    assert set(got) == {"loss_1", "loss_2", "loss_3", "grad0", "coef.fixed",
+                        "coef.per-c10", "small.fixed", "small.per-c10"}
+    assert max(got["loss_1"], got["loss_2"], got["loss_3"]) < 1e-3, got
+    assert got["grad0"] < 1e-4, got
+    assert got["coef.fixed"] < 2e-2 and got["coef.per-c10"] < 2e-2, got
+    assert got["small.fixed"] < 3e-4 and got["small.per-c10"] < 3e-4, got
+
+
+def test_the_program_agrees_with_the_reference_at_4096_columns(tmp_path):
+    cell = small_cell(4000, 4096)
+    data = game_criteo.make(987654321, cell["configuration"])
+    model, rows = fit(cell, data, 4, tmp_path / "ledger")
+    got = compared(cell, data, model, rows, 4)
+    assert max(got["loss_1"], got["loss_2"], got["loss_3"]) < 1e-3, got
+    assert got["coef.fixed"] < 2e-2 and got["coef.per-c10"] < 2e-2, got
+    assert got["small.fixed"] < 3e-4 and got["small.per-c10"] < 3e-4, got
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    import ast
+    for name in ("criteo_reference.py", "reference.py"):
+        with open(os.path.join(BENCH, name)) as f:
+            tree = ast.parse(f.read())
+        names = {a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+                 for a in n.names} | {
+            n.module for n in ast.walk(tree) if isinstance(n, ast.ImportFrom)}
+        assert not any(m and m.startswith("photon_ml_tpu") for m in names)
