@@ -152,26 +152,27 @@ def dataset(data: Data):
 
 # -- the estimator, behind its guard ------------------------------------------
 
-def resident_plan(feature_dtype: str) -> dict:
+def resident_plan(mesh, feature_dtype: str) -> dict:
     """What the program's resident layout would allocate for the fixed
     effect of the data made last, reckoned from the program's own planner
-    before anything is allocated: the hot block's columns and bytes. A
-    program without the byte planner is asked through ``build_hybrid``'s own
-    count threshold and column cap."""
-    import jax
+    before anything is allocated: the hot block's columns and bytes on one
+    device of ``mesh``. A program whose coordinate derives no byte budget
+    is asked through ``build_hybrid``'s own count threshold and column cap."""
     import jax.numpy as jnp
 
+    from photon_ml_tpu.game.coordinates import sparse_fixed
     from photon_ml_tpu.ops import hybrid_sparse as hs
 
     counts, n = _MADE["counts"], _MADE["rows"]
     dt = jnp.bfloat16 if feature_dtype == "bfloat16" else jnp.float32
-    if hasattr(hs, "plan_resident_hot"):
-        k = hs.plan_resident_hot(counts, n, dt)
+    if hasattr(sparse_fixed, "hot_block_budget"):
+        k = hs.plan_resident_hot(
+            counts, n, dt, hot_block_bytes=sparse_fixed.hot_block_budget(mesh))
     else:
         cap = inspect.signature(hs.build_hybrid).parameters["max_hot"].default
         k = min(int(cap), int(
             (counts >= hs._default_hot_threshold(n, dt)).sum()))
-    stats = jax.local_devices()[0].memory_stats() or {}
+    stats = mesh.devices.flat[0].memory_stats() or {}
     return {"num_hot": int(k),
             "hot_bytes": int(k) * n * (2 if dt == jnp.bfloat16 else 4),
             "device_bytes": int(stats.get("bytes_limit", 0))}
@@ -182,7 +183,7 @@ def estimator(cell: dict, mesh, sweeps: int, ledger_dir: str,
     """``game_dense``'s estimator, after the guard: where the hot block the
     program would allocate is larger than the device, exit with a plain
     message before the host or the device holds any of it."""
-    plan = resident_plan(feature_dtype)
+    plan = resident_plan(mesh, feature_dtype)
     if plan["device_bytes"] and plan["hot_bytes"] > plan["device_bytes"]:
         raise SystemExit(
             f"game_criteo: this program's resident sparse layout would "

@@ -35,6 +35,22 @@ def _leaf_bytes(tree) -> int:
     return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
 
 
+# The share of one device's memory the resident hot block may take. A
+# placeholder: no sweep of the share against a sweep's seconds backs it
+# (PERF.md section 7 row 15); a quarter leaves the cold classes, the other
+# coordinates' buckets and the optimizer's vectors three.
+_HOT_SHARE_OF_DEVICE = 4
+
+
+def hot_block_budget(mesh) -> Optional[int]:
+    """Bytes the resident hot block may take on one device of ``mesh``;
+    None where the backend reports no limit (the CPU), and then the column
+    counts alone size the block."""
+    stats = mesh.devices.flat[0].memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 0))
+    return limit // _HOT_SHARE_OF_DEVICE if limit else None
+
+
 class SparseFixedEffectCoordinate:
     """Fixed-effect GLM over an ELL sparse shard (the Criteo path).
 
@@ -141,13 +157,16 @@ class SparseFixedEffectCoordinate:
 
             dt = (jnp.bfloat16 if feature_dtype == "bfloat16"
                   else jnp.float32)
+            budget = hot_block_budget(mesh)
             with obs.phase("fe.host_stage"):
                 if self._hybrid_sharded:
                     host = hybrid_mod.build_hybrid_shards(
-                        batch, mesh.shape[DATA_AXIS], feature_dtype=dt)
+                        batch, mesh.shape[DATA_AXIS], feature_dtype=dt,
+                        hot_block_bytes=budget)
                 else:
                     host = hybrid_mod.build_hybrid(
-                        batch, feature_dtype=dt, device=False)
+                        batch, feature_dtype=dt, device=False,
+                        hot_block_bytes=budget)
             with obs.phase("fe.transfer") as ph:
                 self._staged = (
                     sp.shard_hybrid(host, mesh) if self._hybrid_sharded

@@ -122,10 +122,6 @@ class HybridSparseBatch:
 # ignores them would overshoot a tight budget on many-column/few-row configs.
 FEATURE_ITEMSIZE = {"float32": 4, "bfloat16": 2, "int8": 1}
 _SCALE_BYTES_PER_COLUMN = {"float32": 0, "bfloat16": 0, "int8": 4}
-# The resident hot block may take this share of one device's memory; where
-# the backend reports no limit (the CPU), a v5e chip's quarter.
-_HOT_SHARE_OF_DEVICE = 4
-_HOT_BYTES_UNKNOWN_DEVICE = 4 << 30
 _LANES = 128  # a wide block keeps whole lane tiles: the device pads the rest
 _HOT_BLOCK_ROWS = 1 << 17  # rows densified at a time on the host
 
@@ -164,32 +160,25 @@ def plan_num_hot(chunk_rows: int, hot_block_bytes: int,
     return max(8, int(hot_block_bytes) // per_column)
 
 
-def resident_hot_budget_bytes() -> int:
-    """Bytes one device gives the resident hot block: a quarter of the
-    memory the backend reports for it, so that the cold store, the other
-    coordinates' buckets and the optimizer's vectors keep the rest."""
-    stats = jax.local_devices()[0].memory_stats() or {}
-    limit = int(stats.get("bytes_limit", 0))
-    return (limit // _HOT_SHARE_OF_DEVICE if limit
-            else _HOT_BYTES_UNKNOWN_DEVICE)
-
-
 def plan_resident_hot(counts: np.ndarray, rows_per_device: int,
                       feature_dtype=jnp.float32,
                       hot_threshold: Optional[int] = None,
                       max_hot: int = 4096,
                       hot_block_bytes: Optional[int] = None) -> int:
     """Columns the resident layouts densify: those of count ≥
-    ``hot_threshold`` (the throughput-optimal split, at most ``max_hot``),
-    as far as ``hot_block_bytes`` of one device hold them at
-    ``rows_per_device`` rows. Where the bytes bind, a block wider than a
-    lane tile keeps whole tiles."""
+    ``hot_threshold`` (the throughput-optimal split, at most ``max_hot``,
+    which is what that split was swept under), as far as
+    ``hot_block_bytes`` of one device hold them at ``rows_per_device``
+    rows. The caller that owns the device gives the bytes
+    (game/coordinates/sparse_fixed.py); without them only the two counts
+    decide. Where the bytes bind, a block wider than a lane tile keeps
+    whole tiles."""
     if hot_threshold is None:
         hot_threshold = _default_hot_threshold(rows_per_device,
                                                feature_dtype)
-    if hot_block_bytes is None:
-        hot_block_bytes = resident_hot_budget_bytes()
     k = int(min(max_hot, (np.asarray(counts) >= hot_threshold).sum()))
+    if hot_block_bytes is None:
+        return k
     fits = plan_num_hot(max(rows_per_device, 1), hot_block_bytes,
                         feature_dtype)
     if k > fits:
@@ -233,9 +222,9 @@ def build_hybrid(
     half the bytes and the optimum flattens across n/4096–n/8192 (~18.8 M
     samples/s) — n/4096 is kept. That split was swept at n=131072, where
     ``max_hot`` columns are ~2 GB; at a deployment's rows the block is
-    sized from BYTES (``plan_resident_hot``: ``hot_block_bytes``, by
-    default a quarter of the device's memory) and the columns past it stay
-    cold, so neither the host nor the device ever holds more than that.
+    sized from BYTES (``plan_resident_hot``: ``hot_block_bytes``, which
+    the coordinate derives from its mesh's device) and the columns past it
+    stay cold, so neither the host nor the device ever holds more than that.
     """
     indices = np.asarray(batch.indices)
     values = np.asarray(batch.values)

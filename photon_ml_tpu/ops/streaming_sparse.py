@@ -67,7 +67,7 @@ from photon_ml_tpu import obs
 # The hot block's dtype table and byte planner live with the resident
 # layout, which sizes its block through the same plan_num_hot.
 from photon_ml_tpu.ops.hybrid_sparse import (  # noqa: F401
-    _hot_matvec, _hot_rmatvec, feature_dtype_name, plan_num_hot)
+    _dense_hot, _hot_matvec, _hot_rmatvec, feature_dtype_name, plan_num_hot)
 from photon_ml_tpu.ops.losses import PointwiseLoss
 
 Array = jax.Array
@@ -218,11 +218,12 @@ def _build_canonical(raw, d: int, num_hot: int,
     hot_slot = np.full(d + 1, -1, np.int64)
     hot_slot[hot_cols[hot_cols < d]] = np.flatnonzero(hot_cols < d)
 
-    flat_row = np.repeat(np.arange(n, dtype=np.int32), indices.shape[1])
     slot = hot_slot[np.minimum(flat_col, d)]
     hot_sel = live & (slot >= 0)
-    X_hot = np.zeros((n, H), np.float32)
-    X_hot[flat_row[hot_sel], slot[hot_sel]] = flat_val[hot_sel]
+    # Slot by slot, so two slots of a row that meet in one hot column add
+    # up, as they do on the cold side and in the resident layouts.
+    X_hot = _dense_hot(np.where(hot_sel, slot, H).reshape(indices.shape),
+                       values, H, n, jnp.float32)
 
     # Cold ELL: the original (n, k) arrays with hot entries inert.
     is_hot2d = (slot >= 0).reshape(indices.shape)

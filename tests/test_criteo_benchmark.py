@@ -123,7 +123,7 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     assert len(conf["categorical_cardinalities"]) == 26
     assert conf["categorical_cardinalities"][
         conf["entity"]["categorical_index"]] == conf["entity"]["count"]
-    assert conf["num_rows"] == 1_000_000  # PERF.md section 4: the probe
+    assert conf["num_rows"] == 2_000_000  # PERF.md section 4: the probe
     assert cell["mix"]["setup_sweeps"] == 2
     assert set(conf["check"]["limits"]) == {
         "loss_1", "loss_2", "loss_3", "grad0", "coef.fixed", "coef.per-c10",
@@ -134,9 +134,7 @@ def test_the_guard_refuses_a_layout_that_does_not_fit(monkeypatch):
     """The parent's column cap would allocate 4096 columns x the rows; the
     guard reckons that from the program's planner and exits before anything
     is allocated. With the byte planner the block fits."""
-    import jax
-
-    from photon_ml_tpu.ops import hybrid_sparse as hs
+    from photon_ml_tpu.game.coordinates import sparse_fixed
 
     n = 2_500_000
     counts = np.zeros(1 << 20, np.int64)
@@ -148,15 +146,18 @@ def test_the_guard_refuses_a_layout_that_does_not_fit(monkeypatch):
         def memory_stats(self):
             return {"bytes_limit": 16 << 30}
 
-    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()])
-    plan = game_criteo.resident_plan("float32")
+    class Mesh:
+        devices = np.array([Chip()], object)
+
+    plan = game_criteo.resident_plan(Mesh(), "float32")
     assert plan["num_hot"] == 384 and plan["hot_bytes"] == 384 * n * 4
     assert plan["hot_bytes"] <= (16 << 30) // 4
-    monkeypatch.delattr(hs, "plan_resident_hot")  # the parent's program
-    plan = game_criteo.resident_plan("float32")
+    # the parent's program: its coordinate derives no budget
+    monkeypatch.delattr(sparse_fixed, "hot_block_budget")
+    plan = game_criteo.resident_plan(Mesh(), "float32")
     assert plan["num_hot"] == 4096 and plan["hot_bytes"] > 40e9
     with pytest.raises(SystemExit) as e:
-        game_criteo.estimator({"configuration": {"num_rows": n}}, None, 5,
+        game_criteo.estimator({"configuration": {"num_rows": n}}, Mesh(), 5,
                               "unused", "float32")
     assert "4096 columns" in str(e.value) and "cannot hold" in str(e.value)
 

@@ -132,11 +132,40 @@ def test_the_split_at_the_old_size_is_what_it_was():
     rng = np.random.default_rng(0)
     counts = np.bincount(rng.zipf(1.3, size=n * 39) % (1 << 20),
                          minlength=1 << 20)
+    v5e = 16_909_336_064 // 4  # what the coordinate gives on that chip
     for dt, div in ((jnp.float32, 2048), (jnp.bfloat16, 4096)):
         want = min(4096, int((counts >= n // div).sum()))
-        assert hs.plan_resident_hot(counts, n, dt) == want, dt
+        for budget in (None, v5e):
+            assert hs.plan_resident_hot(counts, n, dt,
+                                        hot_block_bytes=budget) == want, dt
     many = np.full(1 << 20, n, np.int64)  # every column hot: the cap binds
     assert hs.plan_resident_hot(many, n, jnp.float32) == 4096
+    assert hs.plan_resident_hot(many, n, jnp.float32,
+                                hot_block_bytes=v5e) == 4096
+
+
+def test_the_coordinate_takes_the_budget_from_its_mesh():
+    """A quarter of what the mesh's device reports; the CPU reports nothing,
+    and then the counts alone decide, as before the block had a budget."""
+    from photon_ml_tpu.game.coordinates import sparse_fixed
+
+    class Device:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    class Mesh:
+        def __init__(self, stats):
+            self.devices = np.array([Device(stats)], object)
+
+    assert sparse_fixed.hot_block_budget(
+        Mesh({"bytes_limit": 16_909_336_064})) == 4_227_334_016
+    assert sparse_fixed.hot_block_budget(Mesh(None)) is None
+    assert sparse_fixed.hot_block_budget(Mesh({})) is None
+    assert sparse_fixed.hot_block_budget(
+        make_mesh(devices=jax.devices()[:1])) is None  # the CPU
 
 
 def _dense(hb) -> np.ndarray:
@@ -190,6 +219,14 @@ def test_two_slots_of_a_row_that_meet_in_a_hot_column_add_up():
     shb = hs.build_hybrid_shards(batch, 1, hot_threshold=1, max_hot=4)
     np.testing.assert_array_equal(
         np.asarray(shb.X_hot[0])[:, np.asarray(shb.inv_perm)], want)
+    # the streamed chunks' hot block, which assigned until PR 29
+    for num_hot in (2, 4):
+        ch = ss._build_canonical(batch, 4, num_hot, jnp.float32)
+        got = np.zeros((3, 5))
+        got[:, np.asarray(ch.hot_cols)] += np.asarray(ch.X_hot)
+        np.add.at(got, (np.arange(3)[:, None].repeat(3, 1),
+                        np.asarray(ch.cold_cols)), np.asarray(ch.cold_vals))
+        np.testing.assert_array_equal(got[:, :4], want)
 
 
 @pytest.mark.parametrize("d", [4096, 1 << 20])

@@ -588,15 +588,24 @@ def _ctx(rows, **over):
 
 def test_benchmark_lists_the_new_metrics_additively():
     new = _new_metrics()
-    # PR 26's nineteen read the first cell; PR 29 appended its cell to the
-    # ten of them it can report and added four of these stems for its table
-    first = [m for m in new if m["workloads"][0] == "ml20m-logistic.steady"]
-    assert len(first) == 19 and len(new) == 23
+    first, second = "ml20m-logistic.steady", "criteo-1m-logistic.steady"
+    # PR 26's nineteen read the first cell alone; PR 29 appended its cell to
+    # the eleven of them a cell with a sparse fixed effect and one table can
+    # report, and added four of these stems for that table
+    both = {"ls_evals.fixed"} | {"phase_s." + p for p in (
+        "digest", "bucketing", "host_stage", "transfer", "program_load")} | {
+        "scope_s." + p for p in ("line_search", "value_grad", "direction",
+                                 "gather_scatter", "score")}
+    table = {stem + ".per-c10" for stem in ("re_iters", "lane_util",
+                                            "pad_share", "ls_evals")}
+    assert len(new) == 23
+    assert {m["name"] for m in new if m["workloads"] == [first, second]
+            } == both
+    assert {m["name"] for m in new if m["workloads"] == [second]} == table
+    assert {m["name"] for m in new if m["workloads"] == [first]} == {
+        stem + c for stem in ("re_iters", "lane_util", "pad_share",
+                              "ls_evals") for c in (".per-user", ".per-item")}
     for m in new:
-        assert m["workloads"] in (["ml20m-logistic.steady"],
-                                  ["ml20m-logistic.steady",
-                                   "criteo-1m-logistic.steady"],
-                                  ["criteo-1m-logistic.steady"])
         assert os.path.exists(os.path.join(
             BENCH, "layer_metrics", m["name"].split(".", 1)[0] + ".py"))
     assert {m["moves"] for m in new
