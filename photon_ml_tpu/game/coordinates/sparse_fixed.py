@@ -76,6 +76,9 @@ class SparseFixedEffectCoordinate:
       an evaluation takes 0.55 s against ~1.1 s for a plain ELL pass:
       2×, PERF.md section 6, PR 29). Exact, not approximate: the
       solve happens in a statically permuted feature space and maps back.
+      On one data shard L-BFGS's line search crosses the data twice an
+      iteration whatever its trials (parallel/sparse_problem.py
+      ``_hybrid_line``), so a fit's seconds follow its iterations alone.
       On a multi-data-shard mesh the rows split contiguously into
       per-shard hybrid layouts under one GLOBAL permutation
       (HybridShards): hot/cold aggregates run shard-local and psum over

@@ -44,6 +44,12 @@ The two halves carry the scopes ``fe.hot`` (the dense block's two passes)
 and ``fe.cold`` (the scatter-add of margins, the gather of the gradient)
 in a profiler trace (docs/OBSERVABILITY.md).
 
+Every evaluation pays both crossings, so L-BFGS's line search does not
+evaluate: ``products`` crosses once for the direction's margins,
+``row_terms`` reads a trial's value and slope off rows alone, and
+``row_gradient`` crosses once at the point accepted
+(parallel/sparse_problem.py ``_hybrid_line``).
+
 Pad slots carry rowid == n (a zero sentinel lane) and value 0, so they
 are inert in every pass without masks. All layout arrays are static
 (computed once at staging from the CSR/ELL structure); per optimizer
@@ -605,6 +611,12 @@ def margins(hb: HybridSparseBatch, w_perm: Array) -> Array:
     return z
 
 
+def products(hb: HybridSparseBatch, v_perm: Array) -> Array:
+    """(n,) X·v, the margins without the offsets: a direction's."""
+    return margins(
+        dataclasses.replace(hb, offsets=jnp.zeros_like(hb.offsets)), v_perm)
+
+
 def _masked(weights: Array, term: Array) -> Array:
     return jnp.where(weights > 0.0, weights * term, 0.0)
 
@@ -643,7 +655,15 @@ def _assemble_grad(hb: HybridSparseBatch, g_hot: Optional[Array],
     return jnp.zeros((d,), jnp.float32).at[:dense.shape[0]].set(dense)
 
 
-def _rowterm_gradient(hb: HybridSparseBatch, r: Array) -> Array:
+def row_terms(loss: PointwiseLoss, hb: HybridSparseBatch,
+              z: Array) -> tuple[Array, Array]:
+    """(Σ w·l, w·dl) at the margins ``z``: no pass over the features."""
+    l, dl = loss.loss_and_dz(z, hb.labels)
+    return (jnp.sum(_masked(hb.weights, l), axis=-1),
+            _masked(hb.weights, dl))
+
+
+def row_gradient(hb: HybridSparseBatch, r: Array) -> Array:
     """Σ_i r_i·x_i in PERMUTED space: hot matvec + cold class sums."""
     g_hot = None
     if hb.num_hot:
@@ -658,11 +678,8 @@ def value_and_gradient(
     hb: HybridSparseBatch,
 ) -> tuple[Array, Array]:
     """(Σ w·l, Σ w·dl·x) in permuted space — the fused hot/cold pass."""
-    z = margins(hb, w_perm)
-    l, dl = loss.loss_and_dz(z, hb.labels)
-    value = jnp.sum(_masked(hb.weights, l), axis=-1)
-    r = _masked(hb.weights, dl)
-    return value, _rowterm_gradient(hb, r)
+    value, r = row_terms(loss, hb, margins(hb, w_perm))
+    return value, row_gradient(hb, r)
 
 
 def hessian_vector(
@@ -676,7 +693,7 @@ def hessian_vector(
     xv = margins(hb, v_perm) - hb.offsets
     d2 = loss.d2z(z, hb.labels)
     r = _masked(hb.weights, d2) * xv
-    return _rowterm_gradient(hb, r)
+    return row_gradient(hb, r)
 
 
 def hessian_diagonal(

@@ -22,6 +22,7 @@ from photon_ml_tpu.optim.regularization import (RegularizationContext,
 
 Array = jax.Array
 
+LineOracle = _lbfgs.LineOracle
 minimize_lbfgs = _lbfgs.minimize
 minimize_owlqn = _lbfgs.minimize_owlqn
 minimize_tron = _tron.minimize
@@ -34,17 +35,20 @@ def optimize(
     *,
     hvp: Optional[Hvp] = None,
     l1_weights: Optional[Array] = None,
+    line: Optional[LineOracle] = None,
 ) -> OptResult:
     """Dispatch on OptimizerType (reference: OptimizerFactory.scala).
 
     ``value_and_grad`` must already include any L2 term (use ``with_l2``);
     ``l1_weights`` routes to OWL-QN; TRON additionally needs ``hvp``.
+    ``line`` is the same objective taken apart for L-BFGS's line search
+    (optim/lbfgs.py ``LineOracle``); the other optimizers do not ask it.
     """
     t = OptimizerType(config.optimizer_type)
     if t == OptimizerType.LBFGS:
         if l1_weights is not None:
             raise ValueError("L1 regularization requires OWLQN, not LBFGS")
-        return minimize_lbfgs(value_and_grad, w0, config)
+        return minimize_lbfgs(value_and_grad, w0, config, line=line)
     if t == OptimizerType.OWLQN:
         if l1_weights is None:
             raise ValueError("OWLQN requires l1_weights (else use LBFGS)")
@@ -67,6 +71,7 @@ def optimize(
 
 __all__ = [
     "OptResult", "OptimizerConfig", "OptimizerType", "ValueAndGrad", "Hvp",
+    "LineOracle",
     "RegularizationContext", "RegularizationType",
     "minimize_lbfgs", "minimize_owlqn", "minimize_tron", "optimize",
     "with_l2", "with_l2_hvp", "l1_weights_vector", "intercept_mask",

@@ -30,13 +30,19 @@ optimization library, the whole optimizer is a single compiled state machine:
   the direction and the post-step point, and the L1 term added to the
   line-search objective.
 
+- where a pass over the data is what an evaluation costs and the objective
+  meets the data through margins alone (a GLM), the caller hands over a
+  ``LineOracle``: a line search then makes one margins pass for the
+  direction and one gradient pass at the point it accepts, and its trials
+  none, so an iteration costs the same whatever the search needed.
+
 OWL-QN follows Andrew & Gao (2007), as Breeze's implementation does.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +57,25 @@ Array = jax.Array
 _EPS = 1e-10
 
 
+@dataclasses.dataclass(frozen=True)
+class LineOracle:
+    """A smooth objective f(w) = h(z(w)) + reg(w) with margins z affine in w,
+    taken apart so that a line search crosses the data twice however many
+    trials it makes: z(w + αd) = z(w) + α·z'(d).
+
+    ``start(w)``            → (f, g, carry): the first evaluation; ``carry``
+                              is whatever of it later lines reuse (the margins)
+    ``along(carry, w, d)``  → ray: one pass, the direction's margins
+    ``trial(ray, α)``       → (f, dφ/dα) at w + αd: no pass over the data
+    ``accept(ray, α)``      → (f, g, carry) at w + αd: one gradient pass
+    """
+
+    start: Callable[[Array], tuple[Array, Array, Any]]
+    along: Callable[[Any, Array, Array], Any]
+    trial: Callable[[Any, Array], tuple[Array, Array]]
+    accept: Callable[[Any, Array], tuple[Array, Array, Any]]
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class _LBFGSState:
@@ -63,11 +88,13 @@ class _LBFGSState:
     count: Array  # int32: number of valid pairs (slots 0 … count−1)
     it: Array  # int32
     evals: Array  # int32: value_and_grad calls so far, trials included
+    #               (under a LineOracle: pairs of passes over the data)
     converged: Array  # bool
     failed: Array  # bool: line search stalled
     g0_norm: Array
     value_history: Array
     grad_norm_history: Array
+    carry: Any = None  # a LineOracle's, at w (no leaf without one)
 
 
 def _two_loop(g, s_hist, y_hist, rho, count):
@@ -124,11 +151,16 @@ def minimize(
     w0: Array,
     config: OptimizerConfig = OptimizerConfig(),
     l1_weights: Optional[Array] = None,
+    line: Optional[LineOracle] = None,
 ) -> OptResult:
     """Minimize f(w) (+ Σ l1ⱼ|wⱼ| when ``l1_weights`` given → OWL-QN).
 
     ``value_and_grad`` must be the SMOOTH part only; the L1 term is handled
-    by pseudo-gradients / orthant projection, never differentiated.
+    by pseudo-gradients / orthant projection, never differentiated. With
+    ``line`` (the same smooth objective as a ``LineOracle``; not under
+    OWL-QN, whose trial points leave the line) nothing else evaluates it,
+    and ``evaluations`` counts pairs of passes over the data: the first
+    evaluation and one an iteration.
     """
     m = config.history_length
     max_iter = config.max_iterations
@@ -147,7 +179,13 @@ def minimize(
             return g
         return _pseudo_gradient(w, g, l1_weights)
 
-    f0, g0 = value_and_grad(w0)
+    if line is not None and is_owlqn:
+        raise ValueError("a LineOracle needs straight lines: not under OWL-QN")
+    carry0 = None
+    if line is None:
+        f0, g0 = value_and_grad(w0)
+    else:
+        f0, g0, carry0 = line.start(w0)
     ft0 = total_value(f0, w0)
     sg0 = search_gradient(w0, g0)
     g0_norm = jnp.linalg.norm(sg0)
@@ -174,7 +212,7 @@ def minimize(
         converged=g0_norm <= config.tolerance,
         failed=jnp.asarray(False),
         g0_norm=g0_norm,
-        value_history=vh, grad_norm_history=gh,
+        value_history=vh, grad_norm_history=gh, carry=carry0,
     )
 
     def line_search_owlqn(w, ft, sg, direction):
@@ -274,6 +312,50 @@ def minimize(
              new_w, new_f, new_g) = lax.while_loop(ls_cond, ls_body, st)
         return done | has_pt, new_w, new_f, new_g, steps
 
+    def line_search_along(w, ft, sg, direction, carry):
+        """``line_search_wolfe``'s bracket over a ``LineOracle``: the same
+        tests on the same two numbers of a trial, (f, φ'), which here cost
+        no pass over the data; what is kept of a trial is its α, and the
+        gradient is taken once, at the α the search ends on (0 where no
+        trial met Armijo: the point it started from). One pair of passes
+        whatever the trials, and that is what it reports."""
+        c1 = config.wolfe_c1
+        c2 = config.wolfe_c2
+        dg0 = jnp.dot(sg, direction)
+        inf = jnp.asarray(jnp.inf, dtype)
+
+        def ls_cond(st):
+            _, _, _, steps, done, *_ = st
+            return (~done) & (steps < config.max_line_search_steps)
+
+        def ls_body(st):
+            a, b, alpha, steps, done, has_pt, res_alpha, res_f = st
+            f_new, dg_new = line.trial(ray, alpha)
+            armijo = jnp.isfinite(f_new) & (f_new <= ft + c1 * alpha * dg0)
+            strong = armijo & (jnp.abs(dg_new) <= -c2 * dg0)
+            curv_low = dg_new < c2 * dg0
+            take = strong | (armijo & (f_new < res_f))
+            res_alpha = jnp.where(take, alpha, res_alpha)
+            res_f = jnp.where(take, f_new, res_f)
+            grow = armijo & curv_low & ~strong
+            a2 = jnp.where(grow, alpha, a)
+            b2 = jnp.where(~strong & ~grow, alpha, b)
+            alpha2 = jnp.where(grow & ~jnp.isfinite(b2),
+                               2.0 * alpha, 0.5 * (a2 + b2))
+            return (a2, b2, alpha2, steps + 1, strong, has_pt | armijo,
+                    res_alpha, res_f)
+
+        zero = jnp.asarray(0.0, dtype)
+        st = (zero, inf, jnp.asarray(1.0, dtype), jnp.asarray(0, jnp.int32),
+              jnp.asarray(False), jnp.asarray(False), zero, ft)
+        with jax.named_scope("lbfgs.line_search"):
+            ray = line.along(carry, w, direction)
+            _, _, _, _, done, has_pt, alpha, _ = lax.while_loop(
+                ls_cond, ls_body, st)
+            new_f, new_g, new_carry = line.accept(ray, alpha)
+        return (done | has_pt, w + alpha * direction, new_f, new_g,
+                jnp.asarray(1, jnp.int32), new_carry)
+
     line_search = line_search_owlqn if is_owlqn else line_search_wolfe
 
     def body(state: _LBFGSState) -> _LBFGSState:
@@ -296,7 +378,13 @@ def minimize(
                 d_dir)
 
         ft = total_value(state.f, state.w)
-        ok, new_w, new_f, new_g, trials = line_search(state.w, ft, sg, d_dir)
+        carry = None
+        if line is None:
+            ok, new_w, new_f, new_g, trials = line_search(
+                state.w, ft, sg, d_dir)
+        else:
+            ok, new_w, new_f, new_g, trials, carry = line_search_along(
+                state.w, ft, sg, d_dir, state.carry)
 
         with jax.named_scope("lbfgs.direction"):  # the history update
             s = new_w - state.w
@@ -334,6 +422,8 @@ def minimize(
             failed=state.failed | failed,
             g0_norm=state.g0_norm,
             value_history=vh, grad_norm_history=gh,
+            carry=jax.tree.map(lambda new, old: jnp.where(ok, new, old),
+                               carry, state.carry),
         )
         # vmap safety: freeze lanes that were already converged (history
         # buffers included — body still executes for them).

@@ -566,8 +566,8 @@ def test_hybrid_layout_roundtrip():
     z = np.asarray(hs.margins(hb, wp))
     np.testing.assert_allclose(z, X @ w, rtol=1e-4, atol=1e-4)
     r = rng.normal(size=512).astype(np.float32)
-    from photon_ml_tpu.ops.hybrid_sparse import _rowterm_gradient
-    g = np.asarray(hs.to_original_space(hb, _rowterm_gradient(hb, jnp.asarray(r))))
+    from photon_ml_tpu.ops.hybrid_sparse import row_gradient
+    g = np.asarray(hs.to_original_space(hb, row_gradient(hb, jnp.asarray(r))))
     np.testing.assert_allclose(g, r @ X, rtol=1e-3, atol=1e-3)
 
 
