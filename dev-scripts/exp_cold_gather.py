@@ -13,7 +13,7 @@ on the crossing. This script measures both formulations on the bench
 config (n=131k, d=1M, nnz=32 — BASELINE config 5's shape) and prints a
 JSON verdict for PARITY.
 
-    python dev-scripts/exp_cold_gather.py [--json]
+    python dev-scripts/exp_cold_gather.py [--json] [--interpret]
 
 VMEM bound: the fused kernel needs the full (n,) residual resident per
 grid cell, so it applies when n ≤ ~2M f32 rows (16 MB VMEM) — the
@@ -86,6 +86,8 @@ def main():
     ap.add_argument("--d", type=int, default=1_000_000)
     ap.add_argument("--nnz", type=int, default=32)
     ap.add_argument("--json", action="store_true")
+    ap.add_argument("--interpret", action="store_true",
+                    help="interpret the kernel (the CPU, small --n)")
     args = ap.parse_args()
 
     from photon_ml_tpu.data import sparse as sp
@@ -98,7 +100,7 @@ def main():
     batch, _ = sp.synthetic_sparse(args.n, args.d, args.nnz, seed=2)
     hb = hs.build_hybrid(batch)
     n = args.n
-    cold_nnz = sum(int((np.asarray(r) < n).sum()) for r in hb.cold_rowids)
+    cold_nnz = hb.entries[1]
     log(f"hybrid: {hb.num_hot} hot cols, {len(hb.cold_rowids)} cold "
         f"classes, {cold_nnz:,} cold nnz "
         f"(shapes {[tuple(r.shape) for r in hb.cold_rowids]})")
@@ -110,18 +112,29 @@ def main():
     r2d = jnp.concatenate(
         [r, jnp.zeros((flat_pad,), jnp.float32)]).reshape(-1, 128)
 
-    # Baseline: the current two-pass XLA formulation, all classes.
+    # Baseline: the current two-pass XLA formulation, all classes: the
+    # cold gradient, (present,).
     @jax.jit
     def xla_cold(rr):
-        parts = hs._cold_grad(hb, rr, hb.cold_vals)
-        return jnp.concatenate(parts)
+        return hs._cold_grad(hb, rr, hb.cold_vals)[0]
 
-    # Fused: one pallas_call per class (same per-class decomposition).
+    # Fused: one pallas_call per class (same per-class decomposition). A
+    # class's row is one chunk of a column (ops/hybrid_sparse.py), a class
+    # of L < 128 is held (L, C), and the chunk sums become the columns' as
+    # in ``_cold_grad``: the top chunks' by concatenation, the remainder
+    # chunks' by one scatter-add over ``chunk_cols``.
     @jax.jit
     def pallas_cold(rr2d):
-        return jnp.concatenate([
-            fused_cold_grad(rr2d, rows, vals)
-            for rows, vals in zip(hb.cold_rowids, hb.cold_vals)])
+        tops, rems = [], []
+        for L, n_rems, rows, vals in zip(hb.class_lens, hb.class_rems,
+                                         hb.cold_rowids, hb.cold_vals):
+            if L < hs._LANES:
+                rows, vals = rows.T, vals.T
+            sums = fused_cold_grad(rr2d, rows, vals, args.interpret)
+            rems.append(sums[:n_rems])
+            tops.append(sums[n_rems:])
+        return jnp.concatenate(tops).at[hb.chunk_cols].add(
+            jnp.concatenate(rems))
 
     def timed(f, x, iters):
         o = f(x)

@@ -185,7 +185,11 @@ class SparseFixedEffectCoordinate:
                     hot_bytes=int(host.X_hot.nbytes),
                     hot_entries=host.entries[0],
                     cold_entries=host.entries[1],
-                    cold_slots=sum(int(r.size) for r in host.cold_rowids))
+                    cold_slots=sum(int(r.size) for r in host.cold_rowids),
+                    cold_chunks=sum(
+                        int(r.size) // L for r, L in zip(
+                            host.cold_rowids, host.class_lens)),
+                    cold_classes=len(host.class_lens))
         else:
             if self.feature_sharded:
                 from photon_ml_tpu.parallel.mesh import MODEL_AXIS

@@ -6,10 +6,10 @@ restructured around how a TPU actually moves data.
 
 Why: measured on one v5e chip, XLA's random 4M-element gather runs at
 ~0.14 Gelem/s and its scatter-add at ~0.16 G-updates/s (at a deployment's
-size the same: 33.4M cold slots cross twice an evaluation in 0.51 s, 0.13
-G slots/s, and a plain ELL pass over 97.5M entries takes 0.75 s for its
-gather and 0.67 s for its segment-sum; PERF.md section 6, PR 29), and a Mosaic
-(8, 128)-window vector shuffle tops out at ~0.84 Gelem/s — so ANY exact
+size the same: 23.6M cold entries cross twice an evaluation in 0.32 s, 0.15
+G entries/s, and a plain ELL pass over 97.5M entries takes 0.75 s for its
+gather and 0.67 s for its segment-sum; PERF.md section 6, PRs 29–30), and a
+Mosaic (8, 128)-window vector shuffle tops out at ~0.84 Gelem/s — so ANY exact
 ELL step at d=1e6 pays two ~26 ms random crossings (expand w→entries,
 reduce entries→gradient) and lands near 60 ms regardless of formulation
 (plain scatter, pre-sorted segment-sum, one-hot matmul tiles, and
@@ -31,14 +31,25 @@ The hybrid split exploits that:
 - **Cold columns** are relabeled into count-descending order (a static
   permutation of the feature space — the GLM objective is permutation-
   equivariant, so the solve happens in permuted space and maps back once
-  per fit) and their entries stored column-contiguous in power-of-two
-  count classes, padded (C, L) blocks:
-  * margins: w broadcast per column (NO gather — columns are contiguous
-    slices), one scatter-add of products by row — the only remaining
-    crossing, now ~15% of the volume;
+  per fit) and their entries stored in power-of-two classes of **chunks**:
+  a column of count c is split along the binary digits of c, one chunk of
+  exactly 2^k entries for every set bit k, and class 2^k holds, each as one
+  full row of a (C, L = 2^k) block, the chunks of every column whose count
+  has bit k set. No slot is padding: what crosses the random path is the
+  non-zeros and nothing else (rounding a column up to one power of two
+  padded 29% of the slots of a 2M-row click log; PERF.md section 6, PR
+  30). Counts descend, so within a class the chunks of the larger columns
+  (**remainder chunks**, which name their column through ``chunk_cols``)
+  come first and the columns whose TOP bit is k follow as one contiguous
+  run of the permuted space (``class_starts``), served by slice:
+  * margins: w per chunk (one small gather for the remainder chunks, a
+    contiguous slice for the rest), broadcast over the chunk's entries,
+    one scatter-add of products by row — the first crossing, now ~15–30%
+    of the volume;
   * gradient: one gather r[rowids] (second crossing, same reduced
-    volume), then padded row-sums per class and CONTIGUOUS writes into
-    the permuted gradient — no scatter at all.
+    volume), then row-sums per class: the top chunks' sums are the
+    permuted gradient's contiguous slices, the remainder chunks' are added
+    into their columns by one small scatter-add.
 
 The two halves carry the scopes ``fe.hot`` (the dense block's two passes)
 and ``fe.cold`` (the scatter-add of margins, the gather of the gradient)
@@ -50,10 +61,13 @@ evaluate: ``products`` crosses once for the direction's margins,
 ``row_gradient`` crosses once at the point accepted
 (parallel/sparse_problem.py ``_hybrid_line``).
 
-Pad slots carry rowid == n (a zero sentinel lane) and value 0, so they
-are inert in every pass without masks. All layout arrays are static
-(computed once at staging from the CSR/ELL structure); per optimizer
-iteration only w changes.
+The data-sharded layout (``HybridShards``) keeps one padded row per column
+and class, because a column's count differs by shard while the class shapes
+are shared: it has no remainder chunks (chunk i of a class is column
+``start + i``), and its pad slots carry rowid == n (a zero sentinel lane)
+and value 0, so they are inert in every pass without masks. All layout
+arrays are static (computed once at staging from the CSR/ELL structure);
+per optimizer iteration only w changes.
 """
 
 from __future__ import annotations
@@ -84,9 +98,14 @@ class HybridSparseBatch:
     """
 
     X_hot: Array  # (n, k) dense hot block (k may be 0)
-    cold_rowids: tuple[Array, ...]  # per class: (C, L) int32, pad == n
-    cold_vals: tuple[Array, ...]  # per class: (C, L) f32, pad == 0;
-    #                               both (L, C) where L < 128
+    # Per class: (C, L) int32 row ids and f32 values, one chunk of L entries
+    # a row, every slot live; both (L, C) where L < 128. (A shard of the
+    # data-sharded layout: one row a column, pad slots rowid == n, value 0.)
+    cold_rowids: tuple[Array, ...]
+    cold_vals: tuple[Array, ...]
+    # The cold column (hot block excluded) of every remainder chunk, class
+    # by class in the classes' row order: (sum(class_rems),) int32.
+    chunk_cols: Array
     labels: Array  # (n,)
     weights: Array  # (n,)
     offsets: Array  # (n,)
@@ -94,13 +113,19 @@ class HybridSparseBatch:
     inv_perm: Array  # (d,) int32: original col -> new col
     num_features: int = dataclasses.field(metadata=dict(static=True))
     num_hot: int = dataclasses.field(metadata=dict(static=True))
-    # Per class: first permuted column id (hot block excluded) and count.
+    # Per class: the first permuted column id (hot block excluded) of the
+    # run of columns whose top chunk it holds: the rows past its
+    # ``class_rems`` remainder chunks, in column order.
     class_starts: tuple[int, ...] = dataclasses.field(
         metadata=dict(static=True))
     # Per class: its slot count L. A class of L < 128 is held lane-major,
     # (L, C), so that its minor dimension is the long one (see
     # ``_class_block``).
     class_lens: tuple[int, ...] = dataclasses.field(
+        metadata=dict(static=True))
+    # Per class: the rows at its head that are remainder chunks (of columns
+    # whose count has a higher bit set too); ``chunk_cols`` names theirs.
+    class_rems: tuple[int, ...] = dataclasses.field(
         metadata=dict(static=True))
     # Live entries the hot block and the cold classes serve (the run
     # ledger's ``fe_layout`` row).
@@ -114,11 +139,6 @@ class HybridSparseBatch:
     @property
     def dim(self) -> int:
         return self.num_features
-
-    @property
-    def num_cold_present(self) -> int:
-        return sum(int(r.size) // L
-                   for r, L in zip(self.cold_rowids, self.class_lens))
 
 
 # Bytes per element of each hot-block storage dtype. int8 (the streamed
@@ -263,45 +283,37 @@ def build_hybrid(
     c_row = flat_row[cold_sel]
     c_val = flat_val[cold_sel]
     order = np.argsort(c_new, kind="stable")
-    c_new, c_row, c_val = c_new[order], c_row[order], c_val[order]
-    cold_counts = counts[order_desc][k:]  # descending
-    present = int((cold_counts > 0).sum())
-    col_start = np.concatenate(
-        [[0], np.cumsum(cold_counts[:present])[:-1]]).astype(np.int64)
+    c_row, c_val = c_row[order], c_val[order]
+    cnts = counts[order_desc][k:]  # descending
+    cnts = cnts[:int((cnts > 0).sum())].astype(np.int64)  # the present ones
+    col_start = np.cumsum(cnts) - cnts
 
-    # Power-of-two count classes over the present cold columns; counts are
-    # descending, so each class is one contiguous slice of columns.
+    # A column of count c gives one chunk of 2^b entries for every set bit
+    # b of c: its entries p = 0..c-1 in runs from the top bit down, the
+    # chunk of bit b starting where b and every bit under it are cleared
+    # from c. Class 2^b holds a row for each column with bit b set, in
+    # column order: counts descend, so the columns of count >= 2^(b+1)
+    # (remainder chunks) come before the run whose top bit is b. Descending
+    # class order == the permuted column layout, so the top chunks' sums
+    # concatenate back in place.
     rowids_cls: list[np.ndarray] = []
     vals_cls: list[np.ndarray] = []
+    rem_cols: list[np.ndarray] = []
     class_starts: list[int] = []
     class_lens: list[int] = []
-    if present:
-        # Counts are descending, so equal-class columns are contiguous and
-        # padding is < 2x within each power-of-two class.
-        cls = np.ceil(np.log2(np.maximum(
-            cold_counts[:present], 1))).astype(np.int64)
-        # Descending class order == the permuted column layout, so the
-        # per-class gradient slices concatenate back in place.
-        for kk in np.unique(cls)[::-1]:
-            sel = np.flatnonzero(cls == kk)
-            L = 1 << int(kk)
-            C = sel.size
-            rp = np.full((C, L), n, np.int32)
-            vp = np.zeros((C, L), np.float32)
-            # Vectorized fill: position of each entry within its column.
-            starts = col_start[sel]
-            cnts = cold_counts[sel].astype(np.int64)
-            total = int(cnts.sum())
-            colpos = np.arange(total) - np.repeat(
-                np.concatenate([[0], np.cumsum(cnts)[:-1]]), cnts)
-            src = np.repeat(starts, cnts) + colpos
-            crow = np.repeat(np.arange(C, dtype=np.int64), cnts)
-            rp[crow, colpos] = c_row[src]
-            vp[crow, colpos] = c_val[src]
-            rowids_cls.append(_class_block(rp, L))
-            vals_cls.append(_class_block(vp, L))
-            class_starts.append(int(sel[0]))
-            class_lens.append(L)
+    for b in reversed(range(int(cnts.max(initial=0)).bit_length())):
+        cols = np.flatnonzero((cnts >> b) & 1)
+        if not cols.size:
+            continue
+        L = 1 << b
+        first = col_start[cols] + ((cnts[cols] >> (b + 1)) << (b + 1))
+        slots = first[:, None] + np.arange(L)
+        rowids_cls.append(_class_block(c_row[slots], L))
+        vals_cls.append(_class_block(c_val[slots], L))
+        start = int((cnts >= 2 * L).sum())  # the run of top bit b begins
+        rem_cols.append(cols[cols < start].astype(np.int32))
+        class_starts.append(start)
+        class_lens.append(L)
 
     # device=False keeps the leaves as host numpy (a valid pytree): the
     # row-streaming path (ops/streaming_sparse.py) holds many chunks on
@@ -312,6 +324,7 @@ def build_hybrid(
         X_hot=put(X_hot),
         cold_rowids=tuple(put(a) for a in rowids_cls),
         cold_vals=tuple(put(a) for a in vals_cls),
+        chunk_cols=put(np.concatenate(rem_cols or [np.zeros(0, np.int32)])),
         labels=put(np.asarray(batch.labels)),
         weights=put(np.asarray(batch.weights)),
         offsets=put(np.asarray(batch.offsets)),
@@ -321,7 +334,8 @@ def build_hybrid(
         num_hot=k,
         class_starts=tuple(class_starts),
         class_lens=tuple(class_lens),
-        entries=(int(counts[order_desc[:k]].sum()), int(c_new.size)),
+        class_rems=tuple(c.size for c in rem_cols),
+        entries=(int(counts[order_desc[:k]].sum()), int(c_row.size)),
     )
 
 
@@ -422,10 +436,11 @@ def local_shard(shb: HybridShards, X_hot: Array,
     empty = jnp.zeros((0,), jnp.int32)
     return HybridSparseBatch(
         X_hot=X_hot[0], cold_rowids=tuple(r[0] for r in cold_rowids),
-        cold_vals=tuple(v[0] for v in cold_vals), labels=labels[0],
-        weights=weights[0], offsets=offsets[0], perm=empty, inv_perm=empty,
-        num_features=shb.num_features, num_hot=shb.num_hot,
-        class_starts=shb.class_starts, class_lens=shb.class_lens)
+        cold_vals=tuple(v[0] for v in cold_vals), chunk_cols=empty,
+        labels=labels[0], weights=weights[0], offsets=offsets[0], perm=empty,
+        inv_perm=empty, num_features=shb.num_features, num_hot=shb.num_hot,
+        class_starts=shb.class_starts, class_lens=shb.class_lens,
+        class_rems=(0,) * len(shb.class_lens))
 
 
 def build_hybrid_shards(
@@ -577,15 +592,22 @@ def _cold_products(hb: HybridSparseBatch, w_perm: Array,
                    cold_vals: tuple[Array, ...]) -> Array:
     """Flat per-entry w[col]·value products over all classes.
 
-    Column coefficients arrive by contiguous SLICE broadcast (no gather):
-    each class's columns are one run of the permuted space.
+    A chunk's coefficient arrives by ONE gather over the remainder chunks
+    of all classes (``chunk_cols``) and, for the rest of a class, by
+    contiguous SLICE: those columns are one run of the permuted space.
     """
+    w_cold = w_perm[hb.num_hot:]
+    w_rem = w_cold[hb.chunk_cols]
     parts = []
-    for start, L, vals in zip(hb.class_starts, hb.class_lens, cold_vals):
-        C = vals.size // L
-        w_c = w_perm[hb.num_hot + start: hb.num_hot + start + C]
+    off = 0
+    for start, L, rems, vals in zip(hb.class_starts, hb.class_lens,
+                                    hb.class_rems, cold_vals):
+        tops = vals.size // L - rems
+        w_c = jnp.concatenate([w_rem[off: off + rems],
+                               w_cold[start: start + tops]])
         w_c = w_c[None, :] if L < _LANES else w_c[:, None]
         parts.append((w_c * vals).reshape(-1))
+        off += rems
     return jnp.concatenate(parts)
 
 
@@ -594,9 +616,9 @@ def _cold_flat_rowids(hb: HybridSparseBatch) -> Array:
 
 
 def margins(hb: HybridSparseBatch, w_perm: Array) -> Array:
-    """(n,) wᵀx + offset. Hot: one MXU matvec. Cold: contiguous-slice
-    broadcast products + ONE fused scatter-add by row (the only random
-    crossing in this direction)."""
+    """(n,) wᵀx + offset. Hot: one MXU matvec. Cold: per-chunk broadcast
+    products + ONE fused scatter-add by row (the only crossing of the
+    entries in this direction)."""
     n = hb.labels.shape[0]
     z = hb.offsets
     if hb.num_hot:
@@ -623,20 +645,25 @@ def _masked(weights: Array, term: Array) -> Array:
 
 def _cold_grad(hb: HybridSparseBatch, r: Array,
                cold_vals: tuple[Array, ...]) -> list[Array]:
-    """Per class, (C,) gradient slice: ONE fused gather r[rowids] (the
-    second random crossing), then padded row-sums and contiguous writes."""
+    """The cold columns' gradient, (present,), as a list of one: ONE fused
+    gather r[rowids] (the second random crossing), then row-sums per class.
+    The top chunks' sums are contiguous slices of the result; the remainder
+    chunks' are added into their columns by one scatter-add."""
     if not hb.cold_rowids:
         return []
     with jax.named_scope("fe.cold"):
         r_pad = jnp.concatenate([r, jnp.zeros((1,), r.dtype)])
         gathered = r_pad[_cold_flat_rowids(hb)]
-        out = []
+        tops, rems = [], []
         off = 0
-        for L, vals in zip(hb.class_lens, cold_vals):
+        for L, n_rems, vals in zip(hb.class_lens, hb.class_rems, cold_vals):
             ru = gathered[off: off + vals.size].reshape(vals.shape)
-            out.append(jnp.sum(ru * vals, axis=0 if L < _LANES else 1))
+            sums = jnp.sum(ru * vals, axis=0 if L < _LANES else 1)
+            rems.append(sums[:n_rems])
+            tops.append(sums[n_rems:])
             off += vals.size
-    return out
+        return [jnp.concatenate(tops).at[hb.chunk_cols].add(
+            jnp.concatenate(rems))]
 
 
 def _assemble_grad(hb: HybridSparseBatch, g_hot: Optional[Array],
@@ -664,7 +691,7 @@ def row_terms(loss: PointwiseLoss, hb: HybridSparseBatch,
 
 
 def row_gradient(hb: HybridSparseBatch, r: Array) -> Array:
-    """Σ_i r_i·x_i in PERMUTED space: hot matvec + cold class sums."""
+    """Σ_i r_i·x_i in PERMUTED space: hot matvec + cold chunk sums."""
     g_hot = None
     if hb.num_hot:
         with jax.named_scope("fe.hot"):

@@ -72,7 +72,7 @@ def _hybrid_line(loss: PointwiseLoss, hb, l2: float,
                  mask: Array) -> LineOracle:
     """``run_hybrid``'s objective, Σ w·l(z) + ½·λ‖w∘mask‖² with z = offsets
     + X·w, for L-BFGS's line search. An evaluation here is two crossings of
-    the cold classes (0.5 s at 2M rows of click logs against 11 ms for the
+    the cold classes (0.33 s at 2M rows of click logs against 11 ms for the
     hot block, PERF.md section 5), and a search takes one to a dozen trials
     as the data fall: along w + αd the margins are z + α·(X·d), so X·d is
     crossed once, a trial reads rows and columns and no feature, and the
