@@ -81,8 +81,7 @@ def leg(name: str, t0: float) -> None:
 
 def kernel_cases(rng, full: bool) -> dict:
     """name -> (arrays, static tail, exact?) for every registered kernel,
-    at the TPU shapes bench.py's kernel sweep uses (small ones for the
-    interpreter). ``exact`` cases hold integers and power-of-two scales,
+    at TPU shapes (small ones for the interpreter). ``exact`` cases hold integers and power-of-two scales,
     so any summation order gives the same bits."""
     import jax.numpy as jnp
 

@@ -16,9 +16,7 @@ line: ``serving_saturation_knee_qps`` with the full
 ``serving_p99_vs_qps_curve``, per-stage attribution fractions
 (queue wait / assemble / device score / respond), and a bench-vs-metrics
 cross-check — the bench's request counts and latency totals must agree
-with the serving scoreboard within 10%, the same shared-provenance
-discipline check_bench_regression.py gates for the flagship
-(docs/OBSERVABILITY.md).
+with the serving scoreboard within 10% (docs/OBSERVABILITY.md).
 
 **Closed-loop (--closed-loop).** The original bench: N client threads,
 submit→result round trips; still the right tool for steady-state
@@ -110,8 +108,7 @@ def build_parser():
                         "map's degradation alongside — the acceptance "
                         "claim is knee retention as the head "
                         "concentrates (fleet_knee_vs_skew_curve, "
-                        "fleet_p99_vs_skew_curve; gated by "
-                        "check_bench_regression.py)")
+                        "fleet_p99_vs_skew_curve)")
     p.add_argument("--zipf-skews", default="0.0,0.6,0.9,1.2",
                    help="comma-separated Zipf exponents of the skew "
                         "sweep")
@@ -149,7 +146,7 @@ def build_parser():
                         "plus the in-process model-load walls and a "
                         "rehome-under-restart p99 leg through a "
                         "2-replica mmap-booted fleet (unserved must be "
-                        "0; gated by check_bench_regression.py)")
+                        "0)")
     p.add_argument("--restart-entities", type=int, default=200_000,
                    help="entity-table rows of the restart-arm model "
                         "(large enough that parse-vs-mmap dominates "
@@ -166,8 +163,7 @@ def build_parser():
                         "device-byte budget: f32 vs int8 caches sized to "
                         "the same HBM spend, one open-loop level each — "
                         "int8 holds ~4x the entities, so hit rate rises "
-                        "and p99 falls at equal budget (gated by "
-                        "check_bench_regression.py)")
+                        "and p99 falls at equal budget")
     p.add_argument("--cache-budget-kb", type=float, default=8.0,
                    help="device bytes per coordinate the sweep holds "
                         "fixed across dtypes (cache table + int8 scale "
@@ -515,7 +511,7 @@ def run_closed_loop(args, service, make_request, load_seconds):
 def run_publish(args):
     """One open-loop constant-QPS stream with a refit→delta→hot-swap
     landing at the half-way mark: the bench form of the zero-drop
-    contract. Gated lines (check_bench_regression.py): the swap wall is
+    contract. What it holds: the swap wall is
     bounded, p99 inside the swap window stays within band of steady
     state, and NOT ONE request goes unserved."""
     import tempfile
@@ -1103,10 +1099,10 @@ def run_zipf_sweep(args):
     """The acceptance sweep of ROADMAP item 2: with the elastic loop
     armed, knee QPS and steady p99 must hold as Zipf skew rises (the
     static map's degradation is measured alongside as the comparison
-    line). Gated by check_bench_regression.py: knee at the highest
+    line). The acceptance: knee at the highest
     skew >= 0.9x the knee at zero skew, p99 in band; on boxes under 4
     cores the fleet shares one core and the knee measures scheduling,
-    so the gate is reported-only (`zipf_sweep_valid: false` — the
+    so the reading is marked (`zipf_sweep_valid: false` — the
     restart-arm discipline)."""
     import tempfile
 
@@ -1416,8 +1412,8 @@ def run_cache_sweep(args):
     ~4× the entities of the f32 one on the same spend. One open-loop
     constant-arrival level per dtype over the SAME Zipf draw; the
     hit-rate → p99 movement at equal bytes is the BENCH claim
-    (``serving_cache_dtype_sweep``), gated by check_bench_regression.py
-    (int8 capacity ≥ 2× f32, int8 hit rate ≥ f32's)."""
+    (``serving_cache_dtype_sweep``: int8 capacity ≥ 2× f32, int8 hit
+    rate ≥ f32's)."""
     from photon_ml_tpu.serving import ScoringService
     from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
 

@@ -9,8 +9,8 @@ real download. The run reports:
 
   * host staging seconds per coordinate (bucketing + block packing),
   * steady-state seconds per CD sweep — min-of-3 slope between 1- and
-    3-iteration descents (the same dependency-chain discipline bench.py
-    uses; min-of-N because dispatch delay is additive and heavy-tailed),
+    3-iteration descents (min-of-N because dispatch delay is additive
+    and heavy-tailed),
   * validation AUC vs the planted effects.
 
     python dev-scripts/flagship_movielens.py [--rows 20000000] [--json]
@@ -19,8 +19,7 @@ Needs ~6 GB host RAM for generation. At the full 20M rows, --bf16 is
 REQUIRED on one 16 GB chip: the f32 run exhausts HBM during the first
 descent even with the active-row cap (measured 2026-07-31; the resident
 set roughly doubles and the solver's per-class scratch follows), while
-bf16 completes with headroom. The same config is available in bench.py
-behind PML_BENCH_20M=1 as ``game_cd_iteration_seconds_20m`` (bf16).
+bf16 completes with headroom.
 """
 import argparse
 import json
@@ -45,7 +44,7 @@ def run_flagship(n_rows=20_000_000, n_users=138_000, n_items=27_000,
                  min_of=3, max_samples=65536, validate_each=False,
                  quality_only=False, seed=2026, log=lambda msg: None):
     """Build the MovieLens-shaped dataset and measure staged CD. Returns a
-    dict of measurements (shared by this script and bench.py's gated line)."""
+    dict of measurements."""
     import jax.numpy as jnp
 
     from photon_ml_tpu.data import synthetic

@@ -151,16 +151,6 @@ if [ "$rc" -eq 0 ]; then
   env JAX_PLATFORMS=cpu python dev-scripts/kernel_smoke.py; rc=$?
 fi
 
-# Sweep smoke (docs/SWEEPS.md): a tiny dirty-gated GAME fit through
-# the real CLI — bare --sweep bit-equal to the ungated leg, the gate
-# engaging then backstopping in the re_fit_wave ledger aggregates, the
-# refit/skipped counters agreeing with the ledger, the dirty-set
-# checkpoint artifact on disk, and photon-obs diff rendering the
-# entities-fit table. ~1 minute on CPU.
-if [ "$rc" -eq 0 ]; then
-  env JAX_PLATFORMS=cpu python dev-scripts/sweep_smoke.py; rc=$?
-fi
-
 # Fabric smoke (docs/STREAMING.md "Multi-host streaming"): a REAL
 # 2-process jax.distributed CPU fit with the host-level fabric armed —
 # chunk ranges shard over the two ranks, host partials meet in one
@@ -171,15 +161,5 @@ fi
 # ~1-2 minutes on CPU.
 if [ "$rc" -eq 0 ]; then
   env JAX_PLATFORMS=cpu python dev-scripts/fabric_smoke.py; rc=$?
-fi
-
-# Opt-in staging-bench regression gate (slow: measures a fresh 10M-row
-# staging tail, several minutes). PML_CHECK_BENCH=1 enables it; a >20%
-# regression of the guarded staging lines vs the bench tail named by
-# PML_BENCH_BASELINE (taken on this machine; none is committed) fails
-# the run. See dev-scripts/check_bench_regression.py.
-if [ "$rc" -eq 0 ] && [ "${PML_CHECK_BENCH:-0}" = "1" ]; then
-  env JAX_PLATFORMS=cpu python dev-scripts/check_bench_regression.py --run-staging \
-    --baseline "${PML_BENCH_BASELINE:?PML_CHECK_BENCH=1 needs PML_BENCH_BASELINE=<bench tail JSON>}"; rc=$?
 fi
 exit $rc

@@ -13,7 +13,7 @@ Asserts, in order:
    certificate contract of docs/STREAMING.md "Stochastic solvers";
 3. both convergence curves reach a common target (the worse final
    value plus a relative band) — ``time_to_target`` is non-None for
-   each, the quantity bench.py's ``bench_solver_race`` races at scale;
+   each;
 4. ``photon-obs diff`` across the two runs gates the shared coordinate
    (a time-to-target ratio exists) and renders the
    "duality gap vs wall clock" overlay — the gap series must survive

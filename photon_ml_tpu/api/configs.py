@@ -15,7 +15,6 @@ import dataclasses
 from typing import Optional, Union
 
 from photon_ml_tpu.game.staging import StagingConfig
-from photon_ml_tpu.game.sweep import SweepConfig
 from photon_ml_tpu.ingest import IngestConfig
 from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType,
                                  RegularizationContext, RegularizationType)
@@ -31,13 +30,11 @@ __all__ = [
     "RandomEffectDataConfiguration",
     "StagingConfig",
     "StreamingConfig",
-    "SweepConfig",
     "parse_ingest_config",
     "parse_kv",
     "parse_optimizer_config",
     "parse_staging_config",
     "parse_streaming_config",
-    "parse_sweep_config",
 ]
 
 
@@ -350,47 +347,6 @@ def parse_streaming_config(spec: str) -> StreamingConfig:
         workers=int(kv["workers"]) if "workers" in kv else None,
         solver=(kv["solver"].lower() if "solver" in kv
                 else defaults.solver),
-    )
-
-
-def parse_sweep_config(spec: str) -> SweepConfig:
-    """Parse ``key=value,...`` mini-DSL for dirty-gated incremental
-    sweeps (game/sweep.py, docs/SWEEPS.md). An empty spec (bare
-    ``--sweep``) takes every default — ``gate=0``, which tracks nothing
-    and is bit-identical to an ungated run.
-
-    Keys: theta (mean per-row offset-drift threshold), grad_tol
-    (per-entity gradient-norm threshold), min_sweeps_full (leading
-    outer iterations forced full, >= 1), final_full (true|false — force
-    the last outer iteration full, the parity-band backstop), gram
-    (true|false — reuse per-bucket normal-equation Gram blocks for the
-    squared-loss bucket solver).
-    """
-    kv = parse_kv(spec)
-    known = {"theta", "grad_tol", "min_sweeps_full", "final_full", "gram"}
-    unknown = set(kv) - known
-    if unknown:
-        raise ValueError(f"unknown sweep keys {sorted(unknown)}; "
-                         f"expected {sorted(known)}")
-    defaults = SweepConfig()
-
-    def _bool(key: str, default: bool) -> bool:
-        if key not in kv:
-            return default
-        v = kv[key].lower()
-        if v not in ("true", "false"):
-            raise ValueError(f"{key} must be true or false, got {kv[key]!r}")
-        return v == "true"
-
-    return SweepConfig(
-        theta=float(kv["theta"]) if "theta" in kv else defaults.theta,
-        grad_tol=(float(kv["grad_tol"]) if "grad_tol" in kv
-                  else defaults.grad_tol),
-        min_sweeps_full=(int(kv["min_sweeps_full"])
-                         if "min_sweeps_full" in kv
-                         else defaults.min_sweeps_full),
-        final_full_sweep=_bool("final_full", defaults.final_full_sweep),
-        gram=_bool("gram", defaults.gram),
     )
 
 

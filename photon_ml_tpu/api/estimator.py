@@ -70,7 +70,6 @@ class GameEstimator:
         staging: Optional[StagingConfig] = None,
         ingest: Optional[IngestConfig] = None,
         streaming: Optional[StreamingConfig] = None,
-        sweep=None,
         trace=None,
         ledger_dir: Optional[str] = None,
         watchdog=None,
@@ -102,14 +101,6 @@ class GameEstimator:
         # chunk ranges sharded over the mesh's data axis, psum-merged
         # partials, n bounded by host RAM instead of HBM.
         self.streaming = streaming
-        # Dirty-gated incremental sweeps (docs/SWEEPS.md): a SweepConfig
-        # routing random-effect coordinates onto the gated descent path —
-        # outer iterations past min_sweeps_full refit only entities whose
-        # residual offsets drifted or whose last solve left gradient
-        # mass. Deliberately NOT part of the coordinate cache key below:
-        # gating changes which lanes dispatch, never how coordinates are
-        # constructed/staged.
-        self.sweep = sweep
         # Span tracing (docs/OBSERVABILITY.md): an obs.Tracer instance
         # activated for the duration of each fit() — library users get
         # the same timeline `game_train --trace-out` produces, without
@@ -356,7 +347,6 @@ class GameEstimator:
                       "reg_weight_grid": list(cc.reg_weight_grid)}
                 for cid, cc in self.coordinate_configs.items()},
             "streaming": descent._jsonable(self.streaming),
-            "sweep": descent._jsonable(self.sweep),
             "normalization": {
                 s: descent.normalization_digest(ctx)
                 for s, ctx in self.normalization.items()},
@@ -508,8 +498,7 @@ class GameEstimator:
                     initial_models=initial_models,
                     locked_coordinates=locked_coordinates,
                     validation_fn=val_fn,
-                    checkpoint_manager=manager,
-                    sweep=self.sweep)
+                    checkpoint_manager=manager)
             model = self._finalize_variances(model, coords, data)
             evaluation = (self._evaluate(model, validation_data)
                           if validation_data is not None else None)

@@ -27,8 +27,7 @@ from photon_ml_tpu.utils import events as ev_mod
 logger = logging.getLogger("photon_ml_tpu.avro")
 
 # What the fallback warning says about the pure-Python codec; the ratio
-# to the native block decoder is not measured on the current chip's host
-# (bench.py, bench_avro_ingest).
+# to the native block decoder is not measured on the current chip's host.
 _FALLBACK_RATE_GAP = "far slower (one Python call per field)"
 
 

@@ -32,8 +32,7 @@ from photon_ml_tpu.api.configs import (CoordinateConfiguration,
                                        parse_ingest_config, parse_kv,
                                        parse_optimizer_config,
                                        parse_staging_config,
-                                       parse_streaming_config,
-                                       parse_sweep_config)
+                                       parse_streaming_config)
 from photon_ml_tpu.api.estimator import GameEstimator
 from photon_ml_tpu.data.io import load_game_dataset
 from photon_ml_tpu.data.validators import (DataValidationLevel,
@@ -192,20 +191,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "solver=sdca|sgd runs the duality-gap-certified "
                         "stochastic solvers over the same chunk feed, "
                         "docs/STREAMING.md)")
-    p.add_argument("--sweep", nargs="?", const="",
-                   help="dirty-gated incremental sweeps for random-effect "
-                        "coordinates (docs/SWEEPS.md): outer iterations "
-                        "past min_sweeps_full refit only entities whose "
-                        "residual offsets drifted past theta or whose "
-                        "last solve left gradient norm above grad_tol, "
-                        "compacted into dense active waves, with "
-                        "incremental residual rescoring. Mini-DSL "
-                        "'theta=1e-4,grad_tol=1e-5,min_sweeps_full=1,"
-                        "final_full=true,gram=false' (bare --sweep takes "
-                        "every default — gate=0, bit-identical to an "
-                        "ungated run; gram=true reuses per-bucket "
-                        "normal-equation blocks for squared-loss bucket "
-                        "solves)")
     p.add_argument("--ingest-cache-dir",
                    help="persist decoded Avro columns here (columnar "
                         "mmap ingest cache, keyed by file identity + "
@@ -630,10 +615,7 @@ def _run(args) -> dict:
         ingest=_ingest_config(args) if args.avro_feature_shard else None,
         streaming=(parse_streaming_config(args.streaming)
                    if getattr(args, "streaming", None) is not None
-                   else None),
-        sweep=(parse_sweep_config(args.sweep)
-               if getattr(args, "sweep", None) is not None
-               else None))
+                   else None))
     device = device_summary()
     logger.info("running on platform=%s device_kind=%s devices=%d",
                 device["platform"], device["kind"], device["count"])

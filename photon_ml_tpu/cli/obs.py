@@ -818,9 +818,8 @@ def _overlay(curve_a: list, curve_b: list, x_key: str,
 
 
 def _fit_wave_table(entry: dict) -> list[str]:
-    """Per-outer-iteration entities_fit/skipped/seconds table for one
-    coordinate's ``re_fit_wave`` aggregates — where a gated-vs-full run
-    pair's wall time went (docs/SWEEPS.md). A plain table, not an
+    """Per-outer-iteration entities_fit/seconds table for one
+    coordinate's ``re_fit_wave`` aggregates. A plain table, not an
     _overlay: lane counts are discrete per-iteration totals, not a
     convergence curve."""
     wa = {w["outer_iteration"]: w for w in entry.get("fit_waves_a", ())}
@@ -828,13 +827,12 @@ def _fit_wave_table(entry: dict) -> list[str]:
 
     def _cells(w):
         if w is None:
-            return f"{'-':>9} {'-':>9} {'-':>8}"
-        return (f"{w['entities_fit']:>9} {w['entities_skipped']:>9} "
-                f"{w['seconds']:>8.3f}")
+            return f"{'-':>9} {'-':>8}"
+        return f"{w['entities_fit']:>9} {w['seconds']:>8.3f}"
 
     lines = ["  entities fit per outer iteration (A | B):",
-             f"  {'iter':>6} {'A fit':>9} {'A skip':>9} {'A secs':>8}  "
-             f"{'B fit':>9} {'B skip':>9} {'B secs':>8}"]
+             f"  {'iter':>6} {'A fit':>9} {'A secs':>8}  "
+             f"{'B fit':>9} {'B secs':>8}"]
     for it in sorted(set(wa) | set(wb)):
         lines.append(f"  {it:>6} {_cells(wa.get(it))}  "
                      f"{_cells(wb.get(it))}")

@@ -32,12 +32,6 @@ STAGING_CACHE_SHARD_FILE = "staging_cache.shard_file"  # corrupt_file
 CHECKPOINT_SAVE = "checkpoint.save"
 CHECKPOINT_LOAD = "checkpoint.load"
 CHECKPOINT_ARTIFACT = "checkpoint.artifact"  # corrupt_file
-# SWEEP_GATE_STATE fires BEFORE the gated descent's dirty-set state
-# (``sweep/<cid>.npz``: offsets-at-last-fit + per-entity grad norms)
-# is written into a checkpoint commit (kill seam: a SIGKILL here must
-# leave the previous committed generation loadable, and a gated resume
-# from it must be bit-identical to an unkilled gated run).
-SWEEP_GATE_STATE = "sweep.gate_state"
 
 # -- streamed fixed-effect path (ops/streaming_sparse.py, optim/streaming.py,
 #    game/checkpoint.py StreamingStateStore) ---------------------------------

@@ -81,7 +81,6 @@ logger = logging.getLogger("photon_ml_tpu.game")
 _STATE = "state.json"
 _MODEL = "model"
 _RESIDUALS = "residuals.npz"
-_SWEEP_DIR = "sweep"
 _PREV = ".prev"
 _STREAM_STATE = "stream_state.npz"
 _STREAM_META = "stream_meta.json"
@@ -115,11 +114,6 @@ class CheckpointState:
     complete: bool  # descent finished; models are the final result
     fingerprint: Optional[dict]  # config the checkpoint was written under
     residual_total: Optional["np.ndarray"] = None  # (n,) score total
-    # cid -> {array name -> np.ndarray}: the gated descent's dirty-set
-    # evidence (game/sweep.py CoordinateSweepState.to_arrays) — restoring
-    # it makes a resumed GATED run re-derive the exact dirty sets an
-    # uninterrupted run would have used (bit-exact resume).
-    sweep_states: Optional[dict] = None
     recovered: bool = False  # True when this state came from the .prev
     #                          generation after a corruption fallback
 
@@ -175,7 +169,6 @@ class CheckpointManager:
         fingerprint: Optional[dict] = None,
         updated: Optional[list[str]] = None,
         residual_total: Optional["np.ndarray"] = None,
-        sweep_states: Optional[dict] = None,
     ) -> None:
         """Persist state. ``updated`` names the coordinates whose
         coefficients changed since the last save (all, if None or if the
@@ -194,16 +187,14 @@ class CheckpointManager:
             self._write(task, models, done_steps=done_steps,
                         records=records, complete=complete,
                         fingerprint=fingerprint, updated=updated,
-                        residual_total=residual_total,
-                        sweep_states=sweep_states)
+                        residual_total=residual_total)
         mx = obs.metrics()
         if mx is not None:
             mx.counter("photon_checkpoint_writes_total",
                        kind="descent").inc()
 
     def _write(self, task, models, *, done_steps, records, complete,
-               fingerprint, updated, residual_total,
-               sweep_states=None) -> None:
+               fingerprint, updated, residual_total) -> None:
         flt.fire(flt.sites.CHECKPOINT_SAVE)
         model_dir = os.path.join(self.directory, _MODEL)
         os.makedirs(model_dir, exist_ok=True)
@@ -240,33 +231,6 @@ class CheckpointManager:
             if os.path.exists(res_path):
                 os.remove(res_path)
             self._crcs.pop(_RESIDUALS, None)
-        # Gated-sweep dirty-set state: one npz per gated coordinate,
-        # under the same discipline as residuals.npz (atomic, .prev
-        # preserved, CRC'd, written before the commit point). The fire()
-        # is the chaos kill seam (docs/ROBUSTNESS.md ``sweep.gate_state``)
-        # — bit rot coverage rides the shared checkpoint.artifact hook
-        # inside _commit_file.
-        stale_sweep = {r for r in self._crcs if r.startswith(
-            _SWEEP_DIR + "/")}
-        if sweep_states:
-            flt.fire(flt.sites.SWEEP_GATE_STATE)
-            os.makedirs(os.path.join(self.directory, _SWEEP_DIR),
-                        exist_ok=True)
-            for cid, arrays in sweep_states.items():
-                rel = f"{_SWEEP_DIR}/{cid}.npz"
-                self._preserve(rel)
-                atomic_write(self._abs(rel),
-                             lambda f, a=arrays: np.savez(
-                                 f, **{k: np.asarray(v)
-                                       for k, v in a.items()}))
-                self._commit_file(rel)
-                stale_sweep.discard(rel)
-        for rel in stale_sweep:
-            try:
-                os.remove(self._abs(rel))
-            except OSError:
-                pass
-            self._crcs.pop(rel, None)
         # Commit point: state.json last, atomically — carrying the CRC of
         # every artifact this generation consists of.
         self._preserve(_STATE)
@@ -440,27 +404,6 @@ class CheckpointManager:
                     "checkpoint residuals at %s are unreadable (%s: %s) "
                     "— falling back to re-summation", res_path,
                     type(e).__name__, e)
-        # Gated-sweep state: only artifacts the committed generation
-        # vouches for (its CRC map) — a stale file from a discarded run
-        # must not seed dirty sets. Unreadable entries degrade to None
-        # for that coordinate (descent re-tracks from a forced full
-        # sweep — correct, just not bit-exact, and it logs that path).
-        sweep_states = None
-        for rel in (state.get("artifacts") or {}):
-            if not rel.startswith(_SWEEP_DIR + "/"):
-                continue
-            cid = os.path.basename(rel)[:-len(".npz")]
-            try:
-                with np.load(self._abs(rel), allow_pickle=False) as z:
-                    arrays = {k: z[k] for k in z.files}
-            except Exception as e:
-                logger.warning(
-                    "checkpoint sweep state %s is unreadable (%s: %s) — "
-                    "the coordinate re-tracks from a forced full sweep",
-                    rel, type(e).__name__, e)
-                continue
-            sweep_states = sweep_states or {}
-            sweep_states[cid] = arrays
         # Seed the CRC ledger so this process's next incremental save
         # carries forward the artifacts it does not rewrite.
         self._crcs = dict(state.get("artifacts") or {})
@@ -471,7 +414,6 @@ class CheckpointManager:
             complete=bool(state["complete"]),
             fingerprint=saved_fp,
             residual_total=residual_total,
-            sweep_states=sweep_states,
             recovered=recovered,
         )
 

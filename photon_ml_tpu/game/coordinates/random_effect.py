@@ -76,35 +76,6 @@ def _wave_rows(pending):
         yield "re_fit_wave", fields
 
 
-@jax.jit
-def _compact_tuple(sel, Xb, yb, wb, ex, rows, *extra):
-    """Gather the selected (dirty) lanes of one staged bucket tuple into
-    a dense active wave (game/sweep.py; docs/SWEEPS.md).
-
-    ``sel`` is (L',) int32 lane indices, -1-padded to the quantized
-    active-wave width. Padding lanes re-gather lane 0's data but are
-    neutralized the way bucket padding always is: rows → -1 (scatter
-    drop), ex → -1 (delta drop), weights → 0 (benign solve)."""
-    live = sel >= 0
-    take = jnp.maximum(sel, 0)
-    out = (jnp.take(Xb, take, axis=0),
-           jnp.take(yb, take, axis=0),
-           jnp.where(live[:, None], jnp.take(wb, take, axis=0), 0.0),
-           jnp.where(live[:, None], jnp.take(ex, take, axis=0), -1),
-           jnp.where(live, jnp.take(rows, take, axis=0), -1))
-    return out + tuple(jnp.take(a, take, axis=0) for a in extra)
-
-
-@jax.jit
-def _gram_block(Xb, wb):
-    """Per-lane normal-equation Gram block X^T diag(w) X, f32-accumulated
-    (the aggregators.hessian_matrix pattern). Built ONCE per staged tuple
-    and reused every sweep — the design matrices are fixed across outer
-    iterations, only the offsets move (ROADMAP item 4's named target)."""
-    Xf = Xb.astype(jnp.float32)
-    return jnp.einsum("eck,ec,ecm->ekm", Xf, wb, Xf)
-
-
 # The dense table's score, x_i · W[e_i], as the two programs it always was
 # (a row gather, a rowwise dot), now jitted so that they carry their scope.
 # NOT one program: at 10M x 8 rows the fused gather-and-dot reads 36.3 ms
@@ -265,20 +236,12 @@ class RandomEffectCoordinate:
         # first per-entity fits dispatch while later shards still project.
         self._bucket_data = []
         # Host copies of each staged tuple's (E_b,) entity-row map, in
-        # fit-stream order: the gated sweep path (train_model_gated)
-        # selects dirty lanes on host to build compacted active waves.
+        # fit-stream order: which lanes hold an entity (the refit counter
+        # and the wave rows' ``entities_fit``).
         self._host_rows: list[np.ndarray] = []
         # Beside them, each lane's true row count (the rest of its
         # ``cap`` slots is padding): the wave rows' ``rows_useful``.
         self._host_counts: list[np.ndarray] = []
-        self._gram_cache: dict[int, Array] = {}
-        # Lazy gating-support caches (see _bucket_census): per-entity row
-        # counts, the trained-entity mask, and whether segment rescoring
-        # is exact for this bucketing (no passive rows on trained
-        # entities — upper_bound capping breaks that).
-        self._entity_counts: Optional[np.ndarray] = None
-        self._trained_mask: Optional[np.ndarray] = None
-        self._segment_rescore_ok: Optional[bool] = None
         self._pending = None
         self._stager = None
         self.staging = staging or stg.StagingConfig()
@@ -462,7 +425,7 @@ class RandomEffectCoordinate:
                     if ai == 3:  # example map: rows each lane really has
                         self._host_counts.append(
                             (a >= 0).sum(axis=1).astype(np.int64))
-                    if ai == 4:  # entity rows: a host copy for gating
+                    if ai == 4:  # entity rows: which lanes are live
                         self._host_rows.append(np.array(a, copy=True))
                     tup.append(self._put(a))
                     ph["bytes"] += int(a.nbytes)
@@ -522,10 +485,6 @@ class RandomEffectCoordinate:
         W table stays in ORIGINAL space throughout.
         """
         num_entities = self.num_entities
-        # Gated-sweep programs close over the optimization config too —
-        # rebuild lazily after any config swap (with_optimization_config).
-        self._fit_bucket_gated = None
-        self._fit_bucket_gram = None
         if self.projection:
             self._fit_bucket, self._var_bucket = self._build_projected_fits()
             return
@@ -564,9 +523,7 @@ class RandomEffectCoordinate:
         the refit bit-identity invariant holds either way. Projected fits
         keep the XLA moves: their gathers route through per-entity column
         maps, a different access pattern (docs/KERNELS.md "What stays
-        XLA"). Shared by the full-sweep and gated-sweep program builders
-        — compacted active waves reuse the same movers at the quantized
-        wave width."""
+        XLA")."""
         num_entities = self.num_entities
         from photon_ml_tpu.ops import kernels as _kernels
         _reg = _kernels.registry()
@@ -824,7 +781,7 @@ class RandomEffectCoordinate:
         Projected path: transforms are per-entity inside the bucket fit,
         so W stays in original space throughout. Subspace path: same,
         with the table in (E, A) active-column layout — (E, d) never
-        exists. Shared by the full-sweep and gated-sweep train paths."""
+        exists."""
         if initial is None:
             shape = (self.subspace_cols.shape if self.subspace
                      else (self.num_entities, self.dim))
@@ -888,372 +845,20 @@ class RandomEffectCoordinate:
             led.defer(functools.partial(_wave_rows, pending))
         return self._finish_model(W)
 
-    def _wave_fields(self, wave: int, seconds: float, arrays, fit,
-                     skipped: int = 0, drift_p99=None) -> dict:
+    def _wave_fields(self, wave: int, seconds: float, arrays, fit) -> dict:
         """The host's half of one wave's ``re_fit_wave`` row (per-entity
         rows would be 1M-deep noise): the dispatch's shape and lane counts.
-        ``arrays`` is the dispatched tuple (None: nothing dispatched),
-        ``fit`` masks the staged lanes that were fit. ``seconds`` times
-        the ENQUEUE, not the device. The solver's half is counted inside
-        the program and joins at the ledger's drain (``_wave_rows``)."""
-        cap = int(self._bucket_data[wave][3].shape[1])
-        lanes = 0 if arrays is None else int(arrays[4].shape[0])
-        fields = dict(
+        ``arrays`` is the dispatched tuple, ``fit`` masks its lanes that
+        hold an entity. ``seconds`` times the ENQUEUE, not the device. The
+        solver's half is counted inside the program and joins at the
+        ledger's drain (``_wave_rows``)."""
+        cap = int(arrays[3].shape[1])
+        lanes = int(arrays[4].shape[0])
+        return dict(
             re_type=self.re_type, wave=wave, seconds=round(seconds, 6),
-            entities_fit=int(fit.sum()), entities_skipped=skipped,
-            cap=cap, lanes=lanes,
+            entities_fit=int(fit.sum()), cap=cap, lanes=lanes,
             rows_useful=int(self._host_counts[wave][fit].sum()),
             rows_padded=lanes * cap)
-        if drift_p99 is not None:
-            fields["drift_p99"] = round(drift_p99, 9)
-        return fields
-
-    # -- dirty-gated sweeps (game/sweep.py; docs/SWEEPS.md) ------------------
-
-    def _bucket_census(self) -> None:
-        """One host pass over the bucketing: per-entity row counts, the
-        trained-entity mask, and whether every trained entity's rows are
-        reachable through the bucket example maps (segment rescoring is
-        exact iff they are — ``upper_bound`` capping leaves passive rows
-        that ``score()`` covers but no ``ex`` map reaches)."""
-        if self._segment_rescore_ok is not None:
-            return
-        counts = np.bincount(
-            np.asarray(self.dataset.entity_ids[self.re_type]),
-            minlength=self.num_entities)
-        trained = np.zeros((self.num_entities,), bool)
-        active_rows = 0
-        for b in self.bucketing.buckets:
-            live = b.entity_rows >= 0
-            trained[b.entity_rows[live]] = True
-            active_rows += int(b.counts[live].sum())
-        self._entity_counts = counts
-        self._trained_mask = trained
-        self._segment_rescore_ok = \
-            int(counts[trained].sum()) == active_rows
-
-    def make_sweep_state(self):
-        """Fresh dirty-set state for this coordinate (descent start)."""
-        from photon_ml_tpu.game import sweep as swp
-
-        self._bucket_census()
-        scale = np.maximum(self._entity_counts, 1).astype(np.float32)
-        return swp.CoordinateSweepState(
-            self.num_entities, self._ids, scale, self._trained_mask)
-
-    def _gram_eligible(self) -> bool:
-        """Normal-equation reuse applies when the bucket solve IS a
-        ridge-regularized least-squares problem in the staged feature
-        space: squared loss, strictly positive L2 (the ridge term is what
-        makes the normal matrix positive-definite for entities with fewer
-        samples than features — at λ=0 the closed form is singular where
-        the iterative solver returns the min-norm solution), no L1 (no
-        prox in the closed form), no per-entity projection (G caches per
-        full-width lane), identity normalization (transformed == staged
-        space), and a Gram footprint that fits (E·d² elements).
-        Everything else falls back to the iterative solver — silently,
-        per the registry-fallback idiom."""
-        return (not self.projection
-                and self.loss.name == "squared"
-                and self.config.regularization.l2_weight() > 0.0
-                and self.config.regularization.l1_weight() == 0.0
-                and self.norm.factors is None
-                and self.norm.shifts is None
-                and self.num_entities * self.dim * self.dim <= (1 << 27))
-
-    def _gram_for_wave(self, wave: int, arrays) -> Array:
-        G = self._gram_cache.get(wave)
-        if G is None:
-            G = _gram_block(arrays[0], arrays[2])
-            self._gram_cache[wave] = G
-        return G
-
-    def _build_gated_fits(self) -> None:
-        """Jitted gated-wave programs: the same per-lane solves as the
-        full-sweep program plus (a) final per-lane gradient norms spilled
-        into the (E,) evidence vector and (b) the fit lanes' score-segment
-        deltas scatter-added into an (n,) delta accumulator — exactly 0.0
-        on rows of unfit entities, so ``total += delta`` preserves the f32
-        accumulation discipline on clean rows. SEPARATE executables from
-        ``_fit_bucket`` by design: the full-sweep program stays
-        byte-identical to HEAD, which is what makes the gate=0 rung of the
-        parity ladder bit-exact by construction."""
-        self._bucket_census()
-        num_entities = self.num_entities
-        n = int(self.dataset.num_rows)
-        seg_ok = bool(self._segment_rescore_ok)
-        max_it = self.config.optimizer.max_iterations
-
-        def seg_scatter(delta, Xb, ex, d_orig):
-            if not seg_ok:
-                return delta
-            if Xb.dtype == jnp.bfloat16:
-                seg = jnp.einsum("ecd,ed->ec", Xb,
-                                 d_orig.astype(jnp.bfloat16),
-                                 preferred_element_type=jnp.float32)
-            else:
-                seg = jnp.einsum("ecd,ed->ec", Xb, d_orig)
-            return delta.at[jnp.where(ex >= 0, ex, n)].add(
-                seg, mode="drop")
-
-        if not self.projection:
-            norm = self.norm
-            cfg = self.config
-
-            def solve_gn(X, y, w, o, w0):
-                batch = LabeledBatch(X, y, w, o)
-                vg, hvp, l1w = make_objective(
-                    self.loss, batch, norm, cfg.regularization,
-                    self.intercept_index, X.shape[-1])
-                opt_cfg = resolve_optimizer_config(
-                    cfg.optimizer, l1w is not None)
-                result = optimize(vg, w0, opt_cfg, hvp=hvp,
-                                  l1_weights=l1w)
-                return (result.w, result.grad_norm, result.iterations,
-                        result.evaluations)
-
-            vsolve = jax.vmap(solve_gn)
-            _gather_rows, _scatter_rows = self._row_movers()
-
-            def fit_gated(W, delta, gnorms, offsets, Xb, yb, wb, ex,
-                          rows):
-                with jax.named_scope("re.gather"):
-                    ob = offsets[jnp.maximum(ex, 0)]
-                    w0 = _gather_rows(W, rows)
-                with jax.named_scope("re.solve"):
-                    w_fit, gn, its, evals = vsolve(Xb, yb, wb, ob, w0)
-                    stats = _wave_stats(rows, its, evals, max_it)
-                with jax.named_scope("re.scatter"):
-                    W = _scatter_rows(W, rows, w_fit)
-                    safe = jnp.where(rows >= 0, rows, num_entities)
-                    gnorms = gnorms.at[safe].set(gn, mode="drop")
-                    # Score delta in ORIGINAL space: the staged Xb are raw
-                    # features, so x·Δw_orig is exactly the per-example
-                    # score movement score() would report.
-                    d_orig = (norm.model_to_original_space(w_fit)
-                              - norm.model_to_original_space(w0))
-                    delta = seg_scatter(delta, Xb, ex, d_orig)
-                return W, delta, gnorms, stats
-
-            self._fit_bucket_gated = jax.jit(fit_gated,
-                                             donate_argnums=(0, 1, 2))
-            self._fit_bucket_gram = self._build_gram_fit(seg_scatter)
-            return
-
-        # Projected/subspace variant — mirrors _build_projected_fits.
-        dim = self.dim
-        has_f = not (self.norm.factors is None and self.norm.shifts is None)
-        has_s = self.norm.shifts is not None
-        ii_proj = 0 if self.intercept_index is not None else None
-
-        def ctx_for(f, s):
-            if not has_f:
-                return NormalizationContext()
-            return NormalizationContext(factors=f, shifts=s,
-                                        intercept_index=ii_proj)
-
-        def solve_one_gn(X, y, w, o, w0_orig, f, s):
-            ctx = ctx_for(f, s)
-            w0 = ctx.model_to_transformed_space(w0_orig)
-            batch = LabeledBatch(X, y, w, o)
-            vg, hvp, l1w = make_objective(
-                self.loss, batch, ctx, self.config.regularization,
-                ii_proj, X.shape[-1])
-            opt_cfg = resolve_optimizer_config(
-                self.config.optimizer, l1w is not None)
-            result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w)
-            return (ctx.model_to_original_space(result.w), result.grad_norm,
-                    result.iterations, result.evaluations)
-
-        norm_axes = (0 if has_f else None, 0 if has_s else None)
-        vsolve = jax.vmap(solve_one_gn,
-                          in_axes=(0, 0, 0, 0, 0) + norm_axes)
-        subspace = self.subspace
-
-        def unpack(extra):
-            cols = extra[0]
-            f = extra[1] if has_f else None
-            s = extra[2 if has_f else 1] if has_s else None
-            return cols, f, s
-
-        def fit_gated(W, delta, gnorms, offsets, Xb, yb, wb, ex, rows,
-                      *extra):
-            cols, f, s = unpack(extra)
-            with jax.named_scope("re.gather"):
-                ob = offsets[jnp.maximum(ex, 0)]
-                safe_rows = jnp.where(rows >= 0, rows, num_entities)
-                if subspace:
-                    da = cols.shape[1]
-                    w0 = W[jnp.maximum(rows, 0)][:, :da]
-                else:
-                    valid = (cols >= 0).astype(W.dtype)
-                    w0 = W[jnp.maximum(rows, 0)[:, None],
-                           jnp.maximum(cols, 0)] * valid
-                    safe_cols = jnp.where(cols >= 0, cols, dim)
-            with jax.named_scope("re.solve"):
-                w_fit, gn, its, evals = vsolve(Xb, yb, wb, ob, w0, f, s)
-                stats = _wave_stats(rows, its, evals, max_it)
-            with jax.named_scope("re.scatter"):
-                if subspace:
-                    w_pad = jnp.pad(w_fit, ((0, 0), (0, W.shape[1] - da)))
-                    W = W.at[safe_rows].set(w_pad, mode="drop")
-                else:
-                    W = W.at[safe_rows].set(0.0, mode="drop")
-                    W = W.at[safe_rows[:, None], safe_cols].set(
-                        w_fit, mode="drop")
-                gnorms = gnorms.at[safe_rows].set(gn, mode="drop")
-                # Active-column delta: exact vs the full-row difference
-                # because gated waves always follow >= 1 full sweep
-                # (min_sweeps_full), which leaves no inactive-column mass
-                # (projectBackward).
-                delta = seg_scatter(delta, Xb, ex, w_fit - w0)
-            return W, delta, gnorms, stats
-
-        self._fit_bucket_gated = jax.jit(fit_gated,
-                                         donate_argnums=(0, 1, 2))
-        self._fit_bucket_gram = None
-
-    def _build_gram_fit(self, seg_scatter):
-        """Closed-form gated wave for the squared-loss ridge problem:
-        (G + λ·diag(mask)) w = X^T(w_ex·(y − o)) with the per-lane Gram
-        block G = X^T diag(w_ex) X cached across sweeps (_gram_for_wave).
-        The gradient norm spilled as evidence is ‖A w − rhs‖ — the true
-        objective gradient at the returned point, so a lane that fell
-        back (non-finite solve) stays dirty."""
-        if not self._gram_eligible():
-            return None
-        from photon_ml_tpu.optim.regularization import intercept_mask
-        num_entities = self.num_entities
-        l2 = float(self.config.regularization.l2_weight())
-        maskv = jnp.asarray(intercept_mask(self.dim, self.intercept_index))
-        _gather_rows, _scatter_rows = self._row_movers()
-
-        def gram_solve_one(G, X, y, w, o, w0):
-            Xf = X.astype(jnp.float32)
-            rhs = jnp.einsum("ck,c->k", Xf, w * (y - o))
-            A = G + l2 * jnp.diag(maskv)
-            w_new = jnp.linalg.solve(A, rhs)
-            w_new = jnp.where(jnp.all(jnp.isfinite(w_new)), w_new, w0)
-            gn = jnp.linalg.norm(A @ w_new - rhs)
-            return w_new, gn
-
-        vsolve = jax.vmap(gram_solve_one)
-
-        def fit_gram(W, delta, gnorms, offsets, G, Xb, yb, wb, ex, rows):
-            with jax.named_scope("re.gather"):
-                ob = offsets[jnp.maximum(ex, 0)]
-                w0 = _gather_rows(W, rows)
-            with jax.named_scope("re.solve"):
-                w_fit, gn = vsolve(G, Xb, yb, wb, ob, w0)
-            with jax.named_scope("re.scatter"):
-                W = _scatter_rows(W, rows, w_fit)
-                safe = jnp.where(rows >= 0, rows, num_entities)
-                gnorms = gnorms.at[safe].set(gn, mode="drop")
-                delta = seg_scatter(delta, Xb, ex, w_fit - w0)
-            # A closed form: no iterations to count, so no solver fields
-            # on this wave's ledger row.
-            return W, delta, gnorms, None
-
-        return jax.jit(fit_gram, donate_argnums=(0, 1, 2))
-
-    def train_model_gated(self, offsets, state, config, initial=None,
-                          force_full=False):
-        """Dirty-gated train (docs/SWEEPS.md): refit only entities whose
-        residual offsets drifted past ``theta·scale`` or whose last solve
-        left gradient mass above ``grad_tol``, compacted into dense
-        active waves; a 90%-converged sweep dispatches ~10% of the lanes.
-
-        Returns ``(model, delta, stats)``. ``delta`` is the (n,) score
-        delta to add into the residual total — exactly 0.0 on rows of
-        unfit entities — or None when segment rescoring is inexact for
-        this bucketing (``upper_bound`` leaves passive rows) and the
-        caller must rescore via ``score()``. ``force_full`` refits every
-        trained entity through the gated (evidence-spilling) programs —
-        the forced-full rungs of the parity ladder (warm-up sweeps and
-        the final backstop)."""
-        from photon_ml_tpu.game import sweep as swp
-
-        if initial is not None:
-            initial = self.adapt_initial(initial)
-        W = self._prepare_table(initial)
-        offsets = jnp.asarray(offsets)
-        n = int(self.dataset.num_rows)
-        if self._fit_bucket_gated is None:
-            self._build_gated_fits()
-        use_gram = config.gram and self._fit_bucket_gram is not None
-        dirty = drift = dirty_host = None
-        if not force_full and state.off_ref is not None:
-            dirty, drift = state.gate(offsets, config)
-            # Host-side lane selection: compacted wave shapes must be
-            # known on host to build/dispatch the programs.
-            dirty_host = np.asarray(dirty)
-        p99 = state.drift_p99(drift) if drift is not None else 0.0
-        delta = jnp.zeros((n,), jnp.float32)
-        gnorms = state.grad_norms
-        pad = self.bucketing.entity_pad_multiple
-        led = obs.ledger()
-        mx = obs.metrics()
-        pending = []  # this update's wave rows, for the ledger's drain
-        total_fit = total_skip = 0
-        for wave, arrays in enumerate(self._iter_bucket_data()):
-            rows_host = self._host_rows[wave]
-            live = rows_host >= 0
-            live_n = int(live.sum())
-            sel_dev = None
-            if dirty_host is None:
-                fit_lanes, skip_lanes = live_n, 0
-                args = arrays
-                lane_dirty = live
-            else:
-                lane_dirty = live & dirty_host[np.maximum(rows_host, 0)]
-                fit_lanes = int(lane_dirty.sum())
-                skip_lanes = live_n - fit_lanes
-            total_fit += fit_lanes
-            total_skip += skip_lanes
-            if mx is not None:
-                if fit_lanes:
-                    mx.counter("photon_re_entities_refit_total",
-                               re_type=self.re_type).inc(fit_lanes)
-                if skip_lanes:
-                    mx.counter("photon_re_entities_skipped_total",
-                               re_type=self.re_type).inc(skip_lanes)
-            if dirty_host is not None and fit_lanes == 0:
-                # Fully-converged wave: nothing dispatches at all.
-                if led is not None:
-                    pending.append((self._wave_fields(
-                        wave, 0.0, None, lane_dirty, skip_lanes, p99), None))
-                continue
-            t_wave = time.perf_counter()
-            with obs.annotated("re.fit_wave", cat="train", wave=wave,
-                               re_type=self.re_type):
-                if dirty_host is not None:
-                    idx = np.flatnonzero(lane_dirty)
-                    L = swp.compact_lanes(idx.size, pad, rows_host.size)
-                    sel = np.full((L,), -1, np.int32)
-                    sel[:idx.size] = idx.astype(np.int32)
-                    sel_dev = jnp.asarray(sel)
-                    args = _compact_tuple(sel_dev, *arrays)
-                if use_gram:
-                    G = self._gram_for_wave(wave, arrays)
-                    if sel_dev is not None:
-                        G = jnp.take(G, jnp.maximum(sel_dev, 0), axis=0)
-                    W, delta, gnorms, stats = self._fit_bucket_gram(
-                        W, delta, gnorms, offsets, G, *args)
-                else:
-                    W, delta, gnorms, stats = self._fit_bucket_gated(
-                        W, delta, gnorms, offsets, *args)
-            if led is not None:
-                pending.append((self._wave_fields(
-                    wave, time.perf_counter() - t_wave, args, lane_dirty,
-                    skip_lanes, p99), stats))
-        if led is not None:
-            led.defer(functools.partial(_wave_rows, pending))
-        state.grad_norms = gnorms
-        state.advance(offsets, None if dirty_host is None else dirty)
-        stats = {"entities_fit": total_fit,
-                 "entities_skipped": total_skip, "drift_p99": p99}
-        return (self._finish_model(W),
-                delta if self._segment_rescore_ok else None, stats)
 
     def compute_model_variances(
         self, model: RandomEffectModel, offsets: Array

@@ -65,7 +65,6 @@ def run(
     locked_coordinates: Optional[set[str]] = None,
     validation_fn: Optional[Callable[[GameModel], dict]] = None,
     checkpoint_manager=None,
-    sweep=None,
 ) -> tuple[GameModel, CoordinateDescentHistory]:
     """Run block coordinate descent (reference: CoordinateDescent.run).
 
@@ -87,19 +86,7 @@ def run(
     they disagree beyond accumulation noise (a kill between the model and
     residual writes can leave a newer model directory with older
     residuals — re-summation is always consistent with the model files).
-
-    ``sweep`` (game/sweep.py SweepConfig) turns on dirty-gated sweeps for
-    random-effect coordinates: outer iterations past ``min_sweeps_full``
-    refit only entities whose residual offsets drifted or whose last
-    solve left gradient mass, and the residual total updates
-    incrementally (``total += delta``, delta exactly 0.0 on clean rows).
-    ``gate=0`` (theta=0, grad_tol=0) is normalized to ``sweep=None`` so
-    the run takes THIS function's unmodified full-sweep expressions and
-    is bit-identical to an ungated run — the base rung of the parity
-    ladder (docs/SWEEPS.md).
     """
-    if sweep is not None and sweep.gate_zero:
-        sweep = None
     seq = list(config.update_sequence)
     unknown = [c for c in seq if c not in coordinates]
     if unknown:
@@ -118,13 +105,6 @@ def run(
     resume = None
     if checkpoint_manager is not None or led is not None:
         fingerprint = _fingerprint(task, coordinates, seq, config, locked, n)
-        if sweep is not None:
-            # Gated runs take different training steps (skipped entities,
-            # incremental rescoring), so their checkpoints are not
-            # interchangeable with full-sweep ones. Only added when
-            # tracking is on: sweep=None (and the gate=0 normalization
-            # above) keeps the fingerprint byte-identical to HEAD's.
-            fingerprint["sweep"] = _jsonable(sweep)
     if led is not None:
         # Stamp (or validate, on a --resume append) the run ledger's
         # identity from the SAME fingerprint machinery the checkpoint
@@ -156,28 +136,6 @@ def run(
                               None)
             if advance is not None:
                 advance(k)
-
-    # Dirty-set gating state, one per unlocked coordinate that supports
-    # it (RandomEffectCoordinate.make_sweep_state); fixed-effect and
-    # factored coordinates simply keep taking the full-sweep path.
-    sweep_states: dict[str, object] = {}
-    if sweep is not None:
-        for cid in seq:
-            if cid in locked:
-                continue
-            mk = getattr(coordinates[cid], "make_sweep_state", None)
-            if mk is not None:
-                sweep_states[cid] = mk()
-        if resume is not None and resume.sweep_states:
-            # Restore drift references + gradient evidence so the gated
-            # resume takes the SAME skip decisions an unkilled run would
-            # (bit-identical gated resume). A coordinate whose artifact
-            # was missing/unreadable keeps off_ref=None and re-tracks
-            # from a forced full sweep — correct, just less incremental.
-            for cid, st in sweep_states.items():
-                arrays = resume.sweep_states.get(cid)
-                if arrays is not None:
-                    st.restore(arrays)
 
     models: dict[str, CoordinateModel] = {}
     scores: dict[str, jnp.ndarray] = {}
@@ -291,41 +249,11 @@ def run(
                     # Residual offsets: everything except this
                     # coordinate.
                     offsets = base + total - scores[cid]
-                    st = sweep_states.get(cid)
-                    if st is None:
-                        model = coord.train_model(offsets,
-                                                  initial=models[cid])
-                        new_scores = coord.score(model)
-                        total = total + new_scores - scores[cid]
-                        scores[cid] = new_scores
-                    else:
-                        # Parity-ladder rungs: warm-up sweeps seed the
-                        # drift/gradient evidence, the final full sweep
-                        # is the correctness backstop.
-                        force_full = (
-                            it < sweep.min_sweeps_full
-                            or (sweep.final_full_sweep
-                                and it == config.iterations - 1))
-                        model, delta, _sstats = coord.train_model_gated(  # pml: allow[PML012] one loop iteration IS one whole gated sweep of the coordinate; its (E,) dirty-mask fetch selects the wave shapes and amortizes over every vmapped bucket solve it dispatches
-                            offsets, state=st, config=sweep,
-                            initial=models[cid], force_full=force_full)
-                        new_scores = coord.score(model)
-                        if delta is None:
-                            # Segment rescoring is inexact for this
-                            # bucketing (passive rows under upper_bound):
-                            # rescore fully. Unchanged entity rows give
-                            # bitwise-equal scores, so the difference is
-                            # still exactly 0.0 on clean rows.
-                            delta = new_scores - scores[cid]
-                        total = total + delta
-                        # The per-coordinate bookkeeping takes the FRESH
-                        # score, not scores[cid] + delta: resume rebuilds
-                        # scores from score(model), so the live run must
-                        # hold the same values or a killed-and-resumed
-                        # gated run drifts from an unkilled one by f32
-                        # association noise. Only the residual total is
-                        # incremental.
-                        scores[cid] = new_scores
+                    model = coord.train_model(offsets,
+                                              initial=models[cid])
+                    new_scores = coord.score(model)
+                    total = total + new_scores - scores[cid]
+                    scores[cid] = new_scores
                     models[cid] = model
                     _sync(total)
                     if led is not None:
@@ -358,8 +286,7 @@ def run(
                         # pml: allow[PML001] checkpoint persistence NEEDS the
                         # host copy, once per coordinate update (seconds of
                         # device work), and _sync already drained the stream
-                        updated=[cid], residual_total=np.asarray(total),
-                        sweep_states=_sweep_arrays(sweep_states))
+                        updated=[cid], residual_total=np.asarray(total))
                     # The step committed: its mid-step stream state is
                     # stale (a later resume starts AFTER this step).
                     clear = getattr(coord, "clear_step_checkpoint", None)
@@ -374,17 +301,8 @@ def run(
         checkpoint_manager.save(task, models, done_steps=step,
                                 records=history.records, complete=True,
                                 fingerprint=fingerprint,
-                                residual_total=np.asarray(total),
-                                sweep_states=_sweep_arrays(sweep_states))
+                                residual_total=np.asarray(total))
     return GameModel(task=task, models=models), history
-
-
-def _sweep_arrays(sweep_states: dict) -> Optional[dict]:
-    """Serialize live gating states for a checkpoint commit (None when
-    gating is off, keeping the artifact set byte-identical to HEAD's)."""
-    if not sweep_states:
-        return None
-    return {cid: st.to_arrays() for cid, st in sweep_states.items()}
 
 
 def _dataset_digest(ds) -> str:

@@ -42,12 +42,10 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
   validation metrics.
 * ``re_fit_wave`` — one vmapped random-effect fit-wave dispatch:
   re_type, wave index, ``seconds`` (the ENQUEUE's: dispatch is
-  asynchronous), ``entities_fit``/``entities_skipped`` lane counts, the
-  dispatch's shape (``cap``, ``lanes``, ``rows_useful``,
-  ``rows_padded``), the solver's own counts reduced on the device over
-  live lanes (``iters_sum``, ``iters_max``, ``evals_sum``,
-  ``lanes_at_cap``), and (gated sweeps, docs/SWEEPS.md) ``drift_p99`` —
-  the p99 per-entity residual-offset drift the gate saw this sweep.
+  asynchronous), the ``entities_fit`` lane count, the dispatch's shape
+  (``cap``, ``lanes``, ``rows_useful``, ``rows_padded``), and the
+  solver's own counts reduced on the device over live lanes
+  (``iters_sum``, ``iters_max``, ``evals_sum``, ``lanes_at_cap``).
   Written through :meth:`RunLedger.defer`: the counts are read once per
   update, after the descent loop's barrier.
 * ``phase`` — one set-up phase, written as it ends: ``name``
@@ -712,9 +710,9 @@ def final_validation_metrics(rows: list[dict]) -> dict:
 
 def fit_wave_summary(rows: list[dict]) -> dict:
     """Per-(coordinate, outer iteration) aggregation of ``re_fit_wave``
-    rows: lane counts fit/skipped, wave seconds, and the max drift_p99
-    the gate saw. The ``photon-obs diff`` entities_fit overlay's data —
-    recorded by every random-effect train call, gated or not."""
+    rows: lanes fit, wave seconds and wave count. The ``photon-obs
+    diff`` entities_fit table's data — recorded by every random-effect
+    train call."""
     agg: dict = {}
     for row in rows:
         if row.get("kind") != "re_fit_wave":
@@ -723,15 +721,11 @@ def fit_wave_summary(rows: list[dict]) -> dict:
         it = int(row.get("outer_iteration") or 0)
         e = agg.setdefault(coord, {}).setdefault(
             it, {"outer_iteration": it, "entities_fit": 0,
-                 "entities_skipped": 0, "seconds": 0.0, "waves": 0,
-                 "drift_p99": 0.0})
+                 "seconds": 0.0, "waves": 0})
         e["entities_fit"] += int(row.get("entities_fit") or 0)
-        e["entities_skipped"] += int(row.get("entities_skipped") or 0)
         e["seconds"] = round(e["seconds"] + float(row.get("seconds") or 0.0),
                              6)
         e["waves"] += 1
-        e["drift_p99"] = max(e["drift_p99"],
-                             float(row.get("drift_p99") or 0.0))
     return {coord: [per_it[k] for k in sorted(per_it)]
             for coord, per_it in agg.items()}
 
@@ -741,8 +735,7 @@ def diff_ledgers(dir_a: str, dir_b: str,
     """Compare two run ledgers: config delta, per-coordinate
     time-to-target (target = the WORSE of the two final values, so both
     runs reached it), value-vs-wall / value-vs-passes curve overlays,
-    and final value/metric deltas. The ``photon-obs diff`` engine, also
-    consumed by check_bench_regression's convergence gate."""
+    and final value/metric deltas. The ``photon-obs diff`` engine."""
     out: dict = {"a": dir_a, "b": dir_b}
     man_a, man_b = read_manifest(dir_a), read_manifest(dir_b)
     if man_a is None or man_b is None:

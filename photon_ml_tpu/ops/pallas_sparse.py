@@ -5,11 +5,11 @@ every Pallas program now — the scatter that used to live here is
 ops/kernels/ell_scatter.py (registry name ``ell_scatter``), unchanged
 tile-for-tile. This module keeps the original import path and the
 original jitted ``scatter_rowterm(indices, rowterm_values, dim,
-interpret=)`` signature for its existing callers (bench.py, tests);
+interpret=)`` signature for its one remaining caller (a parity test);
 production dispatch goes through the registry via
 ops/sparse_aggregators.py, which is where the flag/fallback policy
 lives. Calling this wrapper is an EXPLICIT request for the Pallas
-program (a bench lane, a parity fixture) — no flag, no fallback.
+program (a parity fixture) — no flag, no fallback.
 """
 
 from __future__ import annotations

@@ -182,6 +182,37 @@ def test_random_effect_untrained_entities_score_zero(rng, mesh):
     assert np.all(s[mask] == 0.0)
 
 
+def test_squared_loss_bucket_solve_matches_ridge_closed_form(rng, mesh):
+    """Squared loss + L2 makes every entity's problem a ridge regression:
+    the iterative vmapped bucket solve must land on the normal equations'
+    solution (X^T W X + lam*mask)^-1 X^T W (y - o), worked here in float64
+    numpy per entity — offsets, weights and the unpenalized intercept
+    included."""
+    ds = _tiny_game(rng, n=900)
+    ds.response = rng.normal(size=ds.num_rows).astype(np.float32)
+    ds.weights = rng.uniform(0.5, 2.0, ds.num_rows).astype(np.float32)
+    lam = 0.5
+    coord = RandomEffectCoordinate(ds, "userId", "re_userId", losses.SQUARED,
+                                   _game_config(l2=lam, max_iter=80), mesh)
+    offsets = rng.normal(scale=0.3, size=ds.num_rows).astype(np.float32)
+    W = np.asarray(coord.train_model(jnp.asarray(offsets)).means)
+
+    ids = ds.entity_ids["userId"]
+    X = np.asarray(ds.feature_shards["re_userId"], np.float64)
+    mask = np.ones(X.shape[1])
+    if ds.intercept_index["re_userId"] is not None:
+        mask[ds.intercept_index["re_userId"]] = 0.0
+    trained = np.flatnonzero(coord.bucketing.trained_entities)
+    assert len(trained) >= 20
+    for e in trained:
+        m = ids == e
+        Xe, we = X[m], ds.weights[m].astype(np.float64)
+        A = Xe.T @ (we[:, None] * Xe) + lam * np.diag(mask)
+        rhs = Xe.T @ (we * (ds.response[m] - offsets[m]))
+        np.testing.assert_allclose(W[e], np.linalg.solve(A, rhs),
+                                   rtol=2e-3, atol=2e-3, err_msg=str(e))
+
+
 def test_fixed_effect_coordinate_trains_and_scores(rng, mesh):
     ds = _tiny_game(rng, n=1000)
     coord = FixedEffectCoordinate(ds, "global", losses.LOGISTIC,

@@ -48,7 +48,7 @@ MAX_IT = 6
 SEQ = ["fixed", "per-user"]
 WAVE_FIELDS = ("cap", "lanes", "rows_useful", "rows_padded", "iters_sum",
                "iters_max", "evals_sum", "lanes_at_cap", "entities_fit",
-               "entities_skipped", "seconds")
+               "seconds")
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
@@ -81,6 +81,19 @@ def game():
     return ds, coords
 
 
+def _variant(game, variant, **bounds):
+    """The game's coordinates with ``per-user`` rebuilt as another model
+    type (both projected builders' programs) or under active-data bounds."""
+    ds, coords = game
+    if variant == "dense" and not bounds:
+        return coords
+    kind = ({} if variant == "dense" else
+            dict(projection=True, subspace_model=variant == "subspace"))
+    return dict(coords, **{"per-user": RandomEffectCoordinate(
+        ds, "userId", "re_userId", losses.LOGISTIC, _opt(),
+        coords["per-user"].mesh, **kind, **bounds)})
+
+
 def _descend(coords, sweeps=2):
     model, _ = descent.run(
         TaskType.LOGISTIC_REGRESSION, coords,
@@ -105,12 +118,16 @@ def _with_ledger(tmp_path, coords, sweeps=2):
 
 # -- (a) the wave rows ---------------------------------------------------------
 
-def test_wave_rows_carry_the_solver_counts(game, tmp_path):
-    ds, coords = game
+@pytest.mark.parametrize("variant", ["dense", "projected", "subspace"])
+def test_wave_rows_carry_the_solver_counts(game, tmp_path, variant):
+    ds = game[0]
+    coords = _variant(game, variant)
     _, rows, _ = _with_ledger(tmp_path, coords)
     waves = [r for r in rows if r["kind"] == "re_fit_wave"]
     bucketing = coords["per-user"].bucketing
-    assert len(waves) == 2 * len(bucketing.buckets)
+    # one wave per staged tuple: a bucket, or a shard of one
+    assert len(waves) == 2 * len(coords["per-user"]._bucket_data) \
+        >= 2 * len(bucketing.buckets)
     for w in waves:
         for f in WAVE_FIELDS:
             assert f in w, (f, w)
@@ -127,6 +144,28 @@ def test_wave_rows_carry_the_solver_counts(game, tmp_path):
         assert sum(w["rows_useful"] for w in waves
                    if w["outer_iteration"] == sweep) == own
     assert own == ds.num_rows  # every row trains: no bound cuts this data
+
+
+@pytest.mark.parametrize("bound", ["lower_bound", "upper_bound"])
+def test_wave_rows_count_only_trained_entities(game, tmp_path, bound):
+    """Under an active-data bound some entities have no model and some rows
+    are passive (scored, never fit): the waves count neither."""
+    ds = game[0]
+    counts = np.bincount(ds.entity_ids["userId"])
+    coords = _variant(game, "dense", **{bound: int(np.median(counts))})
+    bucketing = coords["per-user"].bucketing
+    trained = int(bucketing.trained_entities.sum())
+    active = ds.num_rows - bucketing.num_passive_examples
+    assert bucketing.num_passive_examples > 0
+    assert (trained < len(counts)) == (bound == "lower_bound")
+    _, rows, _ = _with_ledger(tmp_path, coords)
+    waves = [r for r in rows if r["kind"] == "re_fit_wave"]
+    for sweep in (0, 1):
+        mine = [w for w in waves if w["outer_iteration"] == sweep]
+        assert sum(w["entities_fit"] for w in mine) == trained
+        assert sum(w["rows_useful"] for w in mine) == active
+        assert all(w["rows_useful"] <= w["entities_fit"] * w["cap"]
+                   for w in mine)
 
 
 def test_fixed_update_reports_its_evaluations(game, tmp_path):
@@ -352,10 +391,10 @@ def _lowered(jitted, *args):
     return jitted.lower(*args).as_text(debug_info=True)
 
 
-def test_fit_bucket_lowers_with_every_scope(game):
-    _, coords = game
-    coord = coords["per-user"]
-    W = jnp.zeros((coord.num_entities, coord.dim), jnp.float32)
+@pytest.mark.parametrize("variant", ["dense", "projected", "subspace"])
+def test_fit_bucket_lowers_with_every_scope(game, variant):
+    coord = _variant(game, variant)["per-user"].wait_staged()
+    W = coord._prepare_table(None)
     offsets = jnp.zeros((coord.dataset.num_rows,), jnp.float32)
     text = _lowered(coord._fit_bucket, W, offsets, *coord._bucket_data[0])
     for scope in ("re.gather", "re.solve", "re.scatter", "lbfgs.direction",
@@ -395,22 +434,6 @@ def test_direction_scope_names_the_unrolled_recursion(game):
                           lowered.compile().as_text())
     assert len(compiled) >= 10  # a rolled loop's body would leave a few
     assert all("re.solve" in scope_reduce.scopes_of(p) for p in compiled)
-
-
-def test_gated_program_lowers_with_the_same_scopes(game):
-    _, coords = game
-    coord = coords["per-user"]
-    coord._build_gated_fits()
-    n = coord.dataset.num_rows
-    text = _lowered(
-        coord._fit_bucket_gated,
-        jnp.zeros((coord.num_entities, coord.dim), jnp.float32),
-        jnp.zeros((n,), jnp.float32),
-        jnp.zeros((coord.num_entities,), jnp.float32),
-        jnp.zeros((n,), jnp.float32), *coord._bucket_data[0])
-    for scope in ("re.gather", "re.solve", "re.scatter",
-                  "lbfgs.line_search"):
-        assert scope in text, scope
 
 
 def test_fixed_fit_and_scores_lower_with_their_scopes(game):
@@ -617,8 +640,7 @@ def test_reader_finds_nothing_in_an_older_ledger(metric):
     """Rows as the parent commit writes them: no solver counters on the
     waves, no evaluations, no phases — None, and no exception."""
     old = [{"kind": "re_fit_wave", "coordinate": c, "outer_iteration": it,
-            "wave": 0, "seconds": 0.01, "entities_fit": 8,
-            "entities_skipped": 0, "seq": 1}
+            "wave": 0, "seconds": 0.01, "entities_fit": 8, "seq": 1}
            for c in ("per-user", "per-item") for it in (1, 2, 3)]
     old += [{"kind": "opt_iter", "coordinate": "fixed", "outer_iteration": 2,
              "iteration": 3, "value": 1.0, "seq": 2},

@@ -25,8 +25,8 @@ import pytest
 from photon_ml_tpu import faults, obs
 from photon_ml_tpu.obs.ledger import (LedgerError, RunLedger,
                                       build_manifest, convergence_curves,
-                                      diff_ledgers, identity_of,
-                                      read_manifest, read_rows,
+                                      diff_ledgers, fit_wave_summary,
+                                      identity_of, read_manifest, read_rows,
                                       spill_history, time_to_fraction,
                                       time_to_target, verify_ledger)
 from photon_ml_tpu.obs.watchdog import (ConvergenceWatchdog,
@@ -178,6 +178,28 @@ def test_curves_spill_and_time_to_target(tmp_path):
     assert ttf is not None and ttf["target_value"] == pytest.approx(
         1.0 + 0.01 * 9.0)
     assert time_to_target(curve, 0.5) is None  # never got there
+
+
+def test_fit_wave_summary_aggregates_per_iteration():
+    rows = [
+        {"kind": "re_fit_wave", "coordinate": "per-user",
+         "outer_iteration": 0, "wave": 0, "seconds": 0.5,
+         "entities_fit": 8},
+        {"kind": "re_fit_wave", "coordinate": "per-user",
+         "outer_iteration": 0, "wave": 1, "seconds": 0.25,
+         "entities_fit": 4},
+        {"kind": "re_fit_wave", "coordinate": "per-user",
+         "outer_iteration": 1, "wave": 0, "seconds": 0.1,
+         "entities_fit": 2},
+        {"kind": "opt_iter", "coordinate": "per-user"},
+    ]
+    got = fit_wave_summary(rows)
+    assert list(got) == ["per-user"]
+    assert got["per-user"] == [
+        {"outer_iteration": 0, "entities_fit": 12, "seconds": 0.75,
+         "waves": 2},
+        {"outer_iteration": 1, "entities_fit": 2, "seconds": 0.1,
+         "waves": 1}]
 
 
 # ---------------------------------------------------------------- watchdog

@@ -743,8 +743,10 @@ def test_staging_cache_keys_on_content(mesh, tmp_path):
                                 upper_bound=2)
     keys = {c._staging_cache_key for c in (c1, c2, c3)}
     assert len(keys) == 3
-    # A corrupt entry is a miss, not an error: truncate every array file.
+    # A corrupt entry is a miss, not an error: truncate every array file
+    # (once the pipeline's background cache writes have landed).
     import os
+    c1.wait_staged()
     entry = os.path.join(cache, c1._staging_cache_key)
     for f in os.listdir(entry):
         if f.endswith(".npy"):
@@ -757,6 +759,7 @@ def test_staging_cache_keys_on_content(mesh, tmp_path):
         np.asarray(c1b.train_model(off).means),
         np.asarray(c1.train_model(off).means), rtol=1e-5, atol=1e-6)
     # ...and the restage REPLACED the poisoned entry (no permanent miss).
+    c1b.wait_staged()
     assert staging_cache.load(cache, c1._staging_cache_key) is not None
 
 
