@@ -7,6 +7,7 @@ the residency discipline shared by all coordinate types.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -35,20 +36,84 @@ def _leaf_bytes(tree) -> int:
     return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
 
 
-# The share of one device's memory the resident hot block may take. A
-# placeholder: no sweep of the share against a sweep's seconds backs it
-# (PERF.md section 7 row 15); a quarter leaves the cold classes, the other
-# coordinates' buckets and the optimizer's vectors three.
-_HOT_SHARE_OF_DEVICE = 4
+# Eighths of the device memory FREE as the coordinate stages that the
+# resident hot block may take: one half. Backed by a sweep of the share
+# against a sweep's seconds at 2M click-log rows on one v5e (PERF.md section
+# 6, PR 32: 9.48 / 8.36 / 7.76 / 7.42 / 7.19 s at 2 to 6 eighths). Every
+# eighth still shortens a sweep, so memory binds, not time: one half is the
+# largest share whose step is worth its set-up (five eighths adds 7% to it
+# for 4.5% of a sweep) and whose peak leaves a third of the device to what
+# this coordinate cannot see: the coordinates staged after it, the
+# evaluation's scratch.
+_HOT_EIGHTHS_OF_FREE = 4
 
 
 def hot_block_budget(mesh) -> Optional[int]:
-    """Bytes the resident hot block may take on one device of ``mesh``;
-    None where the backend reports no limit (the CPU), and then the column
-    counts alone size the block."""
+    """Bytes the resident hot block may take on one device of ``mesh``:
+    half of what the device has free now (``bytes_limit`` less
+    ``bytes_in_use``), so that a device other tables already fill gets a
+    narrower block, not an allocation failure. None where the backend
+    reports no limit (the CPU), and then the column counts alone size the
+    block."""
     stats = mesh.devices.flat[0].memory_stats() or {}
     limit = int(stats.get("bytes_limit", 0))
-    return limit // _HOT_SHARE_OF_DEVICE if limit else None
+    if not limit:
+        return None
+    free = max(0, limit - int(stats.get("bytes_in_use", 0)))
+    return free * _HOT_EIGHTHS_OF_FREE // 8
+
+
+# Rows of the hot block cross to the device this many bytes at a time.
+_PUT_PART_BYTES = 1 << 28
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(block, part, start):
+    return jax.lax.dynamic_update_slice(block, part, (start, 0))
+
+
+def _put_in_parts(x: np.ndarray, sharding) -> Array:
+    """``x`` (rows, columns) on the device, sent in row parts. One
+    ``device_put`` of a host array over 4 GiB crosses to a v5e at 0.4-0.6
+    GB/s, one under it at 9 GB/s (PERF.md section 6, PR 32: 14 s against
+    0.9 s for the cell's 8.2 GB block). Each part is written in place into
+    the resident array and waited for, so the device holds one part beside
+    the array, never a second copy; the last part starts early enough to be
+    as long as the others, so one program serves them all."""
+    rows = _PUT_PART_BYTES // max(1, x.shape[1] * x.dtype.itemsize)
+    if rows >= x.shape[0]:
+        return jax.device_put(x, sharding)
+    block = jnp.zeros(x.shape, x.dtype, device=sharding)
+    for a in range(0, x.shape[0], rows):
+        a = min(a, x.shape[0] - rows)
+        block = _write_rows(
+            block, jax.device_put(x[a:a + rows], sharding), np.int32(a))
+        block.block_until_ready()
+    return block
+
+
+def _cold_column_counts(host) -> np.ndarray:
+    """Non-zeros of every cold column of a host layout, read off the layout
+    (a count over the shard's slots again costs 1.4 s at 78M of them). A
+    class row's live slots belong to the column ``chunk_cols`` names, where
+    it is a remainder chunk, and to ``class_starts + i`` past those; a
+    data shard's pad slots name the row past its last; a class of fewer
+    than 128 slots is held lane-major."""
+    pad_row = getattr(host, "rows_per_shard", -1)
+    rems = getattr(host, "class_rems", (0,) * len(host.class_lens))
+    counts = np.zeros(host.num_features - host.num_hot, np.int64)
+    off = 0
+    for start, L, rem, rows in zip(host.class_starts, host.class_lens, rems,
+                                   host.cold_rowids):
+        live = np.asarray(rows) != pad_row
+        columns = live.ndim - (2 if L >= 128 else 1)
+        per_row = live.sum(axis=tuple(
+            a for a in range(live.ndim) if a != columns))
+        if rem:
+            counts[np.asarray(host.chunk_cols[off:off + rem])] += per_row[:rem]
+        counts[start:start + per_row.size - rem] += per_row[rem:]
+        off += rem
+    return counts
 
 
 class SparseFixedEffectCoordinate:
@@ -72,9 +137,11 @@ class SparseFixedEffectCoordinate:
       feature space rides the MXU as a dense block and the cold tail's
       random crossings shrink to ~15% of the volume (measured ~4-10× the
       ELL step at d=1M and n=131072 on one v5e chip; at 2M rows, where
-      the block is sized from bytes and 30% of the non-zeros stay cold,
-      an evaluation takes 0.55 s against ~1.1 s for a plain ELL pass:
-      2×, PERF.md section 6, PR 29). Exact, not approximate: the
+      the block is sized from bytes, half of the chip's free memory
+      holds 1024 columns and 24% of the non-zeros stay cold, a pair of
+      passes takes 0.29 s against 0.36 s with 512 columns and ~1.1 s
+      for a plain ELL pass: PERF.md section 6, PRs 29-32). Exact, not
+      approximate: the
       solve happens in a statically permuted feature space and maps back.
       On one data shard L-BFGS's line search crosses the data twice an
       iteration whatever its trials (parallel/sparse_problem.py
@@ -171,17 +238,29 @@ class SparseFixedEffectCoordinate:
                         batch, feature_dtype=dt, device=False,
                         hot_block_bytes=budget)
             with obs.phase("fe.transfer") as ph:
-                self._staged = (
-                    sp.shard_hybrid(host, mesh) if self._hybrid_sharded
-                    else jax.device_put(host, self._replicated))
+                if self._hybrid_sharded:
+                    self._staged = sp.shard_hybrid(host, mesh)
+                else:
+                    self._staged = jax.device_put(
+                        dataclasses.replace(host, X_hot=_put_in_parts(
+                            host.X_hot, self._replicated)),
+                        self._replicated)
                 ph["bytes"] = _leaf_bytes(self._staged)
             self._ii_perm = (
                 None if self.intercept_index is None else int(
                     np.asarray(host.inv_perm)[self.intercept_index]))
             led = obs.ledger()
             if led is not None:
+                # What bound the block: the bytes offered, the columns the
+                # count threshold asks for (before bytes or max_hot cut
+                # them: the planner with neither; every hot column clears
+                # the threshold), and what was built.
                 led.record(
                     "fe_layout", shard=shard_id, num_hot=host.num_hot,
+                    hot_budget_bytes=budget,
+                    hot_candidates=host.num_hot + hybrid_mod.plan_resident_hot(
+                        _cold_column_counts(host), dataset.num_rows, dt,
+                        max_hot=self._dim),
                     hot_bytes=int(host.X_hot.nbytes),
                     hot_entries=host.entries[0],
                     cold_entries=host.entries[1],

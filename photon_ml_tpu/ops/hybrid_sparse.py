@@ -19,8 +19,8 @@ fewer elements through the random path.
 
 CTR feature spaces are Zipf-distributed: on the benchmark's zipf(1.3)
 synthetic, the hottest ~1–2k of 1M columns carry ~85% of all nonzeros
-(at 2M rows of 39 hashed click-log fields, where a quarter of a v5e's
-memory holds 512 columns, they carry 70%).
+(at 2M rows of 39 hashed click-log fields, where half of a v5e's memory
+holds 1024 columns, they carry 76%; 512 columns carry 70%).
 The hybrid split exploits that:
 
 - **Hot columns** (count ≥ ``hot_threshold``, at most ``max_hot``) are
@@ -249,8 +249,11 @@ def build_hybrid(
     samples/s) — n/4096 is kept. That split was swept at n=131072, where
     ``max_hot`` columns are ~2 GB; at a deployment's rows the block is
     sized from BYTES (``plan_resident_hot``: ``hot_block_bytes``, which
-    the coordinate derives from its mesh's device) and the columns past it
-    stay cold, so neither the host nor the device ever holds more than that.
+    the coordinate derives from what its mesh's device has free) and the
+    columns past it stay cold, so neither the host nor the device ever
+    holds more than that. There memory binds, not time: at 2M click-log
+    rows the 1024th column is 0.3% non-zero and still worth four times its
+    read (PERF.md section 7 row 17).
     """
     indices = np.asarray(batch.indices)
     values = np.asarray(batch.values)

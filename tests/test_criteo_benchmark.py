@@ -163,8 +163,8 @@ def test_the_guard_refuses_a_layout_that_does_not_fit(monkeypatch):
         devices = np.array([Chip()], object)
 
     plan = game_criteo.resident_plan(Mesh(), "float32")
-    assert plan["num_hot"] == 384 and plan["hot_bytes"] == 384 * n * 4
-    assert plan["hot_bytes"] <= (16 << 30) // 4
+    assert plan["num_hot"] == 768 and plan["hot_bytes"] == 768 * n * 4
+    assert plan["hot_bytes"] <= (16 << 30) // 2  # half of what is free
     # the parent's program: its coordinate derives no budget
     monkeypatch.delattr(sparse_fixed, "hot_block_budget")
     plan = game_criteo.resident_plan(Mesh(), "float32")

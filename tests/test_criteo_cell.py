@@ -20,7 +20,8 @@ import pytest
 from photon_ml_tpu import obs
 from photon_ml_tpu.data import sparse as sp_data
 from photon_ml_tpu.data.sparse import SparseBatch
-from photon_ml_tpu.game.coordinates import SparseFixedEffectCoordinate
+from photon_ml_tpu.game.coordinates import (SparseFixedEffectCoordinate,
+                                             sparse_fixed)
 from photon_ml_tpu.obs.ledger import read_rows
 from photon_ml_tpu.ops import hybrid_sparse as hs
 from photon_ml_tpu.ops import losses
@@ -102,6 +103,24 @@ def _batch(data) -> SparseBatch:
 
 # -- the byte-sized hot block -------------------------------------------------
 
+V5E_BYTES = 16_909_336_064  # ``bytes_limit`` of one v5e chip
+
+
+class _Device:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+class _Mesh:
+    """A stand-in for a mesh over one device that reports ``stats``."""
+
+    def __init__(self, stats):
+        self.devices = np.array([_Device(stats)], object)
+
+
 @pytest.mark.parametrize("k", [8, 64, 128, 384])
 def test_a_budget_that_admits_k_columns_yields_k(k):
     rows = 10_000
@@ -134,7 +153,9 @@ def test_the_split_at_the_old_size_is_what_it_was():
     rng = np.random.default_rng(0)
     counts = np.bincount(rng.zipf(1.3, size=n * 39) % (1 << 20),
                          minlength=1 << 20)
-    v5e = 16_909_336_064 // 4  # what the coordinate gives on that chip
+    # what the coordinate gives on that chip
+    v5e = sparse_fixed.hot_block_budget(_Mesh({"bytes_limit": V5E_BYTES}))
+    assert v5e > 8e9
     for dt, div in ((jnp.float32, 2048), (jnp.bfloat16, 4096)):
         want = min(4096, int((counts >= n // div).sum()))
         for budget in (None, v5e):
@@ -146,28 +167,64 @@ def test_the_split_at_the_old_size_is_what_it_was():
                                 hot_block_bytes=v5e) == 4096
 
 
-def test_the_coordinate_takes_the_budget_from_its_mesh():
-    """A quarter of what the mesh's device reports; the CPU reports nothing,
-    and then the counts alone decide, as before the block had a budget."""
-    from photon_ml_tpu.game.coordinates import sparse_fixed
+@pytest.mark.parametrize("stats, budget, columns", [
+    pytest.param({"bytes_limit": V5E_BYTES, "bytes_in_use": 0},
+                 8_454_668_032, 1024, id="an-empty-chip"),
+    pytest.param({"bytes_limit": V5E_BYTES},
+                 8_454_668_032, 1024, id="no-bytes-in-use-reported"),
+    pytest.param({"bytes_limit": V5E_BYTES, "bytes_in_use": 330_000_000},
+                 8_289_668_032, 1024, id="the-table-staged-first"),
+    pytest.param({"bytes_limit": V5E_BYTES, "bytes_in_use": 12_000_000_000},
+                 2_454_668_032, 256, id="a-chip-other-tables-fill"),
+    pytest.param({"bytes_limit": V5E_BYTES, "bytes_in_use": V5E_BYTES + 1},
+                 0, 8, id="nothing-free"),
+    pytest.param(None, None, 4096, id="no-stats"),
+    pytest.param({}, None, 4096, id="empty-stats"),
+    pytest.param("cpu", None, 4096, id="the-cpu-mesh"),
+])
+def test_the_coordinate_takes_the_budget_from_its_mesh(stats, budget,
+                                                       columns):
+    """Half of what the mesh's device has free as it is asked; the CPU
+    reports no limit, and then the counts alone decide, as before the block
+    had a budget. ``columns`` is what the planner makes of the budget at the
+    cell's 2M rows when every column clears the count threshold: whole lane
+    tiles where the bytes bind, ``max_hot`` where they do not."""
+    mesh = (make_mesh(devices=jax.devices()[:1]) if stats == "cpu"
+            else _Mesh(stats))
+    got = sparse_fixed.hot_block_budget(mesh)  # the guard's call: mesh alone
+    assert got == budget
+    rows = 2_000_000
+    counts = np.full(8192, rows, np.int64)
+    k = hs.plan_resident_hot(counts, rows, jnp.float32, hot_block_bytes=got)
+    assert k == columns
+    if budget:
+        assert k * rows * 4 <= budget and k % 128 == 0
 
-    class Device:
-        def __init__(self, stats):
-            self.stats = stats
 
-        def memory_stats(self):
-            return self.stats
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows, part_rows", [(1000, 1000), (1000, 999),
+                                             (1000, 256), (1000, 1),
+                                             (7, 1000)])
+def test_the_hot_block_crosses_in_row_parts_and_arrives_whole(
+        monkeypatch, rows, part_rows, dtype):
+    """Over 4 GiB one ``device_put`` takes the slow path, so the block goes
+    over in row parts written in place: whatever the part's length, the
+    array on the device is the host's, on the coordinate's own sharding."""
+    import ml_dtypes
+    from jax.sharding import NamedSharding, PartitionSpec
 
-    class Mesh:
-        def __init__(self, stats):
-            self.devices = np.array([Device(stats)], object)
-
-    assert sparse_fixed.hot_block_budget(
-        Mesh({"bytes_limit": 16_909_336_064})) == 4_227_334_016
-    assert sparse_fixed.hot_block_budget(Mesh(None)) is None
-    assert sparse_fixed.hot_block_budget(Mesh({})) is None
-    assert sparse_fixed.hot_block_budget(
-        make_mesh(devices=jax.devices()[:1])) is None  # the CPU
+    dt = np.dtype(ml_dtypes.bfloat16 if dtype == "bfloat16" else np.float32)
+    x = np.random.default_rng(rows + part_rows).standard_normal(
+        (rows, 24)).astype(dt)
+    monkeypatch.setattr(sparse_fixed, "_PUT_PART_BYTES",
+                        part_rows * 24 * dt.itemsize)
+    sharding = NamedSharding(make_mesh(devices=jax.devices()[:1]),
+                             PartitionSpec())
+    got = sparse_fixed._put_in_parts(x, sharding)
+    assert got.sharding == sharding and got.dtype == dt
+    assert np.array_equal(np.asarray(got), x)
+    empty = sparse_fixed._put_in_parts(x[:, :0], sharding)  # no hot column
+    assert empty.shape == (rows, 0)
 
 
 def _chunk_columns(hb) -> list[np.ndarray]:
@@ -598,6 +655,9 @@ def test_the_ledger_has_the_layout_the_phases_and_the_evaluations(run):
     lay = layout[0]
     assert lay["hot_entries"] + lay["cold_entries"] == 4000 * 39
     assert lay["hot_bytes"] == lay["num_hot"] * 4000 * 4
+    # the CPU offers no bytes and max_hot is far: the count threshold bound
+    assert lay["hot_budget_bytes"] is None
+    assert lay["hot_candidates"] == lay["num_hot"]
     assert lay["cold_slots"] == lay["cold_entries"] > 0 < lay["num_hot"]
     assert lay["cold_entries"] > lay["cold_chunks"] > 0  # chunks of 2^b >= 1
     # at most one class for every bit of the largest cold count
@@ -612,6 +672,37 @@ def test_the_ledger_has_the_layout_the_phases_and_the_evaluations(run):
         assert [r["iteration"] for r in its] == list(range(len(its)))
         assert [r for r in its if "evaluations" in r] == its[-1:]
         assert its[-1]["evaluations"] >= its[-1]["iteration"] + 1
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("budget, bound", [
+    (None, "threshold"), (4 * 4000 * 24, "bytes")])
+def test_the_layout_row_says_what_bound_the_block(run, tmp_path, monkeypatch,
+                                                  shards, budget, bound):
+    """``hot_budget_bytes`` is what the coordinate offered and
+    ``hot_candidates`` the columns over the count threshold before bytes or
+    ``max_hot`` cut them, read off the layout on either form of it: equal
+    to ``num_hot`` where the threshold bound the block, more where the
+    bytes did."""
+    data = run["data"]
+    counts = np.bincount(data.indices.reshape(-1), minlength=1 << 20)
+    monkeypatch.setattr(sparse_fixed, "hot_block_budget", lambda mesh: budget)
+    led = obs.RunLedger.create(str(tmp_path))
+    obs.set_ledger(led)
+    SparseFixedEffectCoordinate(
+        game_criteo.dataset(data), "global", losses.LOGISTIC, _opt(5),
+        make_mesh(devices=jax.devices()[:shards]))
+    obs.set_ledger(None)
+    led.close()
+    lay, = [r for r in read_rows(str(tmp_path))[0]
+            if r.get("kind") == "fe_layout"]
+    assert lay["hot_budget_bytes"] == budget
+    assert lay["hot_candidates"] == int((counts >= 8).sum())  # max(8, n/2048)
+    if bound == "threshold":
+        assert lay["num_hot"] == lay["hot_candidates"]
+    else:  # 24 columns fit a device's rows: 24 on one shard, 48 on two
+        assert lay["num_hot"] == 24 * shards < lay["hot_candidates"]
+    assert lay["hot_entries"] + lay["cold_entries"] == 4000 * 39
 
 
 def test_the_sparse_fit_lowers_with_every_scope(run):
