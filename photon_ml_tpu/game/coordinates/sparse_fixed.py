@@ -22,9 +22,10 @@ from photon_ml_tpu.game.models import FixedEffectModel
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.obs.ledger import spill_history
 from photon_ml_tpu.ops.losses import PointwiseLoss
-from photon_ml_tpu.optim.common import scoped
+from photon_ml_tpu.optim.common import OptimizerType, scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
+                                         resolve_optimizer_config,
                                          variances_from_diagonal)
 from photon_ml_tpu.optim.regularization import intercept_mask
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple
@@ -48,18 +49,44 @@ def _leaf_bytes(tree) -> int:
 _HOT_EIGHTHS_OF_FREE = 4
 
 
-def hot_block_budget(mesh) -> Optional[int]:
+# Vectors of d the compiled solve holds beside its 2m of history, as the
+# TPU's compiler counts its scratch at 54.7M columns (``memory_analysis``
+# of ``optim.lbfgs.minimize`` for a described v5e: 37 vectors under L-BFGS
+# and 44 under OWL-QN with m = 10, fragmentation included; PERF.md section
+# 6, PR 33), and the layout's two permutations.
+_SOLVER_VECTORS = {OptimizerType.LBFGS: 17, OptimizerType.OWLQN: 24,
+                   OptimizerType.TRON: 17}
+_LAYOUT_VECTORS = 2
+
+
+def solver_state_bytes(dim: int,
+                       config: GLMOptimizationConfiguration) -> int:
+    """Bytes the fixed effect's solve will hold on a device at ``dim``
+    columns under ``config``'s optimiser and history length, beside the
+    staged rows: what ``hot_block_budget`` takes off before it halves. 0.16
+    GB at d = 2**20; 10 GB, most of a v5e, at 54.7M under OWL-QN."""
+    opt = resolve_optimizer_config(
+        config.optimizer, config.regularization.l1_weight() > 0.0)
+    kind = OptimizerType(opt.optimizer_type)
+    history = 0 if kind == OptimizerType.TRON else 2 * opt.history_length
+    return 4 * int(dim) * (history + _SOLVER_VECTORS.get(kind, 0)
+                           + _LAYOUT_VECTORS)
+
+
+def hot_block_budget(mesh, solver_bytes: int = 0) -> Optional[int]:
     """Bytes the resident hot block may take on one device of ``mesh``:
     half of what the device has free now (``bytes_limit`` less
-    ``bytes_in_use``), so that a device other tables already fill gets a
-    narrower block, not an allocation failure. None where the backend
-    reports no limit (the CPU), and then the column counts alone size the
-    block."""
+    ``bytes_in_use``) once the fixed effect's own solve has its
+    ``solver_bytes``, so that a device other tables already fill, or a
+    coefficient space that fills it, gets a narrower block, not an
+    allocation failure. None where the backend reports no limit (the CPU),
+    and then the column counts alone size the block."""
     stats = mesh.devices.flat[0].memory_stats() or {}
     limit = int(stats.get("bytes_limit", 0))
     if not limit:
         return None
-    free = max(0, limit - int(stats.get("bytes_in_use", 0)))
+    free = max(0, limit - int(stats.get("bytes_in_use", 0))
+               - int(solver_bytes))
     return free * _HOT_EIGHTHS_OF_FREE // 8
 
 
@@ -227,7 +254,8 @@ class SparseFixedEffectCoordinate:
 
             dt = (jnp.bfloat16 if feature_dtype == "bfloat16"
                   else jnp.float32)
-            budget = hot_block_budget(mesh)
+            solver_bytes = solver_state_bytes(self._dim, config)
+            budget = hot_block_budget(mesh, solver_bytes)
             with obs.phase("fe.host_stage"):
                 if self._hybrid_sharded:
                     host = hybrid_mod.build_hybrid_shards(
@@ -251,15 +279,20 @@ class SparseFixedEffectCoordinate:
                     np.asarray(host.inv_perm)[self.intercept_index]))
             led = obs.ledger()
             if led is not None:
-                # What bound the block: the bytes offered, the columns the
-                # count threshold asks for (before bytes or max_hot cut
-                # them: the planner with neither; every hot column clears
-                # the threshold), and what was built.
+                # What bound the block: the bytes offered (after the
+                # solver's), the columns the count threshold asks for
+                # (before bytes or max_hot cut them: the planner with
+                # neither; every hot column clears the threshold), and
+                # what was built; and the columns any row touches.
+                cold_counts = _cold_column_counts(host)
                 led.record(
                     "fe_layout", shard=shard_id, num_hot=host.num_hot,
                     hot_budget_bytes=budget,
+                    solver_state_bytes=solver_bytes,
+                    touched_columns=host.num_hot + int(
+                        np.count_nonzero(cold_counts)),
                     hot_candidates=host.num_hot + hybrid_mod.plan_resident_hot(
-                        _cold_column_counts(host), dataset.num_rows, dt,
+                        cold_counts, dataset.num_rows, dt,
                         max_hot=self._dim),
                     hot_bytes=int(host.X_hot.nbytes),
                     hot_entries=host.entries[0],
@@ -320,7 +353,8 @@ class SparseFixedEffectCoordinate:
             # ledger's post-fit spill (tiny, device-resident, free when no
             # ledger is active).
             return (coef.means[:d_true], res.value_history,
-                    res.grad_norm_history, res.evaluations)
+                    res.grad_norm_history, res.evaluations,
+                    res.trials_history, res.nnz_history)
 
         @scoped("fe.fit")
         def fit_sampled(staged, idx, mult, offsets, w0):
@@ -337,11 +371,14 @@ class SparseFixedEffectCoordinate:
                                intercept_index=ii,
                                feature_sharded=fs, already_sharded=True)
             return (coef.means[:d_true], res.value_history,
-                    res.grad_norm_history, res.evaluations)
+                    res.grad_norm_history, res.evaluations,
+                    res.trials_history, res.nnz_history)
 
         @scoped("fe.score")
         def score_fn(staged, means):
-            # Staged offsets are zeros, so margins == X @ w exactly.
+            # The staged batch's offsets are zeros whatever the data's are
+            # (those reach a fit through descent's residual and are no
+            # part of a score), so margins == X @ w exactly.
             return sagg.margins(staged, means)
 
         self._fit = jax.jit(fit)
@@ -375,7 +412,7 @@ class SparseFixedEffectCoordinate:
                                       initial=Coefficients(w0),
                                       intercept_index_permuted=ii_perm)
             return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations)
+                    res.evaluations, res.trials_history, res.nnz_history)
 
         @scoped("fe.fit")
         def fit_sampled(hb, idx, mult, offsets, w0):
@@ -387,11 +424,14 @@ class SparseFixedEffectCoordinate:
                                       initial=Coefficients(w0),
                                       intercept_index_permuted=ii_perm)
             return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations)
+                    res.evaluations, res.trials_history, res.nnz_history)
 
         @scoped("fe.score")
         def score_fn(hb, means):
-            # Staged offsets are zeros, so margins == X @ w exactly.
+            # The staged batch's offsets are zeros whatever the data's are
+            # (``__init__``; the data's reach a fit through descent's
+            # residual, ``base + total − scores[cid]``, and are no part of
+            # a score), so margins == X @ w exactly.
             return hybrid_mod.margins(
                 hb, hybrid_mod.to_permuted_space(hb, means))
 
@@ -437,7 +477,7 @@ class SparseFixedEffectCoordinate:
                 loss, shbo, mesh, cfg, initial=Coefficients(w0),
                 intercept_index_permuted=ii_perm)
             return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations)
+                    res.evaluations, res.trials_history, res.nnz_history)
 
         @scoped("fe.fit")
         def fit_sampled(shb, idx, mult, offsets, w0):
@@ -450,11 +490,12 @@ class SparseFixedEffectCoordinate:
                 loss, shbo, mesh, cfg, initial=Coefficients(w0),
                 intercept_index_permuted=ii_perm)
             return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations)
+                    res.evaluations, res.trials_history, res.nnz_history)
 
         @scoped("fe.score")
         def score_fn(shb, means):
-            # Staged offsets are zeros, so margins == X @ w exactly; rows
+            # The staged offsets are zeros whatever the data's are (the
+            # single-shard ``score_fn`` says why), so margins == X @ w; rows
             # come back in flat padded global order.
             return sobj.make_hybrid_margins(mesh, shb)(means[shb.perm])
 
@@ -503,21 +544,34 @@ class SparseFixedEffectCoordinate:
         with obs.annotated("fe.fit", cat="train"):
             if rate < 1.0:
                 idx, mult = draw_down_sample(self, rate)
-                w, vals, gns, evals = self._fit_sampled(
+                w, *spill = self._fit_sampled(
                     self._staged, jnp.asarray(idx), jnp.asarray(mult),
                     self._padded_offsets(offsets), w0)
             else:
-                w, vals, gns, evals = self._fit(self._staged, offsets, w0)
+                w, *spill = self._fit(self._staged, offsets, w0)
         led = obs.ledger()
         if led is not None:
             # Post-fit spill of the compiled histories (one host read,
             # once per coordinate update) — docs/OBSERVABILITY.md. The
-            # update's evaluation count rides on the last row.
-            vals, gns, evals = jax.device_get((vals, gns, evals))
+            # update's evaluation count rides on the last row; an OWL-QN
+            # solve's rows carry its trials, the iterate's non-zeros and
+            # the passes over the shard's non-zeros they cost: two for the
+            # first evaluation, then a trial each and one for the accepted
+            # point's gradient where the solve has its ValueOracle (the
+            # one-shard hybrid layout), two a trial elsewhere.
+            vals, gns, evals, trials, nnz = jax.device_get(spill)
+            counts = None
+            if trials is not None:
+                oracle = self.hybrid and not self._hybrid_sharded
+                crossings = trials + 1 if oracle else 2 * trials
+                crossings[0] = 2
+                counts = {"trials": trials, "nnz": nnz,
+                          "crossings": crossings}
             spill_history(
                 led, vals, gns,
-                opt=self.config.optimizer.optimizer_type.value.lower(),
-                evaluations=int(evals))
+                opt=("owlqn" if counts else
+                     self.config.optimizer.optimizer_type.value.lower()),
+                evaluations=int(evals), counts=counts)
         return FixedEffectModel(shard_id=self.shard_id,
                                 coefficients=Coefficients(w))
 

@@ -465,20 +465,24 @@ def fabric_totals() -> dict:
 
 def spill_history(led: "RunLedger", values, grad_norms,
                   opt: str = "compiled",
-                  evaluations: Optional[int] = None) -> int:
+                  evaluations: Optional[int] = None,
+                  counts: Optional[dict] = None) -> int:
     """Spill a compiled optimizer's NaN-padded value/grad-norm histories
     as post-fit ``opt_iter`` rows (``clock: "post_fit"`` — row ``t`` is
     the spill time, so wall resolution is the coordinate update). The
     solve's ``evaluations`` (objective evaluations, line-search trials
-    included), when given, ride on the last row. Returns the number of
-    rows written."""
+    included), when given, ride on the last row; ``counts`` (name → one
+    whole number an iteration, as long as ``values``: OWL-QN's ``trials``,
+    ``nnz``, ``crossings``) on every row. Returns the number of rows
+    written."""
     rows = []
     for i, (v, g) in enumerate(zip(values, grad_norms)):
         v, g = float(v), float(g)
         if v != v:  # NaN padding past the executed iterations
             continue
         rows.append({"iteration": i, "value": v,
-                     "grad_norm": None if g != g else g})
+                     "grad_norm": None if g != g else g,
+                     **{k: int(c[i]) for k, c in (counts or {}).items()}})
     if rows and evaluations is not None:
         rows[-1]["evaluations"] = int(evaluations)
     for row in rows:

@@ -131,6 +131,10 @@ class HybridSparseBatch:
     # ledger's ``fe_layout`` row).
     entries: tuple[int, int] = dataclasses.field(
         default=(0, 0), metadata=dict(static=True))
+    # Columns some row touches: the head of the permuted space (the absent
+    # ones follow, in column order). 0 where it was not counted.
+    num_touched: int = dataclasses.field(
+        default=0, metadata=dict(static=True))
 
     @property
     def num_rows(self) -> int:
@@ -339,6 +343,7 @@ def build_hybrid(
         class_lens=tuple(class_lens),
         class_rems=tuple(c.size for c in rem_cols),
         entries=(int(counts[order_desc[:k]].sum()), int(c_row.size)),
+        num_touched=max(k, int((counts > 0).sum())),
     )
 
 
@@ -563,16 +568,48 @@ def build_hybrid_shards(
     )
 
 
+def _touched_head(hb) -> int:
+    """The touched columns' count where permuting them alone pays: where it
+    was counted and most columns have no row. A gather of d coefficients
+    costs a v5e 19-24 ns each, 1.0-1.3 s at 54.7M columns, three times a
+    descent sweep, for a vector whose 52.2M absent columns are zeros
+    (PERF.md section 6, PR 33); where nearly every column has a row (2**20
+    hashed ones) there is nothing to save, and 0 keeps the one gather."""
+    t = getattr(hb, "num_touched", 0)
+    return t if 0 < 2 * t <= hb.num_features else 0
+
+
 def to_permuted_space(hb, w: Array) -> Array:
     """Original-space (d,) vector → permuted space (once per fit).
-    Accepts either layout (HybridSparseBatch or HybridShards)."""
-    return w[hb.perm]
+    Accepts either layout (HybridSparseBatch or HybridShards). Where the
+    absent columns are most of the space and all 0 in ``w`` (a model
+    trained here under any regulariser, or from zeros), only the touched
+    head is gathered; a vector with anything there takes the whole gather,
+    as before: the same result either way."""
+    t = _touched_head(hb)
+    if not t:
+        return w[hb.perm]
+    head = w[hb.perm[:t]]
+    return jax.lax.cond(
+        jnp.count_nonzero(w) == jnp.count_nonzero(head),
+        lambda: jnp.concatenate(
+            [head, jnp.zeros((hb.num_features - t,), w.dtype)]),
+        lambda: jnp.concatenate([head, w[hb.perm[t:]]]))
 
 
 def to_original_space(hb, w_perm: Array) -> Array:
     """Permuted-space (d,) vector → original space (once per fit).
-    Accepts either layout (HybridSparseBatch or HybridShards)."""
-    return w_perm[hb.inv_perm]
+    Accepts either layout (HybridSparseBatch or HybridShards); the touched
+    head alone is scattered where ``to_permuted_space`` would gather it
+    alone."""
+    t = _touched_head(hb)
+    if not t:
+        return w_perm[hb.inv_perm]
+    return jax.lax.cond(
+        jnp.count_nonzero(w_perm[t:]) == 0,
+        lambda: jnp.zeros_like(w_perm).at[hb.perm[:t]].set(
+            w_perm[:t], unique_indices=True),
+        lambda: w_perm[hb.inv_perm])
 
 
 def _hot_matvec(X: Array, w: Array) -> Array:

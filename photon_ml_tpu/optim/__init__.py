@@ -23,6 +23,7 @@ from photon_ml_tpu.optim.regularization import (RegularizationContext,
 Array = jax.Array
 
 LineOracle = _lbfgs.LineOracle
+ValueOracle = _lbfgs.ValueOracle
 minimize_lbfgs = _lbfgs.minimize
 minimize_owlqn = _lbfgs.minimize_owlqn
 minimize_tron = _tron.minimize
@@ -35,14 +36,16 @@ def optimize(
     *,
     hvp: Optional[Hvp] = None,
     l1_weights: Optional[Array] = None,
-    line: Optional[LineOracle] = None,
+    line: "Optional[LineOracle | ValueOracle]" = None,
 ) -> OptResult:
     """Dispatch on OptimizerType (reference: OptimizerFactory.scala).
 
     ``value_and_grad`` must already include any L2 term (use ``with_l2``);
     ``l1_weights`` routes to OWL-QN; TRON additionally needs ``hvp``.
-    ``line`` is the same objective taken apart for L-BFGS's line search
-    (optim/lbfgs.py ``LineOracle``); the other optimizers do not ask it.
+    ``line`` is the same objective taken apart for the line search
+    (optim/lbfgs.py): a ``LineOracle`` for L-BFGS, a ``ValueOracle`` for
+    OWL-QN, and ``minimize`` refuses the one under the other; TRON has no
+    line search and does not ask it.
     """
     t = OptimizerType(config.optimizer_type)
     if t == OptimizerType.LBFGS:
@@ -52,7 +55,8 @@ def optimize(
     if t == OptimizerType.OWLQN:
         if l1_weights is None:
             raise ValueError("OWLQN requires l1_weights (else use LBFGS)")
-        return minimize_owlqn(value_and_grad, w0, l1_weights, config)
+        return minimize_owlqn(value_and_grad, w0, l1_weights, config,
+                              line=line)
     if t == OptimizerType.TRON:
         if hvp is None:
             raise ValueError("TRON requires a Hessian-vector product (hvp)")
@@ -71,7 +75,7 @@ def optimize(
 
 __all__ = [
     "OptResult", "OptimizerConfig", "OptimizerType", "ValueAndGrad", "Hvp",
-    "LineOracle",
+    "LineOracle", "ValueOracle",
     "RegularizationContext", "RegularizationType",
     "minimize_lbfgs", "minimize_owlqn", "minimize_tron", "optimize",
     "with_l2", "with_l2_hvp", "l1_weights_vector", "intercept_mask",

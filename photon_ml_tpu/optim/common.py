@@ -57,6 +57,8 @@ class OptimizerConfig:
 
     optimizer_type: OptimizerType = OptimizerType.LBFGS
     max_iterations: int = 100
+    # Relative tolerance of ``check_convergence``; 0 asks for no such test:
+    # the iteration cap is the solve's budget.
     tolerance: float = 1e-7
     # L-BFGS/OWL-QN history length (Breeze default m=10).
     history_length: int = 10
@@ -84,6 +86,11 @@ class OptResult:
     converged: Array  # bool
     value_history: Array  # (max_iterations + 1,), NaN past the end
     grad_norm_history: Array  # (max_iterations + 1,), NaN past the end
+    # OWL-QN only: (max_iterations + 1,) int32, −1 past the end: the trials
+    # of each iteration's line search (0 at the start) and the non-zero
+    # coefficients of the iterate it ended on
+    trials_history: Optional[Array] = None
+    nnz_history: Optional[Array] = None
 
 
 def scoped(name: str, fn: Optional[Callable] = None) -> Callable:
@@ -121,7 +128,16 @@ def check_convergence(
 
     Reference parity: Optimizer.scala convergence checks
     (``relativeTolerance`` on both loss delta and gradient norm).
+
+    A ``tolerance`` of 0 makes no test (the same program as before for any
+    other): the solve runs to its iteration cap, or until a line search
+    fails. A float32 objective of 7e5 resolves 0.06, so whether a warm
+    step's change passes 1e-7 of it is rounding's to say, and a solve then
+    takes 5 iterations or 25 by the flip of a coin (PERF.md section 6, PR
+    33): a job that budgets its sweeps by iterations says so with 0.
     """
+    if tolerance <= 0.0:
+        return jnp.asarray(False)
     grad_ok = grad_norm <= tolerance * jnp.maximum(initial_grad_norm, 1.0)
     val_ok = jnp.abs(value - prev_value) <= tolerance * jnp.maximum(
         jnp.abs(prev_value), 1e-12)

@@ -22,8 +22,9 @@ from jax.sharding import Mesh
 from photon_ml_tpu.data.sparse import SparseBatch
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.ops.losses import PointwiseLoss
-from photon_ml_tpu.optim import (LineOracle, OptResult, l1_weights_vector,
-                                 optimize, with_l2, with_l2_hvp)
+from photon_ml_tpu.optim import (LineOracle, OptResult, ValueOracle,
+                                 l1_weights_vector, optimize, with_l2,
+                                 with_l2_hvp)
 from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
@@ -68,19 +69,25 @@ def _pad_features(batch: SparseBatch, d_pad: int) -> SparseBatch:
         weights=batch.weights, offsets=batch.offsets, num_features=d_pad)
 
 
-def _hybrid_line(loss: PointwiseLoss, hb, l2: float,
-                 mask: Array) -> LineOracle:
+def _hybrid_line(loss: PointwiseLoss, hb, l2: float, mask: Array,
+                 owlqn: bool = False) -> "LineOracle | ValueOracle":
     """``run_hybrid``'s objective, Σ w·l(z) + ½·λ‖w∘mask‖² with z = offsets
-    + X·w, for L-BFGS's line search. An evaluation here is two crossings of
+    + X·w, for the line search. An evaluation here is two crossings of
     the cold classes (0.33 s at 2M rows of click logs against 11 ms for the
     hot block, PERF.md section 5), and a search takes one to a dozen trials
     as the data fall: along w + αd the margins are z + α·(X·d), so X·d is
     crossed once, a trial reads rows and columns and no feature, and the
     gradient is crossed once at the point accepted. Every iteration then
-    costs one evaluation's passes, on every data set."""
+    costs one evaluation's passes, on every data set. OWL-QN's trial points
+    π(w + αd) leave that line (``owlqn``): a trial crosses once for its own
+    margins and reads its value off the rows, and the gradient is crossed
+    once from the accepted trial's margins, so an iteration costs its
+    trials + 1 crossings where an evaluation a trial cost twice as many."""
     from photon_ml_tpu.ops import hybrid_sparse as hybrid
 
     def l2_terms(w):
+        if l2 == 0.0:
+            return 0.0, 0.0
         wm = w * mask
         return 0.5 * l2 * jnp.sum(wm * wm, axis=-1), l2 * wm
 
@@ -96,6 +103,19 @@ def _hybrid_line(loss: PointwiseLoss, hb, l2: float,
         f, r, reg_grad = at(z, w)
         return f, hybrid.row_gradient(hb, r) + reg_grad, z
 
+    if owlqn:
+        @scoped("glm.value_grad")
+        def value(w):
+            z = hybrid.margins(hb, w)
+            return at(z, w)[0], z
+
+        @scoped("glm.value_grad")
+        def gradient(w, z):
+            _, r, reg_grad = at(z, w)
+            return hybrid.row_gradient(hb, r) + reg_grad
+
+        return ValueOracle(start, value, gradient)
+
     @scoped("glm.value_grad")
     def along(z, w, d):
         return z, hybrid.products(hb, d), w, d
@@ -104,7 +124,7 @@ def _hybrid_line(loss: PointwiseLoss, hb, l2: float,
     def trial(ray, alpha):
         z, u, w, d = ray
         f, r, reg_grad = at(z + alpha * u, w + alpha * d)
-        return f, jnp.dot(r, u) + jnp.dot(reg_grad, d)
+        return f, jnp.dot(r, u) + (jnp.dot(reg_grad, d) if l2 else 0.0)
 
     @scoped("glm.value_grad")
     def accept(ray, alpha):
@@ -136,7 +156,11 @@ def run_hybrid(
     from photon_ml_tpu.ops import hybrid_sparse as hybrid
 
     dim = hb.num_features
-    mask = jnp.asarray(intercept_mask(dim, intercept_index_permuted))
+    # Made on the device: as a host array the mask is a constant of the
+    # program, 219 MB of it at 54.7M columns.
+    mask = jnp.ones((dim,), jnp.float32)
+    if intercept_index_permuted is not None:
+        mask = mask.at[intercept_index_permuted].set(0.0)
     reg = config.regularization
     l2 = reg.l2_weight()
 
@@ -146,8 +170,7 @@ def run_hybrid(
         lambda w, v: hybrid.hessian_vector(loss, w, v, hb), l2, mask)
 
     l1 = reg.l1_weight()
-    l1w = (jnp.asarray(l1 * intercept_mask(dim, intercept_index_permuted))
-           if l1 > 0.0 else None)
+    l1w = l1 * mask if l1 > 0.0 else None
     opt_cfg = resolve_optimizer_config(config.optimizer, l1w is not None)
 
     if initial is not None:
@@ -156,7 +179,7 @@ def run_hybrid(
         w0 = jnp.zeros((dim,), jnp.float32)
 
     result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w,
-                      line=_hybrid_line(loss, hb, l2, mask))
+                      line=_hybrid_line(loss, hb, l2, mask, l1w is not None))
 
     variances = None
     kind = VarianceComputationType(config.variance_computation)

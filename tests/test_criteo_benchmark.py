@@ -115,9 +115,9 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     the issue names, ten new metrics; every reader is found by name."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [c["name"] for c in bench["configs"]][-1] == (
+    assert [c["name"] for c in bench["configs"]][1] == (
         "glmix-criteo-1m-logistic")
-    assert [w["name"] for w in bench["workloads"]] == [
+    assert [w["name"] for w in bench["workloads"]][:2] == [
         "ml20m-logistic.steady", CELL]
     cell = run.load_cell(CELL)
     assert {m["name"] for m in cell["end_to_end"]} == {"sweep_s", "setup_s"}
@@ -128,7 +128,7 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     assert len(old) == 27 and not old & NEW_METRICS
     for m in cell["per_layer"]:
         assert callable(run.layer_reader(m["name"])), m["name"]
-        assert m["workloads"][-1] == CELL
+        assert CELL in m["workloads"][:2]  # where it was; later cells after
     conf = cell["configuration"]
     assert (conf["hashed_features"], conf["entity"]["count"],
             conf["entity"]["features"], conf["nonzeros_per_row"]) == (

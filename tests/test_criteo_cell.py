@@ -686,7 +686,8 @@ def test_the_layout_row_says_what_bound_the_block(run, tmp_path, monkeypatch,
     bytes did."""
     data = run["data"]
     counts = np.bincount(data.indices.reshape(-1), minlength=1 << 20)
-    monkeypatch.setattr(sparse_fixed, "hot_block_budget", lambda mesh: budget)
+    monkeypatch.setattr(sparse_fixed, "hot_block_budget",
+                        lambda mesh, solver_bytes=0: budget)
     led = obs.RunLedger.create(str(tmp_path))
     obs.set_ledger(led)
     SparseFixedEffectCoordinate(

@@ -5,6 +5,7 @@ Mirrors photon-lib ``LBFGSTest`` / ``TRONTest`` / ``OWLQNTest`` (SURVEY.md
 cross-checks (LBFGS and TRON reach the same optimum), OWL-QN sparsity.
 """
 
+import dataclasses
 import re
 
 import jax
@@ -491,3 +492,161 @@ def test_vmapped_solve_compiles_without_gather_or_scatter(owlqn, rng):
             r"dynamic-slice|dynamic_update_slice|dynamic-update-slice)\b",
             text)
         assert found == [], found
+
+
+# -- OWL-QN's ValueOracle, and the folded ring history (ISSUE 33) -----------
+
+def _poisson_l1_problem(rng, n=400, d=60):
+    """A sparse-ish Poisson regression with row offsets, as an objective
+    and as the pieces a ``ValueOracle`` is made of."""
+    X = jnp.asarray((rng.uniform(size=(n, d)) < 0.15) * rng.normal(
+        size=(n, d)) * 0.5, jnp.float32)
+    w_true = np.where(rng.uniform(size=d) < 0.3, rng.normal(size=d), 0.0
+                      ) * min(1.0, (60 / d) ** 0.5)
+    off = jnp.asarray(np.log(rng.geometric(0.6, size=n)), jnp.float32)
+    y = jnp.asarray(rng.poisson(np.exp(np.asarray(off) + np.asarray(X)
+                                       @ w_true - 1.0)), jnp.float32)
+    calls = {"gradient": 0}
+
+    def value_at(z):
+        return jnp.sum(jnp.exp(z) - y * z)
+
+    def vg(w):
+        calls["gradient"] += 1
+        z = X @ w + off
+        return value_at(z), X.T @ (jnp.exp(z) - y)
+
+    def start(w):
+        z = X @ w + off
+        return value_at(z), X.T @ (jnp.exp(z) - y), z
+
+    def trial(w):
+        z = X @ w + off
+        return value_at(z), z
+
+    def accept(w, z):
+        return X.T @ (jnp.exp(z) - y)
+
+    return vg, lbfgs.ValueOracle(start, trial, accept), d, calls
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["aged", "ring"])
+def test_owlqn_through_its_oracle_gives_the_value_and_grad_path_s_iterates(
+        ring, rng, monkeypatch):
+    """A trial's value from one pass, the gradient once at the accepted
+    point: the same iterates as an evaluation a trial, to rounding, and
+    ``evaluations`` still counts the values taken, 1 + the trials."""
+    if ring:
+        monkeypatch.setattr(lbfgs, "_RING_BYTES", 1)
+    vg, oracle, d, _ = _poisson_l1_problem(rng)
+    cfg = OptimizerConfig(max_iterations=40, tolerance=1e-9)
+    l1 = jnp.full((d,), 0.5)
+    plain = minimize_owlqn(vg, jnp.zeros(d), l1, cfg)
+    asked = minimize_owlqn(vg, jnp.zeros(d), l1, cfg, line=oracle)
+    assert int(asked.iterations) == int(plain.iterations) > 5
+    np.testing.assert_allclose(asked.value_history, plain.value_history,
+                               rtol=1e-6, equal_nan=True)
+    np.testing.assert_allclose(asked.w, plain.w, rtol=1e-4, atol=1e-6)
+    np.testing.assert_array_equal(asked.trials_history, plain.trials_history)
+    np.testing.assert_array_equal(asked.nnz_history, plain.nnz_history)
+    trials = np.asarray(asked.trials_history)
+    its = int(asked.iterations)
+    assert trials[0] == 0 and (trials[1:its + 1] >= 1).all()
+    assert (trials[its + 1:] == -1).all()
+    assert int(asked.evaluations) == 1 + trials[1:its + 1].sum()
+    nnz = np.asarray(asked.nnz_history)
+    assert nnz[0] == 0 and nnz[its] == int(np.sum(np.asarray(asked.w) != 0))
+    assert 0 < nnz[its] < d  # L1 pruned some and kept some
+
+
+def test_the_oracle_spares_every_trial_its_gradient(rng):
+    """Traced once, so Python-side counts are counts of what the program
+    holds: with the oracle the objective's own value_and_grad is never
+    called."""
+    vg, oracle, d, calls = _poisson_l1_problem(rng)
+    cfg = OptimizerConfig(max_iterations=10)
+    minimize_owlqn(vg, jnp.zeros(d), jnp.full((d,), 0.5), cfg, line=oracle)
+    assert calls["gradient"] == 0
+    minimize_owlqn(vg, jnp.zeros(d), jnp.full((d,), 0.5), cfg)
+    assert calls["gradient"] == 2  # the start, and the line search's body
+
+
+def test_each_optimizer_refuses_the_other_s_oracle(rng):
+    vg, oracle, d, _ = _poisson_l1_problem(rng)
+    line = lbfgs.LineOracle(oracle.start, None, None, None)
+    with pytest.raises(ValueError, match="OWL-QN takes a ValueOracle"):
+        lbfgs.minimize(vg, jnp.zeros(d), l1_weights=jnp.ones(d), line=line)
+    with pytest.raises(ValueError, match="L-BFGS takes a LineOracle"):
+        lbfgs.minimize(vg, jnp.zeros(d), line=oracle)
+    with pytest.raises(ValueError, match="ValueOracle"):
+        optimize(vg, jnp.zeros(d),
+                 OptimizerConfig(optimizer_type=OptimizerType.OWLQN),
+                 l1_weights=jnp.ones(d), line=line)
+
+
+@pytest.mark.parametrize("owlqn", [False, True], ids=["lbfgs", "owlqn"])
+def test_the_folded_ring_history_gives_the_aged_history_s_iterates(
+        owlqn, rng, monkeypatch):
+    """From ``_RING_BYTES`` up the solve runs on (rows, 1024) vectors with
+    its history written in place; d here is no multiple of the fold, so
+    pad coordinates exist and have to stay inert."""
+    vg, _, d, _ = _poisson_l1_problem(rng, n=1000, d=1500)
+    cfg = OptimizerConfig(max_iterations=30, tolerance=1e-9)
+    l1 = jnp.full((d,), 0.5) if owlqn else None
+    smooth = vg if owlqn else with_l2(vg, 1.0)
+    aged = lbfgs.minimize(smooth, jnp.zeros(d), cfg, l1_weights=l1)
+    monkeypatch.setattr(lbfgs, "_RING_BYTES", 4 * 10 * d)
+    ring = lbfgs.minimize(smooth, jnp.zeros(d), cfg, l1_weights=l1)
+    assert ring.w.shape == (d,)
+    assert int(ring.iterations) == int(aged.iterations) > 10  # wraps m = 10
+    np.testing.assert_allclose(ring.value_history, aged.value_history,
+                               rtol=2e-5, equal_nan=True)
+    np.testing.assert_allclose(ring.w, aged.w, rtol=2e-3, atol=2e-4)
+    if owlqn:
+        assert int(np.sum(np.asarray(ring.w) == 0)) > 0
+
+
+def test_a_small_or_batched_solve_keeps_the_aged_history(rng):
+    """The ring is for one large unbatched solve: every shape a cell's
+    vmapped bucket solves or its 2**20-column fixed effect has stays under
+    ``_RING_BYTES`` (their compiled programs do not change)."""
+    assert 10 * (1 << 20) * 4 < lbfgs._RING_BYTES <= 10 * 54_686_452 * 4
+    folded = lbfgs._fold(jnp.arange(3000, dtype=jnp.float32))
+    assert folded.shape == (8, 1024) and float(folded.sum()) == 3000 * 2999 / 2
+
+
+def test_a_trial_that_overflows_fails_alone(rng):
+    """Poisson: ``exp`` of a long step's margins is inf in float32. The
+    trial is refused and the step halved; under vmap a lane's inf stays in
+    its lane."""
+    X = jnp.asarray(rng.normal(size=(2, 50, 4)), jnp.float32)
+    y = jnp.asarray(rng.poisson(2.0, size=(2, 50)), jnp.float32)
+    off = jnp.stack([jnp.zeros(50), jnp.full((50,), 80.0)])  # lane 1: e^80
+
+    def solve(X, y, off):
+        def vg(w):
+            z = X @ w + off
+            return jnp.sum(jnp.exp(z) - y * z), X.T @ (jnp.exp(z) - y)
+        return lbfgs.minimize(with_l2(vg, 1.0), jnp.zeros(4),
+                              OptimizerConfig(max_iterations=50))
+
+    outs = jax.vmap(solve)(X, y, off)
+    alone = solve(X[0], y[0], off[0])
+    assert np.isfinite(np.asarray(outs.w)).all()
+    np.testing.assert_allclose(outs.w[0], alone.w, rtol=1e-5, atol=1e-6)
+    assert np.isfinite(float(outs.value[1]))
+    assert float(outs.value[1]) <= float(outs.value_history[1][0])
+
+
+def test_a_tolerance_of_zero_runs_the_iteration_cap(rng):
+    """``tolerance`` 0 makes no convergence test: the cap is the budget. Any
+    other tolerance is the test it was."""
+    vg, _, w_star = _quadratic(6, rng)
+    cfg = OptimizerConfig(max_iterations=12)
+    stopped = minimize_lbfgs(vg, jnp.zeros(6), cfg)
+    assert int(stopped.iterations) < 12 and bool(stopped.converged)
+    capped = minimize_lbfgs(vg, jnp.zeros(6), dataclasses.replace(
+        cfg, tolerance=0.0))
+    assert int(capped.iterations) == 12 and not bool(capped.converged)
+    np.testing.assert_allclose(capped.w, w_star, atol=1e-4)
+    assert float(capped.value) <= float(stopped.value) + 1e-6
