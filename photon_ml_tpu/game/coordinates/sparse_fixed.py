@@ -170,6 +170,17 @@ class SparseFixedEffectCoordinate:
       for a plain ELL pass: PERF.md section 6, PRs 29-32). Exact, not
       approximate: the
       solve happens in a statically permuted feature space and maps back.
+      On one data shard, where every hot column planned holds one float32
+      value (one-hot fields scaled a row: every click log), the block is
+      int8 counts and a float32 scale a column, four times the columns in
+      the same bytes and float32(count) * scale the float32 cell bit for
+      bit (``hybrid_sparse._count_hot``; the ``fe_layout`` row says
+      ``hot_storage: count8``). The layout decides that from the shard: no
+      option, and any other shard stages the float32 (or bf16) block and
+      traces the programs it always did. This is not the streamed path's
+      ``feature_dtype="int8"``, which quantises real values and is lossy;
+      ``feature_dtype`` here is float32 or bfloat16 and never reaches the
+      count block.
       On one data shard L-BFGS's line search crosses the data twice an
       iteration whatever its trials (parallel/sparse_problem.py
       ``_hybrid_line``), so a fit's seconds follow its iterations alone.
@@ -283,8 +294,12 @@ class SparseFixedEffectCoordinate:
                 # solver's), the columns the count threshold asks for
                 # (before bytes or max_hot cut them: the planner with
                 # neither; every hot column clears the threshold), and
-                # what was built; and the columns any row touches.
+                # what was built: a cell's storage, the columns float32
+                # cells would have held in the same bytes, and how far the
+                # columns planned at a byte a cell are count-exact; and
+                # the columns any row touches.
                 cold_counts = _cold_column_counts(host)
+                plan = getattr(host, "hot_plan", (host.num_hot, 0))
                 led.record(
                     "fe_layout", shard=shard_id, num_hot=host.num_hot,
                     hot_budget_bytes=budget,
@@ -294,7 +309,10 @@ class SparseFixedEffectCoordinate:
                     hot_candidates=host.num_hot + hybrid_mod.plan_resident_hot(
                         cold_counts, dataset.num_rows, dt,
                         max_hot=self._dim),
-                    hot_bytes=int(host.X_hot.nbytes),
+                    hot_bytes=_leaf_bytes(
+                        (host.X_hot, getattr(host, "hot_scale", None))),
+                    hot_storage=hybrid_mod.hot_storage(host),
+                    hot_columns_f32=plan[0], hot_exact_candidates=plan[1],
                     hot_entries=host.entries[0],
                     cold_entries=host.entries[1],
                     cold_slots=sum(int(r.size) for r in host.cold_rowids),

@@ -27,7 +27,24 @@ The hybrid split exploits that:
   densified into an (n, k) matrix: margins and gradient contributions are
   plain MXU matmuls (X_hot @ w, X_hotᵀ r) — the 85% of entries ride the
   365 M-samples/s dense path, with the multiply-by-zero waste costing
-  bandwidth, not random access.
+  bandwidth, not random access. Memory bounds that block, not time (PERF.md
+  section 7 row 17), and a click log's features are one-hot fields scaled a
+  row: a float32 cell holds one of {0, s, 2s}. So where every column
+  planned is **count-exact** (``_count_hot``: all its live values one
+  float32 value s_c, no cell named over 127 times, float32(m) · s_c the
+  float32 sum the cell would have held) the block is the **count block**:
+  (n, k₁) int8 counts beside ``hot_scale`` (k₁,) float32, k₁ planned from
+  the same bytes at a byte a cell and 4 a column, four times the columns
+  (4096 where 1024 fit at 2M click-log rows, 1024 where 256 fit at 3M rows
+  beside a 10 GB solve). float32(count) · scale IS the float32 cell, and
+  both products stay float32: Σ_c count · (scale_c · w_c) and scale_c ·
+  Σ_i count · r_i, the convert fused into the reduce, no float32 copy
+  anywhere. The rule is the shard's: no option reaches it, a shard that
+  fails it (one real-valued column among those planned; bf16 storage; the
+  data-sharded layout) keeps its values and the programs it had, and one
+  shard holds one or the other, never both. It is NOT the streamed path's
+  ``feature_dtype="int8"`` (ops/streaming_sparse.py), which quantises real
+  values to 8 bits symmetric a column and is lossy: nothing here rounds.
 - **Cold columns** are relabeled into count-descending order (a static
   permutation of the feature space — the GLM objective is permutation-
   equivariant, so the solve happens in permuted space and maps back once
@@ -135,6 +152,17 @@ class HybridSparseBatch:
     # ones follow, in column order). 0 where it was not counted.
     num_touched: int = dataclasses.field(
         default=0, metadata=dict(static=True))
+    # The block's plan (the run ledger's ``fe_layout`` row): the columns
+    # value cells would have held in the same bytes, and how many of the
+    # columns planned at a byte a cell, from the first, are count-exact
+    # (``_count_hot``; 0 where no count block was planned: bf16 storage).
+    hot_plan: tuple[int, int] = dataclasses.field(
+        default=(0, 0), metadata=dict(static=True))
+    # (k,) float32 where ``X_hot`` is the COUNT block: int8 counts of how
+    # often a row names a column, every live value of column c being the
+    # one float32 ``hot_scale[c]``; the cell's value is float32(count) *
+    # scale, bit for bit. None (no leaf) where ``X_hot`` holds values.
+    hot_scale: Optional[Array] = None
 
     @property
     def num_rows(self) -> int:
@@ -143,6 +171,15 @@ class HybridSparseBatch:
     @property
     def dim(self) -> int:
         return self.num_features
+
+
+def hot_storage(layout) -> str:
+    """What a cell of a layout's ``X_hot`` is: ``count8`` (the exact count
+    block, which only the one-shard layout builds), ``float32`` or
+    ``bfloat16`` (values)."""
+    if getattr(layout, "hot_scale", None) is not None:
+        return "count8"
+    return np.dtype(layout.X_hot.dtype).name
 
 
 # Bytes per element of each hot-block storage dtype. int8 (the streamed
@@ -273,6 +310,8 @@ def build_hybrid(
 
     # Permuted order: count-descending (stable → ties break on column id).
     order_desc = np.argsort(-counts, kind="stable").astype(np.int32)
+    if hot_threshold is None:
+        hot_threshold = _default_hot_threshold(n, feature_dtype)
     k = plan_resident_hot(counts, n, feature_dtype, hot_threshold, max_hot,
                           hot_block_bytes)
 
@@ -281,8 +320,22 @@ def build_hybrid(
 
     # Permuted column of every entry; a dead one gets d, past every block.
     new_col = np.where(live, inv_perm[np.minimum(flat_col, d - 1)], d)
-    X_hot = _dense_hot(new_col.reshape(indices.shape), values, k, n,
-                       feature_dtype)
+    slot_col = new_col.reshape(indices.shape)
+
+    # The same counts and bytes planned at a byte a cell: where every one
+    # of those columns is count-exact the block holds counts and a scale a
+    # column, else values as planned above. One or the other, never both.
+    X_hot = hot_scale = None
+    hot_plan = (k, 0)
+    if feature_dtype_name(feature_dtype) == "float32":
+        k1 = plan_resident_hot(counts, n, "int8", hot_threshold, max_hot,
+                               hot_block_bytes)
+        X_hot, hot_scale, exact = _count_hot(slot_col, values, k1, n)
+        hot_plan = (k, exact)
+        if X_hot is not None:
+            k = k1
+    if X_hot is None:
+        X_hot = _dense_hot(slot_col, values, k, n, feature_dtype)
 
     # Cold entries, column-contiguous in permuted order.
     cold_sel = live & (new_col >= k)
@@ -332,6 +385,7 @@ def build_hybrid(
         cold_rowids=tuple(put(a) for a in rowids_cls),
         cold_vals=tuple(put(a) for a in vals_cls),
         chunk_cols=put(np.concatenate(rem_cols or [np.zeros(0, np.int32)])),
+        hot_scale=None if hot_scale is None else put(hot_scale),
         labels=put(np.asarray(batch.labels)),
         weights=put(np.asarray(batch.weights)),
         offsets=put(np.asarray(batch.offsets)),
@@ -344,7 +398,24 @@ def build_hybrid(
         class_rems=tuple(c.size for c in rem_cols),
         entries=(int(counts[order_desc[:k]].sum()), int(c_row.size)),
         num_touched=max(k, int((counts > 0).sum())),
+        hot_plan=hot_plan,
     )
+
+
+def _row_blocks(n: int):
+    """[a, b) row ranges of ``_HOT_BLOCK_ROWS`` over n rows."""
+    for a in range(0, n, _HOT_BLOCK_ROWS):
+        yield a, min(a + _HOT_BLOCK_ROWS, n)
+
+
+def _hot_hits(new_col: np.ndarray, k: int, a: int, b: int):
+    """The entries of rows [a, b) under column ``k``, a slot at a time:
+    (slot, the rows of the range that hit, their columns). Within one slot
+    a row appears once, so a fancy ``+=`` over a hit adds every entry."""
+    for j in range(new_col.shape[1]):
+        col = new_col[a:b, j]
+        sel = np.flatnonzero(col < k)
+        yield j, sel, col[sel]
 
 
 def _dense_hot(new_col: np.ndarray, values: np.ndarray, k: int,
@@ -356,23 +427,90 @@ def _dense_hot(new_col: np.ndarray, values: np.ndarray, k: int,
     blocks, so the host holds the block itself and one f32 slice of it."""
     import ml_dtypes
 
-    n, slots = new_col.shape
     dtype = (ml_dtypes.bfloat16 if feature_dtype == jnp.bfloat16
              else np.float32)
     X = np.zeros((rows_out, k), dtype)
     if not k:
         return X
     in_place = dtype == np.float32
-    for a in range(0, n, _HOT_BLOCK_ROWS):
-        b = min(a + _HOT_BLOCK_ROWS, n)
+    for a, b in _row_blocks(new_col.shape[0]):
         blk = X[a:b] if in_place else np.zeros((b - a, k), np.float32)
-        for j in range(slots):
-            col = new_col[a:b, j]
-            sel = np.flatnonzero(col < k)
-            blk[sel, col[sel]] += values[a:b, j][sel]
+        for j, sel, col in _hot_hits(new_col, k, a, b):
+            blk[sel, col] += values[a:b, j][sel]
         if not in_place:
             X[a:b] = blk
     return X
+
+
+_MAX_COUNT = 127  # what an int8 cell holds
+
+
+def _count_hot(new_col: np.ndarray, values: np.ndarray, k: int,
+               rows_out: int):
+    """The COUNT block of the entries whose permuted column is under ``k``:
+    ((rows_out, k) int8 counts, (k,) float32 scale, k), or (None, None, the
+    first column that is not count-exact).
+
+    A column is count-exact where all its live values are one float32 value
+    s, no cell is named more than 127 times, and float32(m) * s equals the
+    float32 cell ``_dense_hot`` would have summed for every multiplicity m
+    met: true of m <= 2 always (s, then a doubling), tested against the
+    sequential sum from 3 up. Then float32(count) * scale IS the float32
+    block, and the same bytes hold four times the columns. Nothing is
+    rounded: a shard with one real-valued column among these keeps its
+    float32 block. (Not the streamed path's ``feature_dtype="int8"``,
+    which quantises real values to 8 bits and is lossy; this block is never
+    reached through ``feature_dtype``.)
+
+    The values are tested first, in one pass over the entries under ``k``:
+    any one value of each column written into a guess, every entry held to
+    its column's guess; the counts are built only once that has passed."""
+    if not k:
+        return None, None, 0
+    scale, exact = _one_valued(new_col, values, k)
+    if exact < k:
+        return None, None, exact
+
+    X = np.zeros((rows_out, k), np.int8)
+    guard = new_col.shape[1] > _MAX_COUNT  # else no cell can overflow
+    for a, b in _row_blocks(new_col.shape[0]):
+        blk = X[a:b]
+        for _, sel, col in _hot_hits(new_col, k, a, b):
+            if guard:
+                full = col[blk[sel, col] == _MAX_COUNT]
+                if full.size:
+                    return None, None, int(full.min())
+            blk[sel, col] += 1
+        if blk.max(initial=0) > 2:
+            inexact = _inexact_columns(blk, scale)
+            if inexact.size:
+                return None, None, int(inexact.min())
+    return X, scale, k
+
+
+def _one_valued(new_col: np.ndarray, values: np.ndarray,
+                k: int) -> tuple[np.ndarray, int]:
+    """((k,) float32: one live value of each column under ``k``, the first
+    column some entry of which has another value, or k)."""
+    cols = np.minimum(new_col, k).reshape(-1)  # k: every other entry
+    vals = values.astype(np.float32, copy=False).reshape(-1)
+    scale = np.zeros(k + 1, np.float32)
+    scale[cols] = vals
+    off = cols[(vals != scale[cols]) & (cols < k)]  # a NaN is off its own
+    return scale[:k], int(off.min(initial=k))
+
+
+def _inexact_columns(blk: np.ndarray, scale: np.ndarray) -> np.ndarray:
+    """Columns of a count block's rows where float32(m) * scale is not the
+    sequential float32 sum of m values, over the cells of m >= 3."""
+    rows, col = np.nonzero(blk > 2)
+    pairs = np.unique(col * (_MAX_COUNT + 1) + blk[rows, col])
+    col, m = np.divmod(pairs, _MAX_COUNT + 1)
+    s = scale[col]
+    summed = np.zeros_like(s)
+    for t in range(int(m.max(initial=0))):
+        summed = np.where(t < m, summed + s, summed)
+    return col[m.astype(np.float32) * s != summed]
 
 
 @jax.tree_util.register_dataclass
@@ -612,16 +750,26 @@ def to_original_space(hb, w_perm: Array) -> Array:
         lambda: w_perm[hb.inv_perm])
 
 
-def _hot_matvec(X: Array, w: Array) -> Array:
+def _hot_matvec(X: Array, w: Array, scale: Optional[Array] = None) -> Array:
     """X_hot @ w with f32 MXU accumulation under bf16 storage (same
-    contract as ops/aggregators._matvec)."""
+    contract as ops/aggregators._matvec). With a ``scale`` X holds the
+    count block's counts: Σ_c count[i, c] · (scale[c] · w[c]), the scale
+    folded into the k coefficients first, so that every product is the
+    float32 block's (for the counts 1 and 2 to the bit), float32
+    throughout. The convert fuses into the reduce: the TPU's compiler holds
+    no float32 copy of the block (``memory_analysis``: no scratch)."""
+    if scale is not None:
+        return X.astype(jnp.float32) @ (scale * w)
     if X.dtype == jnp.bfloat16:
         return jnp.einsum("nd,d->n", X, w.astype(jnp.bfloat16),
                           preferred_element_type=jnp.float32)
     return X @ w
 
 
-def _hot_rmatvec(X: Array, r: Array) -> Array:
+def _hot_rmatvec(X: Array, r: Array, scale: Optional[Array] = None) -> Array:
+    """X_hotᵀ r; with a ``scale``, scale[c] · Σ_i count[i, c] · r[i]."""
+    if scale is not None:
+        return (r @ X.astype(jnp.float32)) * scale
     if X.dtype == jnp.bfloat16:
         return jnp.einsum("n,nd->d", r.astype(jnp.bfloat16), X,
                           preferred_element_type=jnp.float32)
@@ -663,7 +811,7 @@ def margins(hb: HybridSparseBatch, w_perm: Array) -> Array:
     z = hb.offsets
     if hb.num_hot:
         with jax.named_scope("fe.hot"):
-            z = z + _hot_matvec(hb.X_hot, w_perm[:hb.num_hot])
+            z = z + _hot_matvec(hb.X_hot, w_perm[:hb.num_hot], hb.hot_scale)
     if hb.cold_rowids:
         with jax.named_scope("fe.cold"):
             prods = _cold_products(hb, w_perm, hb.cold_vals)
@@ -735,7 +883,7 @@ def row_gradient(hb: HybridSparseBatch, r: Array) -> Array:
     g_hot = None
     if hb.num_hot:
         with jax.named_scope("fe.hot"):
-            g_hot = _hot_rmatvec(hb.X_hot, r)
+            g_hot = _hot_rmatvec(hb.X_hot, r, hb.hot_scale)
     return _assemble_grad(hb, g_hot, _cold_grad(hb, r, hb.cold_vals))
 
 
@@ -775,7 +923,9 @@ def hessian_diagonal(
     g_hot = None
     if hb.num_hot:
         # Squares upcast to f32: x² underflows/quantizes harshly in bf16.
+        # The count block's are count² · scale².
         Xsq = hb.X_hot.astype(jnp.float32) ** 2
-        g_hot = r @ Xsq
+        g_hot = _hot_rmatvec(Xsq, r, None if hb.hot_scale is None
+                             else hb.hot_scale ** 2)
     return _assemble_grad(
         hb, g_hot, _cold_grad(hb, r, tuple(v * v for v in hb.cold_vals)))

@@ -246,7 +246,10 @@ def _dense(hb) -> np.ndarray:
     """The (n, d) matrix a hybrid layout holds, in the original columns."""
     n, d = int(hb.labels.shape[0]), hb.num_features
     out = np.zeros((n + 1, d), np.float64)
-    out[:n, :hb.num_hot] = np.asarray(hb.X_hot, np.float64)
+    hot = np.asarray(hb.X_hot)
+    if hb.hot_scale is not None:  # the count block: float32(count) * scale
+        hot = hot.astype(np.float32) * np.asarray(hb.hot_scale)
+    out[:n, :hb.num_hot] = hot.astype(np.float64)
     for cols, L, rows, vals in zip(_chunk_columns(hb), hb.class_lens,
                                    hb.cold_rowids, hb.cold_vals):
         rows, vals = np.asarray(rows), np.asarray(vals, np.float64)
@@ -654,7 +657,11 @@ def test_the_ledger_has_the_layout_the_phases_and_the_evaluations(run):
     assert len(layout) == 1
     lay = layout[0]
     assert lay["hot_entries"] + lay["cold_entries"] == 4000 * 39
-    assert lay["hot_bytes"] == lay["num_hot"] * 4000 * 4
+    # every value is 1/sqrt(39): int8 counts and a float32 scale a column
+    assert lay["hot_storage"] == "count8"
+    assert lay["hot_bytes"] == lay["num_hot"] * (4000 + 4)
+    assert lay["hot_columns_f32"] == lay["hot_exact_candidates"] == lay[
+        "num_hot"]
     # the CPU offers no bytes and max_hot is far: the count threshold bound
     assert lay["hot_budget_bytes"] is None
     assert lay["hot_candidates"] == lay["num_hot"]
@@ -701,8 +708,14 @@ def test_the_layout_row_says_what_bound_the_block(run, tmp_path, monkeypatch,
     assert lay["hot_candidates"] == int((counts >= 8).sum())  # max(8, n/2048)
     if bound == "threshold":
         assert lay["num_hot"] == lay["hot_candidates"]
-    else:  # 24 columns fit a device's rows: 24 on one shard, 48 on two
-        assert lay["num_hot"] == 24 * shards < lay["hot_candidates"]
+    elif shards == 1:  # 24 float32 columns fit, or 95 of counts and a scale
+        assert lay["hot_storage"] == "count8"
+        assert lay["hot_columns_f32"] == 24
+        assert lay["num_hot"] == 384000 // 4004 == 95 < lay["hot_candidates"]
+    else:  # a device holds half the rows; the sharded layout keeps float32
+        assert lay["hot_storage"] == "float32"
+        assert lay["num_hot"] == lay["hot_columns_f32"] == 48 < lay[
+            "hot_candidates"]
     assert lay["hot_entries"] + lay["cold_entries"] == 4000 * 39
 
 
