@@ -1,13 +1,15 @@
 """Flagship-scale sparse random effect on one chip: 10M rows, 1M entities,
 d=1M sparse features.
 
-Reproduces the numbers quoted in docs/PARITY.md (host staging ~60 s
-uncontended, steady-state fit+score 2-4 min across runs for all 10^6
-per-entity L-BFGS solves, AUC ~0.995 against planted effects). Needs ~12 GB host RAM for data
-generation and one TPU chip (first run adds remote-compile time; the
-persistent cache makes reruns fast). Neither the 40 TB dense (n, d)
-matrix nor the 4 TB (E, d) model table ever exists: buckets stage at
-d_active <= 16 and the model is a SubspaceRandomEffectModel.
+The run docs/PARITY.md describes: all 10^6 per-entity L-BFGS solves and
+their AUC against planted effects. It records no time: the projected path's
+chip readings are the benchmark cell ``avazu-sparse-re.steady``'s (PERF.md
+sections 5 and 6, PR 35), which runs the dense ``(E, d)`` model form at
+widths 128 to 1024; this script is what still exercises the *subspace*
+model form (d_active <= 16, a SubspaceRandomEffectModel), which no cell
+runs (ROADMAP.md, Reach 15). Needs ~12 GB host RAM for data generation and
+one TPU chip. Neither the 40 TB dense (n, d) matrix nor the 4 TB (E, d)
+model table ever exists.
 
     python dev-scripts/flagship_sparse_re.py
 """

@@ -134,7 +134,23 @@ class GameEstimator:
     ) -> dict[str, object]:
         coords: dict[str, object] = {}
         streamed: list[str] = []
-        for cid, cc in self.coordinate_configs.items():
+        # A random effect over a sparse shard stages through the pipelined
+        # projector: its blocks reach the device as the first sweep consumes
+        # them, after every coordinate is built. Such coordinates are built
+        # first, so that a resident sparse fixed effect can size its hot
+        # block around the bytes they declare; the update sequence, not this
+        # order, decides who trains when.
+        def stages_at_once(item):
+            data = item[1].data
+            return not (isinstance(data, RandomEffectDataConfiguration)
+                        and data.projector.upper() != "RANDOM"
+                        and (data.projector.upper() == "INDEX_MAP"
+                             or data.features_to_samples_ratio is not None
+                             or isinstance(dataset.feature_shards[
+                                 data.feature_shard_id], SparseShard)))
+
+        for cid, cc in sorted(self.coordinate_configs.items(),
+                              key=stages_at_once):
             opt = opt_configs[cid]
             if isinstance(cc.data, FixedEffectDataConfiguration):
                 shard = dataset.feature_shards[cc.data.feature_shard_id]
@@ -165,7 +181,11 @@ class GameEstimator:
                         self.mesh,
                         feature_sharded=cc.data.feature_sharded,
                         hybrid=cc.data.hybrid,
-                        feature_dtype=cc.data.feature_dtype)
+                        feature_dtype=cc.data.feature_dtype,
+                        deferred_bytes=sum(
+                            c.deferred_device_bytes()
+                            for c in coords.values()
+                            if isinstance(c, RandomEffectCoordinate)))
                     continue
                 coords[cid] = FixedEffectCoordinate(
                     dataset, cc.data.feature_shard_id, self.loss, opt,
@@ -228,7 +248,7 @@ class GameEstimator:
                 "streaming=... was set but no coordinate routed onto the "
                 "streamed path: it applies to FIXED-effect coordinates "
                 "over SPARSE shards (docs/STREAMING.md)")
-        return coords
+        return {cid: coords[cid] for cid in self.coordinate_configs}
 
     # -- evaluation --------------------------------------------------------
 

@@ -64,6 +64,9 @@ class EntityBucketing:
     # the entity axis must keep slice lengths multiples of it to preserve
     # mesh-divisibility of sharded staging).
     entity_pad_multiple: int = 8
+    # Entities that keep ``upper_bound`` of their rows for training (the
+    # rest of their rows are passive).
+    num_capped_entities: int = 0
 
 
 def _next_pow2(x: int) -> int:
@@ -172,6 +175,7 @@ def build_bucketing(
         num_passive_only_entities=num_passive_only,
         num_passive_examples=passive_examples,
         entity_pad_multiple=entity_pad_multiple,
+        num_capped_entities=int(((capped < counts) & keep).sum()),
     )
 
 

@@ -7,8 +7,12 @@ the residency discipline shared by all coordinate types.
 
 from __future__ import annotations
 
+import concurrent.futures as cf
 import dataclasses
 import functools
+import logging
+import os
+import threading
 import time
 from typing import Optional
 
@@ -37,6 +41,8 @@ from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, data_sharded
 
 Array = jax.Array
+
+logger = logging.getLogger("photon_ml_tpu.game")
 
 # Sentinel distinguishing "use the coordinate's intercept" from an explicit
 # None (projected buckets with no intercept column).
@@ -111,6 +117,105 @@ def _subspace_sparse_scores(W_flat, flatpos, values):
     for j in range(flatpos.shape[1]):
         pos = flatpos[:, j]
         g = W_flat[jnp.minimum(pos, lim - 1)] * (pos < lim)
+        acc = acc + values[:, j].astype(jnp.float32) * g
+    return acc
+
+
+def _struct(a) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+
+class _WavePrograms:
+    """A coordinate's jitted per-bucket fit, and its shapes compiled ahead
+    of the first sweep, side by side.
+
+    One program per (lanes, capacity, width) class: a projected table over
+    a few thousand columns has 14 of them, each 2 to 18 s of the TPU
+    compiler, and a first sweep that meets them one after the other spends
+    95 s compiling (PERF.md section 6, PR 35). The shapes are known once
+    the blocks are planned, so ``compile_ahead`` lowers and compiles every
+    one of them on a thread each while the host still stages, and a call
+    runs the compiled program of its arguments' shapes; a shape that was
+    not planned, or a program that refuses its arguments, goes through the
+    jitted function as before. The same computation either way: one traced
+    function, one set of compiler options."""
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+        self._compiled: dict[tuple, cf.Future] = {}
+        self._planned = threading.Event()
+        self._planned.set()  # nothing is being planned yet
+
+    @staticmethod
+    def _key(arrays) -> tuple:
+        return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
+
+    def compile_ahead(self, table, offsets, plan) -> None:
+        """Start compiling the program for every tuple of shape structs
+        ``plan()`` returns (it may block: it runs on a thread of its own);
+        ``table`` and ``offsets`` are the structs of the leading two
+        arguments."""
+        self._planned.clear()
+
+        def one(specs):
+            return self.jitted.lower(table, offsets, *specs).compile()
+
+        def run():
+            try:
+                todo = {self._key(specs): specs for specs in plan()}
+                if todo:
+                    pool = cf.ThreadPoolExecutor(
+                        max_workers=min(len(todo), os.cpu_count() or 1),
+                        thread_name_prefix="pml-re-compile")
+                    for key, specs in todo.items():
+                        self._compiled[key] = pool.submit(one, specs)
+                    pool.shutdown(wait=False)
+            except Exception as e:  # the stager failed: the fit will say
+                logger.warning("wave programs not compiled ahead: %s: %s",
+                               type(e).__name__, e)
+            finally:
+                self._planned.set()
+
+        threading.Thread(target=run, daemon=True,
+                         name="pml-re-compile-plan").start()
+
+    def __call__(self, W, offsets, *arrays):
+        self._planned.wait()
+        key = self._key(arrays)
+        pending = self._compiled.get(key)
+        if pending is not None:
+            try:
+                return pending.result()(W, offsets, *arrays)
+            except (TypeError, ValueError) as e:
+                # refused before anything ran (an argument's placement or
+                # type is not what was planned): the jitted function takes
+                # whatever it is given
+                self._compiled.pop(key, None)
+                logger.warning("compiled wave program refused its "
+                               "arguments (%s); tracing it anew", e)
+        return self.jitted(W, offsets, *arrays)
+
+    def __getattr__(self, name):  # .lower and the like: the jitted one's
+        return getattr(self.jitted, name)
+
+
+@jax.jit
+@scoped("re.score")
+def _table_sparse_scores(W, ids, indices, values):
+    """Σ_k values[i,k] · W[ids[i], indices[i,k]] against the dense (E, d)
+    table, one 1-D gather per ELL slot of the flattened table. The fused
+    form, ``W[ids[:, None], indices]``, lays its (n, k) index and gathered
+    operands out in tiles whose minor dimension pads k to 128 lanes: at 2M
+    rows x 14 slots the v5e's compiler counts 2.05 GB of scratch for it
+    against 0.37 GB for the slot loop (``_subspace_sparse_scores`` has the
+    same reason). ELL padding slots carry value 0 by contract, so clamping
+    their sentinel index (== d) into range is exact."""
+    d = W.shape[1]
+    W_flat = W.reshape(-1)
+    base = ids * d
+    acc = jnp.zeros((ids.shape[0],), jnp.float32)
+    for j in range(indices.shape[1]):
+        g = W_flat[base + jnp.minimum(indices[:, j], d - 1)]
         acc = acc + values[:, j].astype(jnp.float32) * g
     return acc
 
@@ -242,6 +347,11 @@ class RandomEffectCoordinate:
         # Beside them, each lane's true row count (the rest of its
         # ``cap`` slots is padding): the wave rows' ``rows_useful``.
         self._host_counts: list[np.ndarray] = []
+        # And, where the lanes are projected, the columns each lane's own
+        # subspace has (the rest of its ``d_active`` slots is padding): the
+        # wave rows' ``cols_useful``. None for a wave at the shard's width.
+        self._host_active: list[Optional[np.ndarray]] = []
+        self._layout_recorded = False
         self._pending = None
         self._stager = None
         self.staging = staging or stg.StagingConfig()
@@ -352,6 +462,7 @@ class RandomEffectCoordinate:
             for arrays in host_buckets:
                 self._stage_host_tuple(arrays)
             self._pending = None
+            self._record_layout()
         if self.subspace:
             cols_sorted = np.asarray(sub["cols"])
             perm = np.asarray(sub["perm"])
@@ -427,8 +538,13 @@ class RandomEffectCoordinate:
                             (a >= 0).sum(axis=1).astype(np.int64))
                     if ai == 4:  # entity rows: which lanes are live
                         self._host_rows.append(np.array(a, copy=True))
+                    if ai == 5:  # column map: each lane's own subspace
+                        self._host_active.append(
+                            (a >= 0).sum(axis=1).astype(np.int64))
                     tup.append(self._put(a))
                     ph["bytes"] += int(a.nbytes)
+                if len(arrays) < 6:
+                    self._host_active.append(None)
                 self._bucket_data.append(tuple(tup))
 
     def _iter_bucket_data(self):
@@ -452,8 +568,74 @@ class RandomEffectCoordinate:
                 host = next(self._pending)
             except StopIteration:
                 self._pending = None
+                self._record_layout()
                 return
             self._stage_host_tuple(host)
+
+    def _pending_blocks(self):
+        """(lanes, cap, width) of every device tuple the stager's shards
+        will become, as ``_stage_host_tuple`` splits them. Known once every
+        class has its width: blocks on the stager's phase A, not on the
+        feature layout."""
+        pad = self.bucketing.entity_pad_multiple
+        chunk = ((_LANE_CHUNK + pad - 1) // pad) * pad
+        for (bi, lo, hi), cols in zip(self._stager.plan,
+                                      self._stager.cols_list()):
+            cap = self.bucketing.buckets[bi].capacity
+            for a in range(0, hi - lo, chunk):
+                yield min(chunk, hi - lo - a), cap, int(cols.shape[1])
+
+    def deferred_device_bytes(self) -> int:
+        """Bytes this coordinate has yet to put on the device: the projected
+        blocks the pipelined stager hands over as the first update consumes
+        them, which exist nowhere on the device while the coordinates built
+        after this one stage theirs. 0 for an unprojected coordinate, which
+        stages in its constructor."""
+        if self._pending is None:
+            return 0
+        cell = 2 if self.feature_dtype == "bfloat16" else 4
+        extra = 4 * ((self.norm.factors is not None)
+                     + (self.norm.shifts is not None))
+        # features; labels, weights, row ids; the lane's entity; the column
+        # map and the projected normalization arrays
+        total = sum(lanes * (cap * (width * cell + 12) + 4
+                             + width * (4 + extra))
+                    for lanes, cap, width in self._pending_blocks())
+        staged = sum(int(a.nbytes) for t in self._bucket_data for a in t)
+        return max(0, total - staged)
+
+    def _record_layout(self) -> None:
+        """One ``re_layout`` row, as the fixed effect's ``fe_layout`` is:
+        what this coordinate staged, a class a row capacity. Written once,
+        when the last block is on the device."""
+        led = obs.ledger()
+        if led is None or self._layout_recorded:
+            return
+        self._layout_recorded = True
+        classes: dict[int, list] = {}
+        staged = useful = 0
+        cell = 2 if self.feature_dtype == "bfloat16" else 4
+        for t, rows, counts, active in zip(
+                self._bucket_data, self._host_rows, self._host_counts,
+                self._host_active):
+            lanes, cap, width = (int(v) for v in t[0].shape)
+            c = classes.setdefault(cap, [0, 0, width])
+            c[0] += lanes
+            c[1] += int((rows >= 0).sum())
+            staged += sum(int(a.nbytes) for a in t)
+            live = rows >= 0
+            useful += cell * int((counts[live] * (
+                width if active is None else active[live])).sum())
+        b = self.bucketing
+        led.record(
+            "re_layout", re_type=self.re_type, shard=self.shard_id,
+            model_form="subspace" if self.subspace else "dense",
+            projected=bool(self.projection), dim=int(self.dim),
+            classes=[[cap, *classes[cap]] for cap in sorted(classes)],
+            lanes=sum(c[0] for c in classes.values()),
+            entities=int(b.trained_entities.sum()),
+            entities_capped=int(b.num_capped_entities),
+            staged_bytes=staged, useful_bytes=useful)
 
     def wait_staged(self) -> "RandomEffectCoordinate":
         """Barrier: drain the staging pipeline onto the device without
@@ -486,7 +668,9 @@ class RandomEffectCoordinate:
         """
         num_entities = self.num_entities
         if self.projection:
-            self._fit_bucket, self._var_bucket = self._build_projected_fits()
+            fit, self._var_bucket = self._build_projected_fits()
+            self._fit_bucket = _WavePrograms(fit)
+            self._compile_ahead()
             return
         solve = jax.vmap(self._solve_one)
         var_one = jax.vmap(self._variance_one)
@@ -511,8 +695,56 @@ class RandomEffectCoordinate:
 
         # Donate the table being rebuilt (W for fits, V for variances) so the
         # scatter updates in place instead of copying (E, d) per bucket.
-        self._fit_bucket = jax.jit(fit_bucket, donate_argnums=(0,))
+        self._fit_bucket = _WavePrograms(
+            jax.jit(fit_bucket, donate_argnums=(0,)))
         self._var_bucket = jax.jit(var_bucket, donate_argnums=(1,))
+        self._compile_ahead()
+
+    def _compile_ahead(self) -> None:
+        """Hand the wave programs every shape the fit stream will dispatch:
+        the tuples already on the device as they are, the stager's pending
+        shards as ``_stage_host_tuple`` will split and place them (known
+        once each class has its width: the stager's phase A). On one device
+        only: across a mesh the table's and the offsets' placements are the
+        caller's, and the jitted function follows them."""
+        if self.mesh.devices.size != 1:
+            return
+        staged = [tuple(map(_struct, t)) for t in self._bucket_data]
+        pending = self._pending is not None
+
+        def put_struct(shape, dtype):
+            sharded = shape[0] % self._n_data == 0
+            return jax.ShapeDtypeStruct(
+                shape, jax.dtypes.canonicalize_dtype(dtype),
+                sharding=(data_sharded(self.mesh, len(shape)) if sharded
+                          else None))
+
+        def plan():
+            specs = list(staged)
+            if not pending:
+                return specs
+            ds = self.dataset
+            feat = (jnp.bfloat16 if self.feature_dtype == "bfloat16"
+                    else jnp.float32)
+            extra = (self.norm.factors is not None) + (
+                self.norm.shifts is not None)
+            for lanes, cap, width in self._pending_blocks():
+                specs.append((
+                    put_struct((lanes, cap, width), feat),
+                    put_struct((lanes, cap), np.asarray(ds.response).dtype),
+                    put_struct((lanes, cap), np.asarray(ds.weights).dtype),
+                    put_struct((lanes, cap), np.int32),
+                    put_struct((lanes,), np.int32),
+                    put_struct((lanes, width), np.int32),
+                ) + (put_struct((lanes, width), np.float32),) * extra)
+            return specs
+
+        shape = (self.subspace_cols.shape if self.subspace
+                 else (self.num_entities, self.dim))
+        self._fit_bucket.compile_ahead(
+            jax.ShapeDtypeStruct(shape, jnp.float32),
+            jax.ShapeDtypeStruct((self.dataset.num_rows,), jnp.float32),
+            plan)
 
     def _row_movers(self):
         """The bucket layout's row moves — warm-start gather, fitted-row
@@ -854,11 +1086,20 @@ class RandomEffectCoordinate:
         ledger's drain (``_wave_rows``)."""
         cap = int(arrays[3].shape[1])
         lanes = int(arrays[4].shape[0])
-        return dict(
+        counts = self._host_counts[wave][fit]
+        fields = dict(
             re_type=self.re_type, wave=wave, seconds=round(seconds, 6),
             entities_fit=int(fit.sum()), cap=cap, lanes=lanes,
-            rows_useful=int(self._host_counts[wave][fit].sum()),
-            rows_padded=lanes * cap)
+            rows_useful=int(counts.sum()), rows_padded=lanes * cap)
+        active = self._host_active[wave]
+        if active is not None:
+            # A projected wave's width is its class's: the cells a lane's
+            # own rows x own columns fill, against the block's.
+            width = int(arrays[0].shape[2])
+            fields.update(d_active=width,
+                          cols_useful=int((counts * active[fit]).sum()),
+                          cols_padded=lanes * cap * width)
+        return fields
 
     def compute_model_variances(
         self, model: RandomEffectModel, offsets: Array
@@ -910,10 +1151,13 @@ class RandomEffectCoordinate:
             return jnp.einsum("na,na->n", xa,
                               jnp.asarray(model.means)[self._ids])
         if self.is_sparse:
+            W = jnp.asarray(model.means)
+            if W.size < 2**31:  # flat positions fit the device's int32
+                return _table_sparse_scores(W, self._ids, self._sp_indices,
+                                            self._sp_values)
             # Σ_k v_ik · W[e_i, idx_ik]. ELL padding slots carry value 0
             # by contract, so clamping their sentinel index (== d) into
             # range is exact — no (E, d+1) padded copy of the table.
-            W = jnp.asarray(model.means)
             idx = jnp.minimum(self._sp_indices, W.shape[1] - 1)
             return jnp.sum(
                 self._sp_values * W[self._ids[:, None], idx], axis=-1)

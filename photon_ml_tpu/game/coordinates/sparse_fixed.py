@@ -73,20 +73,24 @@ def solver_state_bytes(dim: int,
                            + _LAYOUT_VECTORS)
 
 
-def hot_block_budget(mesh, solver_bytes: int = 0) -> Optional[int]:
+def hot_block_budget(mesh, solver_bytes: int = 0,
+                     deferred_bytes: int = 0) -> Optional[int]:
     """Bytes the resident hot block may take on one device of ``mesh``:
     half of what the device has free now (``bytes_limit`` less
     ``bytes_in_use``) once the fixed effect's own solve has its
-    ``solver_bytes``, so that a device other tables already fill, or a
-    coefficient space that fills it, gets a narrower block, not an
-    allocation failure. None where the backend reports no limit (the CPU),
-    and then the column counts alone size the block."""
+    ``solver_bytes`` and the job's other coordinates the ``deferred_bytes``
+    they have declared but not yet staged (a projected table's blocks cross
+    as the first sweep consumes them: 7 GB that ``bytes_in_use`` cannot
+    show), so that a device other tables fill, or a coefficient space that
+    fills it, gets a narrower block, not an allocation failure. None where
+    the backend reports no limit (the CPU), and then the column counts
+    alone size the block."""
     stats = mesh.devices.flat[0].memory_stats() or {}
     limit = int(stats.get("bytes_limit", 0))
     if not limit:
         return None
     free = max(0, limit - int(stats.get("bytes_in_use", 0))
-               - int(solver_bytes))
+               - int(solver_bytes) - int(deferred_bytes))
     return free * _HOT_EIGHTHS_OF_FREE // 8
 
 
@@ -212,7 +216,12 @@ class SparseFixedEffectCoordinate:
         down_sampling_seed: int = 0,
         hybrid: Optional[bool] = None,
         feature_dtype: str = "float32",
+        deferred_bytes: int = 0,
     ):
+        """``deferred_bytes``: what the job's other coordinates have yet to
+        put on this device (``RandomEffectCoordinate.
+        deferred_device_bytes``, summed by the estimator that builds them):
+        the hot block's budget leaves them their room."""
         from photon_ml_tpu.data.game_data import SparseShard
         from photon_ml_tpu.data.sparse import SparseBatch
         from photon_ml_tpu.parallel import sparse_problem as sp
@@ -266,7 +275,7 @@ class SparseFixedEffectCoordinate:
             dt = (jnp.bfloat16 if feature_dtype == "bfloat16"
                   else jnp.float32)
             solver_bytes = solver_state_bytes(self._dim, config)
-            budget = hot_block_budget(mesh, solver_bytes)
+            budget = hot_block_budget(mesh, solver_bytes, deferred_bytes)
             with obs.phase("fe.host_stage"):
                 if self._hybrid_sharded:
                     host = hybrid_mod.build_hybrid_shards(
@@ -304,11 +313,12 @@ class SparseFixedEffectCoordinate:
                     "fe_layout", shard=shard_id, num_hot=host.num_hot,
                     hot_budget_bytes=budget,
                     solver_state_bytes=solver_bytes,
+                    deferred_bytes=int(deferred_bytes),
                     touched_columns=host.num_hot + int(
                         np.count_nonzero(cold_counts)),
-                    hot_candidates=host.num_hot + hybrid_mod.plan_resident_hot(
-                        cold_counts, dataset.num_rows, dt,
-                        max_hot=self._dim),
+                    hot_candidates=host.num_hot + int(np.count_nonzero(
+                        cold_counts >= hybrid_mod._default_hot_threshold(
+                            dataset.num_rows, dt))),
                     hot_bytes=_leaf_bytes(
                         (host.X_hot, getattr(host, "hot_scale", None))),
                     hot_storage=hybrid_mod.hot_storage(host),

@@ -425,6 +425,13 @@ class ProjectionStager:
         self._claim_lock = threading.Lock()
         self.fault_stats = {"retries": 0, "serial_restages": 0,
                             "stragglers": 0, "quarantined": False}
+        # What the ``re.project`` phase row says of the pass: the split's
+        # seconds, the wall seconds of phases A and B (B ends with the last
+        # staged shard), the bytes staged, and when the consumer took its
+        # first shard (``overlapped``: before the last one was staged).
+        self._pass = {"split": 0.0, "phase_a": 0.0, "bytes": 0}
+        self._t_cols = self._t0
+        self._t_first_taken: Optional[float] = None
 
         # Probe the shard-granular cache: valid shards skip phases A+B
         # entirely (their column map rides in the cached tuple).
@@ -490,6 +497,8 @@ class ProjectionStager:
         bound is released as the consumer takes each staged shard."""
         for i in range(self.num_shards):
             src, t = self._futures[i].result()
+            if self._t_first_taken is None:
+                self._t_first_taken = time.monotonic()
             try:
                 yield t
             finally:
@@ -545,6 +554,8 @@ class ProjectionStager:
         labels = (self._response if self._ratio is not None else None)
         tasks = split_shard_triplets(self._bucketing, self.plan, self._X,
                                      labels=labels)
+        t_split = time.monotonic()
+        self._pass["split"] = t_split - self._t0
         missing = [i for i in range(self.num_shards)
                    if i not in self._cached]
         is_process = self.config.mode == "process"
@@ -599,6 +610,8 @@ class ProjectionStager:
                         # _cols_ready.set(); the Event publishes them.
                         self._cols[i] = prj.fill_cols(
                             u_lane, u_col, hi - lo, width, self._ii)
+            self._t_cols = time.monotonic()
+            self._pass["phase_a"] = self._t_cols - t_split
             self._cols_ready.set()
             self._run_phase_b(tasks, missing, pool_b, ctx, is_process)
         finally:
@@ -676,6 +689,8 @@ class ProjectionStager:
             if i in self._claimed:
                 return
             self._claimed.add(i)
+        with self._state_lock:
+            self._pass["bytes"] += sum(int(a.nbytes) for a in res)
         self._futures[i].set_result(("staged", res))
         bi, lo, hi = self.plan[i]
         self._emitter.emit(ev_mod.StagingShard(
@@ -854,6 +869,21 @@ class ProjectionStager:
                            seconds=round(wall, 6), label=self._label,
                            shards=self.num_shards,
                            cached_shards=len(self._cached))
+                # The projection itself, by its steps: the one pass that
+                # splits the shard's non-zeros by lane slice, phase A (the
+                # active pairs and each class's width) and phase B (column
+                # maps filled, features laid out at ``d_active``), in wall
+                # seconds of the pool; what it staged; and whether the fit
+                # stream took its first shard before the last was staged.
+                led.record("phase", name="re.project", parent=None,
+                           seconds=round(wall, 6), label=self._label,
+                           split_seconds=round(self._pass["split"], 6),
+                           phase_a_seconds=round(self._pass["phase_a"], 6),
+                           phase_b_seconds=round(
+                               wall - (self._t_cols - self._t0), 6),
+                           bytes=self._pass["bytes"],
+                           workers=self.config.resolved_workers(),
+                           overlapped=self._t_first_taken is not None)
             self._maybe_finalize()
 
     def _maybe_finalize(self):

@@ -238,19 +238,20 @@ def plan_resident_hot(counts: np.ndarray, rows_per_device: int,
     ``hot_block_bytes`` of one device hold them at ``rows_per_device``
     rows. The caller that owns the device gives the bytes
     (game/coordinates/sparse_fixed.py); without them only the two counts
-    decide. Where the bytes bind, a block wider than a lane tile keeps
-    whole tiles."""
+    decide. A block wider than a lane tile keeps whole tiles, whichever of
+    the three bound it: the device pads the rest of a tile anyway, and a
+    pass over a ragged width can cost twice its bytes (2M rows of int8
+    counts on a v5e: X w over 2,277 columns 11.3 ms, over 2,282 and over
+    2,176 5.9 ms, the other pass 5.8 ms at every width; PERF.md section 6,
+    PR 35), which the up to 127 columns it would have held do not repay."""
     if hot_threshold is None:
         hot_threshold = _default_hot_threshold(rows_per_device,
                                                feature_dtype)
     k = int(min(max_hot, (np.asarray(counts) >= hot_threshold).sum()))
-    if hot_block_bytes is None:
-        return k
-    fits = plan_num_hot(max(rows_per_device, 1), hot_block_bytes,
-                        feature_dtype)
-    if k > fits:
-        k = fits - fits % _LANES if fits > _LANES else fits
-    return k
+    if hot_block_bytes is not None:
+        k = min(k, plan_num_hot(max(rows_per_device, 1), hot_block_bytes,
+                                feature_dtype))
+    return k - k % _LANES if k > _LANES else k
 
 
 def _class_block(block: np.ndarray, L: int) -> np.ndarray:

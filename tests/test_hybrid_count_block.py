@@ -367,7 +367,7 @@ def test_the_layout_row_carries_the_storage_and_its_plan(shard, storage,
     batch = shard(one_valued(4, n=1000, d=1024, fields=48))
     budget = 4 * 1000 * 130
     monkeypatch.setattr(sparse_fixed, "hot_block_budget",
-                        lambda mesh, solver_bytes=0: budget)
+                        lambda mesh, solver_bytes=0, deferred_bytes=0: budget)
     led = obs.RunLedger.create(str(tmp_path))
     obs.set_ledger(led)
     SparseFixedEffectCoordinate(

@@ -612,23 +612,26 @@ def _ctx(rows, **over):
 def test_benchmark_lists_the_new_metrics_additively():
     new = _new_metrics()
     first, second = "ml20m-logistic.steady", "criteo-1m-logistic.steady"
-    third = "kdd12-poisson-l1.steady"
+    third, fourth = "kdd12-poisson-l1.steady", "avazu-sparse-re.steady"
     # PR 26's nineteen read the first cell alone; PR 29 appended its cell to
     # the eleven of them a cell with a sparse fixed effect and one table can
     # report, and added four of these stems for that table; PR 33 appended
-    # its cell to the same eleven and added the four for its own table
+    # its cell to the same eleven and added the four for its own table, as
+    # PR 35 did, with ``phase_s.project`` for its projection pass
     both = {"ls_evals.fixed"} | {"phase_s." + p for p in (
         "digest", "bucketing", "host_stage", "transfer", "program_load")} | {
         "scope_s." + p for p in ("line_search", "value_grad", "direction",
                                  "gather_scatter", "score")}
     stems = ("re_iters", "lane_util", "pad_share", "ls_evals")
-    assert len(new) == 27
+    assert len(new) == 32
     assert {m["name"] for m in new
-            if m["workloads"] == [first, second, third]} == both
+            if m["workloads"] == [first, second, third, fourth]} == both
     assert {m["name"] for m in new if m["workloads"] == [second]} == {
         stem + ".per-c10" for stem in stems}
     assert {m["name"] for m in new if m["workloads"] == [third]} == {
         stem + ".per-advertiser" for stem in stems}
+    assert {m["name"] for m in new if m["workloads"] == [fourth]} == {
+        stem + ".per-publisher" for stem in stems} | {"phase_s.project"}
     assert {m["name"] for m in new if m["workloads"] == [first]} == {
         stem + c for stem in ("re_iters", "lane_util", "pad_share",
                               "ls_evals") for c in (".per-user", ".per-item")}

@@ -1,7 +1,7 @@
 """The cell of ISSUE 33, benchmark side, on the CPU: the cell rehearsed
 through ``benchmark/run.py`` reads what it read when recorded (limits, keys
 and ``argv`` from ``benchmark/selfcheck/kdd12.rehearsal.expected.json``, the
-readings from ``tests/data/kdd12.rehearsal.pr34.json``), its control and its
+readings from ``tests/data/kdd12.rehearsal.pr35.json``), its control and its
 four faults read ``correct`` false each by the number that exists for it, the
 selfcheck holds the new schema to the contract, the new device readers read a
 hand-made trace, and ``BENCHMARK.json`` gained the entries and lost
@@ -28,7 +28,7 @@ from record_scoped import field, plane  # noqa: E402  (its xplane encoder)
 
 CELL = "kdd12-poisson-l1.steady"
 EXPECTED = os.path.join(BENCH, "selfcheck", "kdd12.rehearsal.expected.json")
-RECORDED = os.path.join(REPO, "tests", "data", "kdd12.rehearsal.pr34.json")
+RECORDED = os.path.join(REPO, "tests", "data", "kdd12.rehearsal.pr35.json")
 OLD_READERS = {"stage_s", "update_s.fixed", "fe_iters", "fe_pass_roofline",
                "sweep_mfu", "device_idle_share", "ls_evals.fixed",
                "phase_s.digest", "phase_s.bucketing", "phase_s.host_stage",
@@ -142,8 +142,8 @@ def test_the_selfcheck_holds_the_new_schema_to_the_contract(run, capsys):
                  "glmix-criteo-1m-logistic: game_criteo ok",
                  "glmix-ml20m-logistic: game_dense ok"):
         assert line in err, line
-    assert err.count("selfcheck check_generator: ok") == 2
-    assert err.count("selfcheck check_work: ok") == 3
+    assert err.count("selfcheck check_generator: ok") >= 2
+    assert err.count("selfcheck check_work: ok") >= 3
 
 
 def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
@@ -152,14 +152,14 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     metrics of its own; every reader is found by name."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [c["name"] for c in bench["configs"]] == [
+    assert [c["name"] for c in bench["configs"]][:3] == [
         "glmix-ml20m-logistic", "glmix-criteo-1m-logistic",
         "glmix-kdd12-poisson-l1"]
-    assert [w["name"] for w in bench["workloads"]] == [
+    assert [w["name"] for w in bench["workloads"]][:3] == [
         "ml20m-logistic.steady", "criteo-1m-logistic.steady", CELL]
     assert all(w["chips"] == 1 for w in bench["workloads"])
-    assert bench["configs"][-1]["reduced"] == ["num_rows",
-                                               "lbfgs_max_iterations"]
+    assert bench["configs"][2]["reduced"] == ["num_rows",
+                                              "lbfgs_max_iterations"]
     assert all(len(c["source"]) <= 200 and len(c["why"]) <= 200
                for c in bench["configs"])
     assert all(len(w["why"]) <= 200 for w in bench["workloads"])
@@ -169,8 +169,9 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     assert mine == OLD_READERS | NEW_METRICS
     for m in cell["per_layer"]:
         assert callable(run.layer_reader(m["name"])), m["name"]
-        assert m["workloads"][-1] == CELL and m["moves"] in ("sweep_s",
-                                                             "setup_s")
+        # later cells append their names after this one's
+        assert CELL in m["workloads"][:3]
+        assert m["moves"] in ("sweep_s", "setup_s")
         if m["name"] in NEW_METRICS:
             assert m["workloads"] == [CELL] and m["moves"] == "sweep_s"
     # the cells that were there report what they reported: 27 metrics each
