@@ -32,11 +32,12 @@ from photon_ml_tpu.game.models import (RandomEffectModel,
                                        sort_subspace_rows)
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops.losses import PointwiseLoss
-from photon_ml_tpu.optim import optimize
+from photon_ml_tpu.optim import OptimizerType, optimize
 from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
-                                         compute_variances, make_objective,
+                                         compute_variances, make_line_oracle,
+                                         make_objective,
                                          resolve_optimizer_config)
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, data_sharded
 
@@ -58,18 +59,20 @@ _LANE_CHUNK = stg.LANE_CHUNK
 
 # What a fit wave counts inside its program, reduced over live lanes: the
 # solver fields of its ``re_fit_wave`` ledger row (docs/OBSERVABILITY.md).
-_WAVE_STATS = ("iters_sum", "iters_max", "evals_sum", "lanes_at_cap")
+_WAVE_STATS = ("iters_sum", "iters_max", "evals_sum", "lanes_at_cap",
+               "trials_sum")
 
 
-def _wave_stats(rows, iterations, evaluations, max_iterations: int):
-    """(4,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
+def _wave_stats(rows, iterations, evaluations, trials, max_iterations: int):
+    """(5,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
     entity (``rows >= 0``; padding lanes solve a benign problem of their
     own). Stays on the device until the update's ledger drain."""
     live = rows >= 0
     its = jnp.where(live, iterations, 0)
     return jnp.stack([its.sum(), its.max(),
                       jnp.where(live, evaluations, 0).sum(),
-                      (its >= max_iterations).sum()]).astype(jnp.int32)
+                      (its >= max_iterations).sum(),
+                      jnp.where(live, trials, 0).sum()]).astype(jnp.int32)
 
 
 def _wave_rows(pending):
@@ -682,8 +685,8 @@ class RandomEffectCoordinate:
                 ob = offsets[jnp.maximum(ex, 0)]
                 w0 = _gather_rows(W, rows)
             with jax.named_scope("re.solve"):
-                w_fit, its, evals = solve(Xb, yb, wb, ob, w0)
-                stats = _wave_stats(rows, its, evals, max_it)
+                w_fit, *counts = solve(Xb, yb, wb, ob, w0)
+                stats = _wave_stats(rows, *counts, max_it)
             with jax.named_scope("re.scatter"):
                 return _scatter_rows(W, rows, w_fit), stats
 
@@ -800,9 +803,9 @@ class RandomEffectCoordinate:
             """One entity's projected solve; original space in and out."""
             ctx = ctx_for(f, s)
             w0 = ctx.model_to_transformed_space(w0_orig)
-            w_t, its, evals = self._solve_one(X, y, w, o, w0, norm=ctx,
-                                              intercept_index=ii_proj)
-            return ctx.model_to_original_space(w_t), its, evals
+            w_t, *counts = self._solve_one(X, y, w, o, w0, norm=ctx,
+                                           intercept_index=ii_proj)
+            return ctx.model_to_original_space(w_t), *counts
 
         def var_one(X, y, w, o, w_orig, f, s):
             ctx = ctx_for(f, s)
@@ -846,8 +849,8 @@ class RandomEffectCoordinate:
 
         def solve(rows, *args):
             with jax.named_scope("re.solve"):
-                w_fit, its, evals = vsolve(*args)
-                return w_fit, _wave_stats(rows, its, evals, max_it)
+                w_fit, *counts = vsolve(*args)
+                return w_fit, _wave_stats(rows, *counts, max_it)
 
         def fit_bucket(W, offsets, Xb, yb, wb, ex, rows, *extra):
             cols, f, s = unpack(extra)
@@ -891,24 +894,43 @@ class RandomEffectCoordinate:
 
     def _solve_one(self, X, y, w, o, w0, norm=None, intercept_index=_UNSET):
         """One entity's GLM solve in transformed space (vmapped per bucket):
-        the fitted row, and the iterations and objective evaluations the
-        solver took for it.
+        the fitted row, and the iterations, objective evaluations and
+        line-search trials the solver took for it.
 
         The projected path passes a per-entity NormalizationContext and the
         projected intercept slot; the unprojected path uses the coordinate's
         own (closed-over) full-space values.
+
+        L-BFGS takes the objective apart as a ``LineOracle``
+        (``_line_oracle``): a wave's line searches then read rows, and an
+        iteration of a lane is one pair of passes over its block whatever
+        the slowest lane's search needed.
         """
         norm = self.norm if norm is None else norm
         ii = self.intercept_index if intercept_index is _UNSET \
             else intercept_index
         batch = LabeledBatch(X, y, w, o)
-        vg, hvp, l1w = make_objective(
-            self.loss, batch, norm, self.config.regularization,
-            ii, X.shape[-1])
+        problem = (self.loss, batch, norm, self.config.regularization, ii,
+                   X.shape[-1])
+        vg, hvp, l1w = make_objective(*problem)
         opt_cfg = resolve_optimizer_config(
             self.config.optimizer, l1w is not None)
-        result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w)
-        return result.w, result.iterations, result.evaluations
+        line = make_line_oracle(*problem) if self._line_oracle else None
+        result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w,
+                          line=line)
+        trials = result.trials  # TRON has no line search
+        return (result.w, result.iterations, result.evaluations,
+                jnp.zeros_like(result.iterations) if trials is None
+                else trials)
+
+    @property
+    def _line_oracle(self) -> bool:
+        """Whether the lane solves' line searches go through a
+        ``LineOracle``: plain L-BFGS does; OWL-QN's trial points leave the
+        line (an L1 table) and TRON has no line search."""
+        return (self.config.regularization.l1_weight() == 0.0
+                and OptimizerType(self.config.optimizer.optimizer_type)
+                == OptimizerType.LBFGS)
 
     def _variance_one(self, X, y, w, o, w_opt, norm=None,
                       intercept_index=_UNSET):
@@ -1090,7 +1112,8 @@ class RandomEffectCoordinate:
         fields = dict(
             re_type=self.re_type, wave=wave, seconds=round(seconds, 6),
             entities_fit=int(fit.sum()), cap=cap, lanes=lanes,
-            rows_useful=int(counts.sum()), rows_padded=lanes * cap)
+            rows_useful=int(counts.sum()), rows_padded=lanes * cap,
+            line="oracle" if self._line_oracle else "evaluation")
         active = self._host_active[wave]
         if active is not None:
             # A projected wave's width is its class's: the cells a lane's

@@ -45,7 +45,10 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
   asynchronous), the ``entities_fit`` lane count, the dispatch's shape
   (``cap``, ``lanes``, ``rows_useful``, ``rows_padded``), and the
   solver's own counts reduced on the device over live lanes
-  (``iters_sum``, ``iters_max``, ``evals_sum``, ``lanes_at_cap``).
+  (``iters_sum``, ``iters_max``, ``evals_sum``, ``lanes_at_cap``,
+  ``trials_sum``), and ``line``: whether a line-search trial read the
+  lane's rows alone (``oracle``) or evaluated the objective over its
+  block (``evaluation``).
   Written through :meth:`RunLedger.defer`: the counts are read once per
   update, after the descent loop's barrier.
 * ``phase`` — one set-up phase, written as it ends: ``name``

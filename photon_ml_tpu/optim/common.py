@@ -91,6 +91,10 @@ class OptResult:
     # coefficients of the iterate it ended on
     trials_history: Optional[Array] = None
     nnz_history: Optional[Array] = None
+    # L-BFGS and OWL-QN: int32, the trials of all the solve's line searches,
+    # whatever a trial cost (under a LineOracle no pass over the data, so
+    # ``evaluations`` no longer holds them)
+    trials: Optional[Array] = None
 
 
 def scoped(name: str, fn: Optional[Callable] = None) -> Callable:

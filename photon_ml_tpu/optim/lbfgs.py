@@ -34,7 +34,11 @@ optimization library, the whole optimizer is a single compiled state machine:
   meets the data through margins alone (a GLM), the caller hands over a
   ``LineOracle``: a line search then makes one margins pass for the
   direction and one gradient pass at the point it accepts, and its trials
-  none, so an iteration costs the same whatever the search needed. OWL-QN's
+  none, so an iteration costs the same whatever the search needed. Two
+  callers do: the resident sparse fixed effect
+  (``parallel/sparse_problem._hybrid_line``) and the vmapped bucket solves
+  of a random-effect table (``optim/problem.make_line_oracle``), where a
+  wave pays its slowest lane's trials at every iteration. OWL-QN's
   trial points are projected onto an orthant and leave the line, so its
   oracle is a ``ValueOracle``: a trial makes the one pass its value needs,
   and the gradient's pass is made once, at the point accepted;
@@ -115,6 +119,7 @@ class _LBFGSState:
     it: Array  # int32
     evals: Array  # int32: value_and_grad calls so far, trials included
     #               (under a LineOracle: pairs of passes over the data)
+    trials: Array  # int32: line-search trials so far, whatever each cost
     converged: Array  # bool
     failed: Array  # bool: line search stalled
     g0_norm: Array
@@ -229,7 +234,8 @@ def minimize(
     ``evaluations`` counts pairs of passes over the data: the first
     evaluation and one an iteration. Under OWL-QN it counts objective
     values taken, 1 + Σ trials, with or without the oracle, which only
-    spares each trial its gradient.
+    spares each trial its gradient. ``trials`` counts the line searches'
+    trials in every case.
     """
     m = config.history_length
     max_iter = config.max_iterations
@@ -323,6 +329,7 @@ def minimize(
         count=jnp.asarray(0, jnp.int32),
         it=jnp.asarray(0, jnp.int32),
         evals=jnp.asarray(1, jnp.int32),  # the evaluation at w0
+        trials=jnp.asarray(0, jnp.int32),
         converged=g0_norm <= config.tolerance,
         failed=jnp.asarray(False),
         g0_norm=g0_norm,
@@ -443,7 +450,7 @@ def minimize(
         no pass over the data; what is kept of a trial is its α, and the
         gradient is taken once, at the α the search ends on (0 where no
         trial met Armijo: the point it started from). One pair of passes
-        whatever the trials, and that is what it reports."""
+        whatever the trials it reports."""
         c1 = config.wolfe_c1
         c2 = config.wolfe_c2
         dg0 = _dot(sg, direction)
@@ -475,11 +482,11 @@ def minimize(
               jnp.asarray(False), jnp.asarray(False), zero, ft)
         with jax.named_scope("lbfgs.line_search"):
             ray = line.along(carry, w, direction)
-            _, _, _, _, done, has_pt, alpha, _ = lax.while_loop(
+            _, _, _, steps, done, has_pt, alpha, _ = lax.while_loop(
                 ls_cond, ls_body, st)
             new_f, new_g, new_carry = line.accept(ray, alpha)
-        return (done | has_pt, w + alpha * direction, new_f, new_g,
-                jnp.asarray(1, jnp.int32), new_carry)
+        return (done | has_pt, w + alpha * direction, new_f, new_g, steps,
+                new_carry)
 
     def body(state: _LBFGSState) -> _LBFGSState:
         sg = search_gradient(state.w, state.g)
@@ -512,6 +519,9 @@ def minimize(
         else:
             ok, new_w, new_f, new_g, trials, carry = line_search_along(
                 state.w, ft, sg, d_dir, state.carry)
+        # What the search cost in evaluations: every trial one, but under a
+        # LineOracle one pair of passes whatever the trials.
+        asked = trials if is_owlqn or line is None else 1
 
         with jax.named_scope("lbfgs.direction"):  # the history update
             s = new_w - state.w
@@ -558,7 +568,8 @@ def minimize(
             s_hist=s_hist, y_hist=y_hist, rho=rho,
             count=new_count,
             it=it,
-            evals=state.evals + trials,
+            evals=state.evals + asked,
+            trials=state.trials + trials,
             converged=state.converged | conv | failed,
             failed=state.failed | failed,
             g0_norm=state.g0_norm,
@@ -585,6 +596,7 @@ def minimize(
         grad_norm=jnp.linalg.norm(sg_final),
         iterations=final.it,
         evaluations=final.evals,
+        trials=final.trials,
         converged=final.converged & ~final.failed,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,

@@ -25,9 +25,10 @@ from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops import aggregators as agg
 from photon_ml_tpu.ops.losses import PointwiseLoss
-from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType, OptResult,
-                                 RegularizationContext, l1_weights_vector,
-                                 optimize, with_l2, with_l2_hvp)
+from photon_ml_tpu.optim import (LineOracle, OptimizerConfig, OptimizerType,
+                                 OptResult, RegularizationContext,
+                                 l1_weights_vector, optimize, with_l2,
+                                 with_l2_hvp)
 from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.regularization import intercept_mask
 
@@ -101,6 +102,97 @@ def make_objective(
     l1_weights = (l1_weights_vector(l1, dim, intercept_index)
                   if l1 > 0.0 else None)
     return vg, hvp, l1_weights
+
+
+def make_line_oracle(
+    loss: PointwiseLoss,
+    batch: LabeledBatch,
+    norm: NormalizationContext,
+    reg: RegularizationContext,
+    intercept_index: Optional[int],
+    dim: int,
+) -> LineOracle:
+    """``make_objective``'s smooth objective, Σ wᵢ·l(zᵢ, yᵢ) + ½·λ‖w∘mask‖²
+    with z = X'·w + offset, taken apart for L-BFGS's line search
+    (optim/lbfgs.py). An evaluation is two passes over ``batch.features``,
+    and under ``vmap`` a wave of bucket solves pays its slowest lane's
+    trials at every iteration. Along w + αd the margins are z + α·(X'·d)
+    and ‖(w + αd)∘mask‖² a quadratic in α: ``along`` crosses the block once
+    for X'·d (the same affine map as the margins' without the offset, so
+    factors and shifts stay linear in d) and takes the quadratic's two dot
+    products; a trial then reads rows (margins, labels, weights) and
+    scalars, no feature and no coefficient; ``accept`` crosses once more
+    for the gradient. Zero-weight rows keep margin 0 and weight 0, as
+    ``agg.margins`` and ``agg._masked`` hold them.
+
+    What is carried from point to point is the margins and that squared
+    norm, both as the line gave them: the value ``accept`` reports is then
+    the accepted trial's own to the bit, and the next search's φ(0) equals
+    it, as they do where every trial is an evaluation. The stopping rule
+    compares consecutive values at float32's last place, where a value
+    recomputed another way (the L2 term from the coefficients) sits a
+    unit apart from its trial's."""
+    mask = jnp.asarray(intercept_mask(dim, intercept_index))
+    l2 = reg.l2_weight()
+    live = batch.weights > 0.0
+
+    def at(z, ww):
+        """(f, the rows' weighted dl/dz) at margins z of a point whose
+        masked squared norm is ww."""
+        l, dl = loss.loss_and_dz(z, batch.labels)
+        f = jnp.sum(agg._masked(batch.weights, l), axis=-1)
+        return (f + 0.5 * l2 * ww if l2 else f,
+                agg._masked(batch.weights, dl))
+
+    def gradient(r, w):
+        g = norm.pullback_gradient(agg._tmatvec(batch.features, r),
+                                   jnp.sum(r, axis=-1))
+        return g + l2 * (w * mask) if l2 else g
+
+    def masked_dot(a, b):
+        return jnp.sum((a * mask) * (b * mask), axis=-1)
+
+    def step(ray, alpha):
+        """The carry at w + αd."""
+        z, u, _, _, quad = ray
+        if not l2:
+            return z + alpha * u, None
+        ww, wd, dd = quad
+        return z + alpha * u, ww + alpha * (2.0 * wd + alpha * dd)
+
+    @scoped("glm.value_grad")
+    def start(w):
+        carry = agg.margins(batch, w, norm), masked_dot(w, w) if l2 else None
+        f, r = at(*carry)
+        return f, gradient(r, w), carry
+
+    @scoped("glm.value_grad")
+    def along(carry, w, d):
+        z, ww = carry
+        d_eff, shift = norm.effective_coefficients(d)
+        u = jnp.where(live, agg._matvec(batch.features, d_eff)
+                      + jnp.expand_dims(shift, -1), 0.0)
+        quad = (ww, masked_dot(w, d), masked_dot(d, d)) if l2 else None
+        return z, u, w, d, quad
+
+    @scoped("glm.value_grad")
+    def trial(ray, alpha):
+        _, u, _, _, quad = ray
+        f, r = at(*step(ray, alpha))
+        slope = jnp.sum(r * u, axis=-1)
+        if l2:
+            _, wd, dd = quad
+            slope = slope + l2 * (wd + alpha * dd)
+        return f, slope
+
+    @scoped("glm.value_grad")
+    def accept(ray, alpha):
+        _, _, w, d, _ = ray
+        carry = step(ray, alpha)
+        f, r = at(*carry)
+        return f, gradient(r, w + alpha * d), carry
+
+    return LineOracle(start, along, trial, accept)
 
 
 def run(

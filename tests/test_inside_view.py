@@ -48,7 +48,7 @@ MAX_IT = 6
 SEQ = ["fixed", "per-user"]
 WAVE_FIELDS = ("cap", "lanes", "rows_useful", "rows_padded", "iters_sum",
                "iters_max", "evals_sum", "lanes_at_cap", "entities_fit",
-               "seconds")
+               "seconds", "trials_sum", "line")
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
@@ -134,7 +134,11 @@ def test_wave_rows_carry_the_solver_counts(game, tmp_path, variant):
         assert w["coordinate"] == "per-user"
         assert 0 < w["iters_max"] <= MAX_IT
         assert w["iters_sum"] <= w["entities_fit"] * w["iters_max"]
-        assert w["evals_sum"] >= w["iters_sum"] + w["entities_fit"]
+        # L-BFGS lanes without L1 search through the oracle: an
+        # evaluation is a pair of passes, the trials are counted apart
+        assert w["line"] == "oracle"
+        assert w["evals_sum"] == w["iters_sum"] + w["entities_fit"]
+        assert w["trials_sum"] >= w["iters_sum"]
         assert 0 < w["rows_useful"] <= w["rows_padded"] == \
             w["lanes"] * w["cap"]
         assert 0 <= w["lanes_at_cap"] <= w["entities_fit"] <= w["lanes"]

@@ -1,7 +1,7 @@
 """The cell of ISSUE 33, benchmark side, on the CPU: the cell rehearsed
 through ``benchmark/run.py`` reads what it read when recorded (limits, keys
 and ``argv`` from ``benchmark/selfcheck/kdd12.rehearsal.expected.json``, the
-readings from ``tests/data/kdd12.rehearsal.pr35.json``), its control and its
+readings from ``tests/data/kdd12.rehearsal.pr36.json``), its control and its
 four faults read ``correct`` false each by the number that exists for it, the
 selfcheck holds the new schema to the contract, the new device readers read a
 hand-made trace, and ``BENCHMARK.json`` gained the entries and lost
@@ -28,7 +28,7 @@ from record_scoped import field, plane  # noqa: E402  (its xplane encoder)
 
 CELL = "kdd12-poisson-l1.steady"
 EXPECTED = os.path.join(BENCH, "selfcheck", "kdd12.rehearsal.expected.json")
-RECORDED = os.path.join(REPO, "tests", "data", "kdd12.rehearsal.pr35.json")
+RECORDED = os.path.join(REPO, "tests", "data", "kdd12.rehearsal.pr36.json")
 OLD_READERS = {"stage_s", "update_s.fixed", "fe_iters", "fe_pass_roofline",
                "sweep_mfu", "device_idle_share", "ls_evals.fixed",
                "phase_s.digest", "phase_s.bucketing", "phase_s.host_stage",
@@ -74,7 +74,8 @@ def test_the_rehearsal_reads_what_it_read(run, capsys):
     # claims a gain may not record anew, and all but ``grad0`` and
     # ``zeros.fixed`` are the slack of solves cut at 25 iterations, which
     # follows the order of the float32 partial sums (since ISSUE 34 a hot
-    # column's counts against the rows, then its scale). ``grad0`` holds
+    # column's counts against the rows, then its scale; since ISSUE 36 the
+    # table's accepted gradients from carried margins). ``grad0`` holds
     # every entry of the first gradient: the count block reads it closer to
     # the float64 one than the float32 block did, never farther.
     with open(RECORDED) as f:
