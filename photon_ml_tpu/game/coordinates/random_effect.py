@@ -124,6 +124,12 @@ def _subspace_sparse_scores(W_flat, flatpos, values):
     return acc
 
 
+def _class_of(arrays) -> str:
+    """A wave's program class, ``lanes x cap x width``: its feature
+    block's shape."""
+    return "x".join(str(int(v)) for v in arrays[0].shape)
+
+
 def _struct(a) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
 
@@ -183,10 +189,18 @@ class _WavePrograms:
                          name="pml-re-compile-plan").start()
 
     def __call__(self, W, offsets, *arrays):
-        self._planned.wait()
+        # The fit thread's waits on the compilers, each a ``re.compile_wait``
+        # row where it blocked; a steady sweep finds everything done
+        if not self._planned.is_set():
+            with obs.phase("re.compile_wait", program=_class_of(arrays)):
+                self._planned.wait()
         key = self._key(arrays)
         pending = self._compiled.get(key)
         if pending is not None:
+            if not pending.done():
+                with obs.phase("re.compile_wait",
+                               program=_class_of(arrays)):
+                    cf.wait([pending])
             try:
                 return pending.result()(W, offsets, *arrays)
             except (TypeError, ValueError) as e:

@@ -21,6 +21,7 @@ import dataclasses
 import enum
 import hashlib
 import logging
+import threading
 import time
 from typing import Callable, Optional
 
@@ -100,110 +101,114 @@ def run(
     some = coordinates[seq[0]]
     n = some.dataset.num_rows
 
-    led = obs.ledger()
-    fingerprint = None
-    resume = None
-    if checkpoint_manager is not None or led is not None:
-        fingerprint = _fingerprint(task, coordinates, seq, config, locked, n)
-    if led is not None:
-        # Stamp (or validate, on a --resume append) the run ledger's
-        # identity from the SAME fingerprint machinery the checkpoint
-        # trusts — a ledger never silently continues a different run's
-        # curve (obs/ledger.py).
-        led.bind_fingerprint(fingerprint)
-    if checkpoint_manager is not None:
-        resume = checkpoint_manager.load(expected_fingerprint=fingerprint)
-    history = CoordinateDescentHistory()
-    done_steps = 0
-    if resume is not None:
-        initial_models = {**(initial_models or {}), **resume.models}
-        done_steps = resume.done_steps
-        history.records = list(resume.records)
-        logger.info("resuming coordinate descent from checkpoint: "
-                    "%d updates already done", done_steps)
-        if resume.complete:
-            return (GameModel(task=task, models=dict(resume.models)),
-                    history)
-        # Fast-forward per-coordinate down-sampling RNGs past the completed
-        # train calls so the remaining steps draw the SAME subsamples as an
-        # uninterrupted run would have.
-        completed: dict[str, int] = {}
-        for rec in resume.records:
-            completed[rec["coordinate"]] = \
-                completed.get(rec["coordinate"], 0) + 1
-        for cid, k in completed.items():
-            advance = getattr(coordinates.get(cid), "advance_down_sampling",
-                              None)
-            if advance is not None:
-                advance(k)
+    # Set-up before the first update: identity, resume, the initial
+    # models and their scores (programs loaded and run in it)
+    with obs.phase("descent.init"):
+        led = obs.ledger()
+        fingerprint = None
+        resume = None
+        if checkpoint_manager is not None or led is not None:
+            fingerprint = _fingerprint(task, coordinates, seq, config,
+                                       locked, n)
+        if led is not None:
+            # Stamp (or validate, on a --resume append) the run ledger's
+            # identity from the SAME fingerprint machinery the checkpoint
+            # trusts — a ledger never silently continues a different run's
+            # curve (obs/ledger.py).
+            led.bind_fingerprint(fingerprint)
+        if checkpoint_manager is not None:
+            resume = checkpoint_manager.load(expected_fingerprint=fingerprint)
+        history = CoordinateDescentHistory()
+        done_steps = 0
+        if resume is not None:
+            initial_models = {**(initial_models or {}), **resume.models}
+            done_steps = resume.done_steps
+            history.records = list(resume.records)
+            logger.info("resuming coordinate descent from checkpoint: "
+                        "%d updates already done", done_steps)
+            if resume.complete:
+                return (GameModel(task=task, models=dict(resume.models)),
+                        history)
+            # Fast-forward per-coordinate down-sampling RNGs past the completed
+            # train calls so the remaining steps draw the SAME subsamples as an
+            # uninterrupted run would have.
+            completed: dict[str, int] = {}
+            for rec in resume.records:
+                completed[rec["coordinate"]] = \
+                    completed.get(rec["coordinate"], 0) + 1
+            for cid, k in completed.items():
+                advance = getattr(coordinates.get(cid),
+                                  "advance_down_sampling", None)
+                if advance is not None:
+                    advance(k)
 
-    models: dict[str, CoordinateModel] = {}
-    scores: dict[str, jnp.ndarray] = {}
-    base = jnp.asarray(some.dataset.offsets)
-    total = jnp.zeros((n,), jnp.float32)
+        models: dict[str, CoordinateModel] = {}
+        scores: dict[str, jnp.ndarray] = {}
+        base = jnp.asarray(some.dataset.offsets)
+        total = jnp.zeros((n,), jnp.float32)
 
-    # At scale, synchronize the dispatch stream once per coordinate
-    # update. JAX enqueues every fit/score program ahead of execution, and
-    # the runtime holds each queued program's output and scratch buffers
-    # from ENQUEUE time — a full un-synced descent sweep at 19M rows
-    # reproducibly exhausts HBM even though the same programs run fine
-    # back-to-back with a barrier between them (and the resident arrays
-    # total only a few GB). The barrier costs one host-device round trip
-    # per coordinate update, so it is gated on an ESTIMATE of the scratch a
-    # fully un-synced descent would hold: per queued update, O(n) score
-    # outputs plus working buffers scaling with the coordinate's feature
-    # dim (capped — sparse/tiled formulations never materialize n×d), for
-    # every update the whole descent enqueues. Small configs keep full
-    # dispatch pipelining; config.sync_updates forces either way.
-    if config.sync_updates is not None:
-        sync_updates = bool(config.sync_updates)
-    else:
-        # The byte estimate only ever ADDS protection beyond the empirical
-        # n >= 4.2M row floor (where the 19M OOM was reproduced): the
-        # estimate undercounts RE training scratch, so it must not be able
-        # to turn the barrier OFF in the regime the floor covers.
-        est_bytes = 0
+        # At scale, synchronize the dispatch stream once per coordinate
+        # update. JAX enqueues every fit/score program ahead of execution, and
+        # the runtime holds each queued program's output and scratch buffers
+        # from ENQUEUE time — a full un-synced descent sweep at 19M rows
+        # reproducibly exhausts HBM even though the same programs run fine
+        # back-to-back with a barrier between them (and the resident arrays
+        # total only a few GB). The barrier costs one host-device round trip
+        # per coordinate update, so it is gated on an ESTIMATE of the scratch a
+        # fully un-synced descent would hold: per queued update, O(n) score
+        # outputs plus working buffers scaling with the coordinate's feature
+        # dim (capped — sparse/tiled formulations never materialize n×d), for
+        # every update the whole descent enqueues. Small configs keep full
+        # dispatch pipelining; config.sync_updates forces either way.
+        if config.sync_updates is not None:
+            sync_updates = bool(config.sync_updates)
+        else:
+            # The byte estimate only ever ADDS protection beyond the empirical
+            # n >= 4.2M row floor (where the 19M OOM was reproduced): the
+            # estimate undercounts RE training scratch, so it must not be able
+            # to turn the barrier OFF in the regime the floor covers.
+            est_bytes = 0
+            for cid in seq:
+                dim = int(getattr(coordinates[cid], "dim", 8) or 8)
+                est_bytes += n * 4 * (2 + min(dim, 4096))
+            est_bytes *= max(1, config.iterations)
+            sync_updates = n >= (1 << 22) or est_bytes >= (1 << 30)
+
+        def _sync(x):
+            if sync_updates:
+                jax.block_until_ready(x)
+
+        # Initialize models (warm starts / checkpoint state) and their scores.
         for cid in seq:
-            dim = int(getattr(coordinates[cid], "dim", 8) or 8)
-            est_bytes += n * 4 * (2 + min(dim, 4096))
-        est_bytes *= max(1, config.iterations)
-        sync_updates = n >= (1 << 22) or est_bytes >= (1 << 30)
+            coord = coordinates[cid]
+            if initial_models and cid in initial_models:
+                # Cross-type warm starts (full-rank ↔ factored random
+                # effects) convert here so scoring and training see the
+                # coordinate's own model type.
+                adapt = getattr(coord, "adapt_initial", None)
+                models[cid] = (adapt(initial_models[cid]) if adapt
+                               else initial_models[cid])
+            else:
+                models[cid] = coord.initial_model()
+            s = coord.score(models[cid])
+            scores[cid] = s
+            total = total + s
+            _sync(total)
 
-    def _sync(x):
-        if sync_updates:
-            jax.block_until_ready(x)
-
-    # Initialize models (warm starts / checkpoint state) and their scores.
-    for cid in seq:
-        coord = coordinates[cid]
-        if initial_models and cid in initial_models:
-            # Cross-type warm starts (full-rank ↔ factored random effects)
-            # convert here so scoring and training see the coordinate's
-            # own model type.
-            adapt = getattr(coord, "adapt_initial", None)
-            models[cid] = (adapt(initial_models[cid]) if adapt
-                           else initial_models[cid])
-        else:
-            models[cid] = coord.initial_model()
-        s = coord.score(models[cid])
-        scores[cid] = s
-        total = total + s
-        _sync(total)
-
-    if resume is not None and resume.residual_total is not None:
-        restored = np.asarray(resume.residual_total)
-        # Benign mismatch vs the fresh sum is f32 accumulation-order noise
-        # (~1e-6); a kill between the model-dir and residual writes leaves
-        # a step-sized gap instead. Restore only in the former case — the
-        # fresh sum is always consistent with the model files.
-        if restored.shape == total.shape and np.allclose(
-                np.asarray(total), restored, rtol=1e-5, atol=1e-5):
-            total = jnp.asarray(restored)
-        else:
-            logger.warning(
-                "checkpoint residuals disagree with re-summed scores; "
-                "using the re-summed total (resume stays correct but is "
-                "no longer bit-exact)")
+        if resume is not None and resume.residual_total is not None:
+            restored = np.asarray(resume.residual_total)
+            # Benign mismatch vs the fresh sum is f32 accumulation-order noise
+            # (~1e-6); a kill between the model-dir and residual writes leaves
+            # a step-sized gap instead. Restore only in the former case — the
+            # fresh sum is always consistent with the model files.
+            if restored.shape == total.shape and np.allclose(
+                    np.asarray(total), restored, rtol=1e-5, atol=1e-5):
+                total = jnp.asarray(restored)
+            else:
+                logger.warning(
+                    "checkpoint residuals disagree with re-summed scores; "
+                    "using the re-summed total (resume stays correct but is "
+                    "no longer bit-exact)")
 
     emitter = ev_mod.default_emitter
     emitter.emit(ev_mod.TrainingStart(
@@ -221,6 +226,7 @@ def run(
                     continue  # already covered by the checkpoint
                 coord = coordinates[cid]
                 t0 = time.monotonic()
+                start = time.perf_counter()
                 # Ledger context: every telemetry row the update's
                 # optimizer produces (live opt_iter rows, compiled
                 # spills, RE waves) carries which coordinate/step it
@@ -278,7 +284,9 @@ def run(
                     led.record("coordinate_update", coordinate=cid,
                                outer_iteration=it, step=step,
                                seconds=round(elapsed, 6),
-                               validation=rec.get("validation"))
+                               validation=rec.get("validation"),
+                               t0=led.clock(start),
+                               thread=threading.current_thread().name)
                 if checkpoint_manager is not None:
                     checkpoint_manager.save(
                         task, models, done_steps=step,

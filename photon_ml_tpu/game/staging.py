@@ -496,6 +496,10 @@ class ProjectionStager:
         """Yield staged host tuples in plan order (blocking); the depth
         bound is released as the consumer takes each staged shard."""
         for i in range(self.num_shards):
+            if not self._futures[i].done():
+                # the fit thread waits on the stager: a ``re.stage_wait`` row
+                with obs.phase("re.stage_wait", shard=i):
+                    cf.wait([self._futures[i]])
             src, t = self._futures[i].result()
             if self._t_first_taken is None:
                 self._t_first_taken = time.monotonic()
@@ -864,11 +868,14 @@ class ProjectionStager:
             led = obs.ledger()
             if led is not None:
                 # The pipelined stager's host pass as one set-up phase
-                # (it overlaps the first fits, so it has no parent).
+                # (it overlaps the first fits, so it has no parent; its
+                # thread is the one that staged the last shard).
+                span = dict(t0=led.clock(time.perf_counter() - wall),
+                            thread=threading.current_thread().name)
                 led.record("phase", name="re.host_stage", parent=None,
                            seconds=round(wall, 6), label=self._label,
                            shards=self.num_shards,
-                           cached_shards=len(self._cached))
+                           cached_shards=len(self._cached), **span)
                 # The projection itself, by its steps: the one pass that
                 # splits the shard's non-zeros by lane slice, phase A (the
                 # active pairs and each class's width) and phase B (column
@@ -883,7 +890,8 @@ class ProjectionStager:
                                wall - (self._t_cols - self._t0), 6),
                            bytes=self._pass["bytes"],
                            workers=self.config.resolved_workers(),
-                           overlapped=self._t_first_taken is not None)
+                           overlapped=self._t_first_taken is not None,
+                           **span)
             self._maybe_finalize()
 
     def _maybe_finalize(self):

@@ -197,10 +197,11 @@ def current_phase() -> Optional[str]:
 @contextlib.contextmanager
 def phase(name: str, **fields):
     """One set-up phase on the run ledger: a ``phase`` row (``name``,
-    ``seconds``, ``parent`` = the phase open around it on this thread)
-    written as the block ends. Yields the row's extra fields, so a site
-    can add what it learns inside (``bytes`` of a transfer). With no
-    ledger open: the one None check."""
+    ``seconds``, ``parent`` = the phase open around it on this thread,
+    ``t0`` = its start on the ledger's clock, ``thread``) written as the
+    block ends. Yields the row's extra fields, so a site can add what it
+    learns inside (``bytes`` of a transfer). With no ledger open: the one
+    None check."""
     led = _LEDGER
     if led is None:
         yield fields
@@ -214,17 +215,20 @@ def phase(name: str, **fields):
     finally:
         stack.pop()
         led.record("phase", name=name, parent=parent,
-                   seconds=round(time.perf_counter() - t0, 6), **fields)
+                   seconds=round(time.perf_counter() - t0, 6),
+                   t0=led.clock(t0), thread=threading.current_thread().name,
+                   **fields)
 
 
 _PROGRAM_LOADS: Optional[ProgramLoads] = None
 
 
 def record_program_loads() -> None:
-    """Register, once per process, the ``jax.monitoring`` listener that
-    writes a ``program.load`` phase row (obs/programs.py) for every program
-    the process traces, lowers, compiles or fetches from the persistent
-    cache WHILE a run ledger is open. Rows carry the ledger's bound
+    """Register, once per process, the ``jax.monitoring`` listeners that
+    write a ``program.load`` phase row (obs/programs.py) for every step of
+    every program the process traces, lowers, compiles or fetches from the
+    persistent cache WHILE a run ledger is open: an interval (``t0``,
+    ``seconds``) on the thread that took it. Rows carry the ledger's bound
     context, so one inside a steady window says which update recompiled."""
     global _PROGRAM_LOADS
     with _LOCK:
@@ -233,14 +237,27 @@ def record_program_loads() -> None:
         _PROGRAM_LOADS = loads = ProgramLoads()
     import jax
 
-    def on_duration(event, duration_secs, **kw):
-        rows = loads.rows(event, duration_secs, **kw)  # always: it pairs
+    def write(rows):
         led = _LEDGER
-        if led is not None:
-            for fields in rows:
-                led.record("phase", name="program.load",
-                           parent=current_phase(), **fields)
+        if led is None or not rows:
+            return
+        now = time.perf_counter()
+        for fields in rows:
+            led.record("phase", name="program.load", parent=current_phase(),
+                       t0=led.clock(now - fields.pop("ago")),
+                       thread=threading.current_thread().name, **fields)
 
+    def on_start(event, value, **kw):  # always: it counts the nesting
+        loads.started(event, **kw)
+
+    def on_span(event, start, end, **kw):
+        write(loads.ended(event, start, end, **kw))
+
+    def on_duration(event, duration_secs, **kw):
+        write(loads.fetched(event, duration_secs))
+
+    jax.monitoring.register_scalar_listener(on_start)
+    jax.monitoring.register_event_time_span_listener(on_span)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 

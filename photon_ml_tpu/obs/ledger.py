@@ -39,7 +39,7 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
   "post_fit"`` — wall resolution is then the coordinate update, not the
   iteration).
 * ``coordinate_update`` — one descent step: coordinate, seconds,
-  validation metrics.
+  validation metrics, ``t0`` (the step's start on the ledger's clock).
 * ``re_fit_wave`` — one vmapped random-effect fit-wave dispatch:
   re_type, wave index, ``seconds`` (the ENQUEUE's: dispatch is
   asynchronous), the ``entities_fit`` lane count, the dispatch's shape
@@ -54,8 +54,9 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
 * ``phase`` — one set-up phase, written as it ends: ``name``
   (``fit.digest``, ``re.bucketing``, ``re.host_stage``,
   ``re.transfer``/``fe.transfer`` with ``bytes``, ``program.load`` with
-  ``event`` and ``program``), ``seconds``, ``parent``
-  (docs/OBSERVABILITY.md "The run ledger").
+  ``event`` and ``program``, the fit thread's waits ``re.compile_wait``
+  and ``re.stage_wait``), ``seconds``, ``parent``, and the interval's
+  ``t0`` and ``thread`` (docs/OBSERVABILITY.md "The run ledger").
 * ``tuning_trial`` — one hyperparameter trial: sampled point, expected
   improvement (GP search), objective, wall seconds.
 * ``watchdog`` — a convergence-watchdog alert (obs/watchdog.py).
@@ -340,6 +341,11 @@ class RunLedger:
         self.manifest.setdefault("clock", []).append(
             {"t": self._t_base, "monotonic": time.monotonic(),
              "time_ns": time.time_ns()})
+
+    def clock(self, perf: float) -> float:
+        """A ``time.perf_counter()`` reading on this ledger's clock, the
+        one every row's ``t`` is on: a row's ``t0`` (obs.phase)."""
+        return round(self._t_base + perf - self._anchor, 6)
 
     def _append_locked(self, kind: str, fields: dict) -> None:
         row = dict(self._ctx)

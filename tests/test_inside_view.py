@@ -223,20 +223,12 @@ def test_model_is_bitwise_the_same_with_a_ledger(game, tmp_path):
         again = _descend(coords)
         n_plain = len(lowered) - n_led
     finally:
-        _unregister(on_duration)
+        jax.monitoring.unregister_event_duration_listener(on_duration)
     for k in plain:
         assert np.array_equal(plain[k], with_led[k]), k
         assert np.array_equal(plain[k], again[k]), k
     # the ledger asks for no program the plain fit does not ask for
     assert n_led == n_plain, lowered
-
-
-def _unregister(callback):
-    from jax._src import monitoring
-    drop = getattr(monitoring, "_unregister_event_duration_listener_by_callback",
-                   None)
-    if drop is not None:
-        drop(callback)
 
 
 # -- (c) no read before the barrier -------------------------------------------
@@ -328,20 +320,34 @@ def test_phase_rows_and_program_loads(tmp_path):
         np.random.default_rng(11), n=320, d_global=4,
         re_specs={"userId": (12, 3)}))
     d = str(tmp_path / "ledger")
+    # a listener of the test's own on the same interval events, beside the
+    # one obs.record_program_loads registers
     mine = obs.ProgramLoads()
     seen = []
 
-    def on_duration(event, duration_secs, **kw):
-        rows = mine.rows(event, duration_secs, **kw)
+    def keep(rows):
         if obs.ledger() is not None:
             seen.extend(rows)
 
+    def on_start(event, value, **kw):
+        mine.started(event, **kw)
+
+    def on_span(event, start, end, **kw):
+        keep(mine.ended(event, start, end, **kw))
+
+    def on_duration(event, duration_secs, **kw):
+        keep(mine.fetched(event, duration_secs))
+
     t_before = time.monotonic()
+    jax.monitoring.register_scalar_listener(on_start)
+    jax.monitoring.register_event_time_span_listener(on_span)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
         _estimator(d).fit(ds)
     finally:
-        _unregister(on_duration)
+        jax.monitoring.unregister_scalar_listener(on_start)
+        jax.monitoring.unregister_event_time_span_listener(on_span)
+        jax.monitoring.unregister_event_duration_listener(on_duration)
     t_after = time.monotonic()
     rows, problems = read_rows(d)
     assert problems == []
@@ -349,26 +355,32 @@ def test_phase_rows_and_program_loads(tmp_path):
     names = {r["name"] for r in phases}
     assert {"fit.digest", "fit.coordinates", "re.bucketing", "re.host_stage",
             "re.transfer", "fe.transfer", "program.load"} <= names
-    assert all(r["seconds"] >= 0 for r in phases if r["name"] !=
-               "program.load")
+    assert all(r["seconds"] >= 0 for r in phases)
     for r in phases:
         if r["name"].endswith(".transfer"):
             assert r["bytes"] > 0 and r["parent"] == "fit.coordinates"
         if r["name"] in ("re.bucketing", "re.host_stage"):
             assert r["parent"] == "fit.coordinates"
+        # an interval on a thread: it starts before it is written
+        assert r["t0"] <= r["t"] and r["thread"] == "MainThread"
     loads = [{k: r[k] for k in ("event", "program", "seconds")}
              for r in phases if r["name"] == "program.load"]
-    assert loads and loads == seen  # one for one, in order
+    assert loads and loads == [{k: r[k] for k in ("event", "program",
+                                                  "seconds")}
+                               for r in seen]  # one for one, in order
     assert {r["event"] for r in loads} >= {"trace", "lower", "compile"}
     fits = [r for r in phases if r["name"] == "program.load"
-            and r["program"] == "jit(fit_bucket)"]
-    assert fits and all(r["coordinate"] == "per-user"
-                        and r["outer_iteration"] == 0 for r in fits)
+            and r["program"] in ("fit_bucket", "jit(fit_bucket)")]
+    assert {r["event"] for r in fits} >= {"trace", "lower", "compile"}
+    assert all(r["coordinate"] == "per-user" and r["outer_iteration"] == 0
+               for r in fits)
     # the manifest's anchors put every row on the host's monotonic clock
     manifest = read_manifest(d)
     assert len(manifest["clock"]) == 1 and manifest["clock"][0]["t"] == 0.0
     for r in rows:
         assert t_before <= monotonic_of(manifest, r["t"]) <= t_after
+        if "t0" in r:
+            assert t_before <= monotonic_of(manifest, r["t0"]) <= t_after
     assert monotonic_of({}, 1.0) is None
 
 

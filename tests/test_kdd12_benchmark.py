@@ -37,6 +37,11 @@ OLD_READERS = {"stage_s", "update_s.fixed", "fe_iters", "fe_pass_roofline",
                "scope_s.direction", "scope_s.gather_scatter", "scope_s.score",
                "sparse_s.hot", "sparse_s.cold", "hot_entry_share",
                "fe_hot_roofline", "fe_cold_roofline"}
+# PR 37's set-up wall: every cell reports them, and a cell's counts below
+# are of the metrics before them
+SETUP_WALL = {f"setup_wall_s.{p}" for p in (
+    "staging", "program_load", "compile_wait", "stage_wait", "sweeps",
+    "other")} | {"program_load_wall_s"}
 NEW_METRICS = {"update_s.per-advertiser", "re_iters.per-advertiser",
                "lane_util.per-advertiser", "pad_share.per-advertiser",
                "ls_evals.per-advertiser", "owlqn_s.orthant",
@@ -167,7 +172,7 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     cell = run.load_cell(CELL)
     assert {m["name"] for m in cell["end_to_end"]} == {"sweep_s", "setup_s"}
     mine = {m["name"] for m in cell["per_layer"]}
-    assert mine == OLD_READERS | NEW_METRICS
+    assert mine == OLD_READERS | NEW_METRICS | SETUP_WALL
     for m in cell["per_layer"]:
         assert callable(run.layer_reader(m["name"])), m["name"]
         # later cells append their names after this one's
@@ -178,7 +183,8 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     # the cells that were there report what they reported: 27 metrics each
     for old in ("ml20m-logistic.steady", "criteo-1m-logistic.steady"):
         theirs = {m["name"] for m in run.load_cell(old)["per_layer"]}
-        assert len(theirs) == 27 and not theirs & NEW_METRICS
+        assert SETUP_WALL <= theirs and len(theirs - SETUP_WALL) == 27
+        assert not theirs & NEW_METRICS
     conf = cell["configuration"]
     assert (conf["num_features"], conf["nonzeros_per_row"],
             conf["entity"]["count"], conf["entity"]["features"]) == (

@@ -39,6 +39,9 @@ def clean_registry():
     reg.reset()
     yield reg
     reg.reset()
+    # Two tests below turn the metrics registry on: leave none installed
+    # for the next file on this worker (test_quant.py read its counts)
+    obs.disable()
     # Streamed kernel caches key on the resolved fused state; drop them
     # so a flag flipped in one test never leaks a closure into the next.
     ss._VG_KERNELS.clear()

@@ -243,8 +243,11 @@ def test_int8_transfer_bytes_tagged_and_quartered():
     w = jnp.zeros((b.num_features,), jnp.float32)
     vg8 = ss.make_value_and_gradient(losses.LOGISTIC, built["int8"])
     float(vg8(w)[0])  # warm-up: compile + first pass, before metrics
-    _, m = obs.enable(trace=False)
-    try:
+    # A registry of this test's own: ``obs.enable`` hands back whatever
+    # registry an earlier test on the same worker left installed, counts
+    # and all (test_kernels.py's int8 streams did, until PR 37)
+    m = obs.MetricsRegistry()
+    with obs.activated(metrics_obj=m):
         float(vg8(w)[0])
         parsed = obs.parse_prometheus_text(m.render_text())
         key = 'photon_transfer_bytes_total{dtype="int8",kind="stream"}'
@@ -255,8 +258,6 @@ def test_int8_transfer_bytes_tagged_and_quartered():
         assert obs.metric_value(
             parsed, "photon_compile_cache_misses_total",
             default=0.0) == 0
-    finally:
-        obs.disable()
 
 
 # --------------------------------------------------------- chunk store
