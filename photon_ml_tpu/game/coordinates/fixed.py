@@ -102,17 +102,18 @@ class FixedEffectCoordinate:
             self.config, variance_computation=VarianceComputationType.NONE)
         loss, mesh, norm = self.loss, self.mesh, self.norm
         ii = self.intercept_index
+        oracle = dist_problem.takes_line_oracle(cfg)
 
         def solve(batch: LabeledBatch, w0: Array):
             coef, res = dist_problem.run(
                 loss, batch, mesh, cfg, initial=Coefficients(w0), norm=norm,
                 intercept_index=ii, already_sharded=True)
-            # Histories and the evaluation count ride along for the run
-            # ledger's post-fit spill (tiny (max_it+1,) vectors and one
-            # integer; they stay on device — and cost nothing — unless a
-            # ledger is active).
+            # Histories, the evaluation count and, under the oracle, the
+            # trials ride along for the run ledger's post-fit spill (tiny
+            # (max_it+1,) vectors and two integers; they stay on device —
+            # and cost nothing — unless a ledger is active).
             return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations)
+                    res.evaluations, res.trials if oracle else None)
 
         @scoped("fe.fit")
         def fit(staged: LabeledBatch, offsets: Array, w0: Array):
@@ -184,23 +185,26 @@ class FixedEffectCoordinate:
             # gather happens on device.
             idx, mult = draw_down_sample(self, rate)
             with obs.annotated("fe.fit", cat="train"):
-                w_t, vals, gns, evals = self._fit_sampled(
+                w_t, *spill = self._fit_sampled(
                     self._staged, jnp.asarray(idx), jnp.asarray(mult),
                     offsets, w0)
         else:
             with obs.annotated("fe.fit", cat="train"):
-                w_t, vals, gns, evals = self._fit(self._staged, offsets, w0)
+                w_t, *spill = self._fit(self._staged, offsets, w0)
         led = obs.ledger()
         if led is not None:
             # Post-fit spill of the compiled optimizer's NaN-padded
             # histories — the run ledger's view of a solve that lives
             # inside one XLA program (one host read, once per update).
-            # The update's evaluation count rides on the last row.
-            vals, gns, evals = jax.device_get((vals, gns, evals))
+            # The update's evaluation count rides on the last row (under
+            # the oracle: pairs of passes over X, one an iteration and the
+            # first), and beside it, where the oracle ran, its trials.
+            vals, gns, evals, trials = jax.device_get(spill)
             spill_history(
                 led, vals, gns,
                 opt=self.config.optimizer.optimizer_type.value.lower(),
-                evaluations=int(evals))
+                evaluations=int(evals),
+                trials=None if trials is None else int(trials))
         raw = Coefficients(self.norm.model_to_original_space(w_t))
         return FixedEffectModel(shard_id=self.shard_id, coefficients=raw)
 

@@ -32,13 +32,14 @@ from photon_ml_tpu.game.models import (RandomEffectModel,
                                        sort_subspace_rows)
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops.losses import PointwiseLoss
-from photon_ml_tpu.optim import OptimizerType, optimize
+from photon_ml_tpu.optim import optimize
 from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          compute_variances, make_line_oracle,
                                          make_objective,
-                                         resolve_optimizer_config)
+                                         resolve_optimizer_config,
+                                         takes_line_oracle)
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, data_sharded
 
 Array = jax.Array
@@ -940,11 +941,8 @@ class RandomEffectCoordinate:
     @property
     def _line_oracle(self) -> bool:
         """Whether the lane solves' line searches go through a
-        ``LineOracle``: plain L-BFGS does; OWL-QN's trial points leave the
-        line (an L1 table) and TRON has no line search."""
-        return (self.config.regularization.l1_weight() == 0.0
-                and OptimizerType(self.config.optimizer.optimizer_type)
-                == OptimizerType.LBFGS)
+        ``LineOracle`` (``optim/problem.takes_line_oracle``)."""
+        return takes_line_oracle(self.config)
 
     def _variance_one(self, X, y, w, o, w_opt, norm=None,
                       intercept_index=_UNSET):

@@ -475,15 +475,16 @@ def fabric_totals() -> dict:
 def spill_history(led: "RunLedger", values, grad_norms,
                   opt: str = "compiled",
                   evaluations: Optional[int] = None,
-                  counts: Optional[dict] = None) -> int:
+                  counts: Optional[dict] = None,
+                  trials: Optional[int] = None) -> int:
     """Spill a compiled optimizer's NaN-padded value/grad-norm histories
     as post-fit ``opt_iter`` rows (``clock: "post_fit"`` — row ``t`` is
     the spill time, so wall resolution is the coordinate update). The
     solve's ``evaluations`` (objective evaluations, line-search trials
-    included), when given, ride on the last row; ``counts`` (name → one
-    whole number an iteration, as long as ``values``: OWL-QN's ``trials``,
-    ``nnz``, ``crossings``) on every row. Returns the number of rows
-    written."""
+    included) and ``trials`` (all its line searches' trials), when given,
+    ride on the last row; ``counts`` (name → one whole number an
+    iteration, as long as ``values``: OWL-QN's ``trials``, ``nnz``,
+    ``crossings``) on every row. Returns the number of rows written."""
     rows = []
     for i, (v, g) in enumerate(zip(values, grad_norms)):
         v, g = float(v), float(g)
@@ -494,6 +495,8 @@ def spill_history(led: "RunLedger", values, grad_norms,
                      **{k: int(c[i]) for k, c in (counts or {}).items()}})
     if rows and evaluations is not None:
         rows[-1]["evaluations"] = int(evaluations)
+    if rows and trials is not None:
+        rows[-1]["trials"] = int(trials)
     for row in rows:
         led.record("opt_iter", opt=opt, clock="post_fit", **row)
     return len(rows)

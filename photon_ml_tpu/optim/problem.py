@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +111,7 @@ def make_line_oracle(
     reg: RegularizationContext,
     intercept_index: Optional[int],
     dim: int,
+    total: Callable[[Array], Array] = lambda x: x,
 ) -> LineOracle:
     """``make_objective``'s smooth objective, Σ wᵢ·l(zᵢ, yᵢ) + ½·λ‖w∘mask‖²
     with z = X'·w + offset, taken apart for L-BFGS's line search
@@ -131,7 +132,13 @@ def make_line_oracle(
     it, as they do where every trial is an evaluation. The stopping rule
     compares consecutive values at float32's last place, where a value
     recomputed another way (the L2 term from the coefficients) sits a
-    unit apart from its trial's."""
+    unit apart from its trial's.
+
+    ``total`` sums over every row of the objective what ``batch``'s rows
+    give (the value, the gradient, the slope): nothing to do where
+    ``batch`` holds them all, a ``psum`` where it is one shard of them
+    (``parallel/objective.make_line_oracle``). The L2 terms are added
+    after it, once, on coefficients every shard holds whole."""
     mask = jnp.asarray(intercept_mask(dim, intercept_index))
     l2 = reg.l2_weight()
     live = batch.weights > 0.0
@@ -140,13 +147,13 @@ def make_line_oracle(
         """(f, the rows' weighted dl/dz) at margins z of a point whose
         masked squared norm is ww."""
         l, dl = loss.loss_and_dz(z, batch.labels)
-        f = jnp.sum(agg._masked(batch.weights, l), axis=-1)
+        f = total(jnp.sum(agg._masked(batch.weights, l), axis=-1))
         return (f + 0.5 * l2 * ww if l2 else f,
                 agg._masked(batch.weights, dl))
 
     def gradient(r, w):
-        g = norm.pullback_gradient(agg._tmatvec(batch.features, r),
-                                   jnp.sum(r, axis=-1))
+        g = total(norm.pullback_gradient(agg._tmatvec(batch.features, r),
+                                         jnp.sum(r, axis=-1)))
         return g + l2 * (w * mask) if l2 else g
 
     def masked_dot(a, b):
@@ -179,7 +186,7 @@ def make_line_oracle(
     def trial(ray, alpha):
         _, u, _, _, quad = ray
         f, r = at(*step(ray, alpha))
-        slope = jnp.sum(r * u, axis=-1)
+        slope = total(jnp.sum(r * u, axis=-1))
         if l2:
             _, wd, dd = quad
             slope = slope + l2 * (wd + alpha * dd)
@@ -193,6 +200,16 @@ def make_line_oracle(
         return f, gradient(r, w + alpha * d), carry
 
     return LineOracle(start, along, trial, accept)
+
+
+def takes_line_oracle(config: GLMOptimizationConfiguration) -> bool:
+    """Whether an L-BFGS solve of ``config`` goes through a ``LineOracle``:
+    plain L-BFGS does; OWL-QN's trial points leave the line (an L1 weight)
+    and TRON has no line search. What a coordinate can see of its solve,
+    and all it decides by."""
+    return (config.regularization.l1_weight() == 0.0
+            and OptimizerType(config.optimizer.optimizer_type)
+            == OptimizerType.LBFGS)
 
 
 def run(

@@ -19,6 +19,7 @@ is asserted in tests, mirroring the reference's
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -31,6 +32,8 @@ from photon_ml_tpu.data.batch import LabeledBatch
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops import aggregators as agg
 from photon_ml_tpu.ops.losses import PointwiseLoss
+from photon_ml_tpu.optim import LineOracle, RegularizationContext
+from photon_ml_tpu.optim import problem as local_problem
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, shard_map
 
 Array = jax.Array
@@ -113,3 +116,52 @@ def make_hessian_matrix(
         return lax.psum(agg.hessian_matrix(loss, w, b, norm), DATA_AXIS)
 
     return lambda w: _hm(w, batch)
+
+
+def make_line_oracle(
+    loss: PointwiseLoss,
+    mesh: Mesh,
+    batch: LabeledBatch,
+    norm: NormalizationContext,
+    reg: RegularizationContext,
+    intercept_index: Optional[int],
+    dim: int,
+) -> LineOracle:
+    """``with_l2(make_value_and_gradient(...))`` taken apart for L-BFGS's
+    line search: ``optim/problem.make_line_oracle``'s four functions, each
+    run on every shard of the batch with its row sums ``psum``-reduced
+    over ``data`` and the L2 terms added once after them. Coefficients
+    come in and values go out replicated; the margins (the carry) and the
+    direction's margins X'·d (in the ray) stay on the shard of the rows
+    they belong to, ``P(data)`` as the batch. A trial is handed the rows'
+    labels and weights and no feature, so the search's loop reads none:
+    an iteration is one pair of passes over X whatever its trials."""
+    rows = P(DATA_AXIS)
+    l2 = P() if reg.l2_weight() else None  # the L2 term's scalars
+    carry = (rows, l2)  # margins, ‖w∘mask‖²
+    ray = (rows, rows, P(), P(), None if l2 is None else (l2,) * 3)
+    full = _batch_specs(batch)
+    labels_weights = dataclasses.replace(batch, features=None, offsets=None)
+
+    def shard(b):
+        return local_problem.make_line_oracle(
+            loss, b, norm, reg, intercept_index, dim,
+            total=lambda x: lax.psum(x, DATA_AXIS))
+
+    def over(fn, in_specs, out_specs):
+        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
+
+    start = over(lambda w, b: shard(b).start(w),
+                 (P(), full), (P(), P(), carry))
+    along = over(lambda c, w, d, b: shard(b).along(c, w, d),
+                 (carry, P(), P(), full), ray)
+    trial = over(lambda r, alpha, b: shard(b).trial(r, alpha),
+                 (ray, P(), _batch_specs(labels_weights)), (P(), P()))
+    accept = over(lambda r, alpha, b: shard(b).accept(r, alpha),
+                  (ray, P(), full), (P(), P(), carry))
+    return LineOracle(
+        lambda w: start(w, batch),
+        lambda c, w, d: along(c, w, d, batch),
+        lambda r, alpha: trial(r, alpha, labels_weights),
+        lambda r, alpha: accept(r, alpha, batch))

@@ -27,6 +27,7 @@ from photon_ml_tpu.optim.common import scoped
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          VarianceComputationType,
                                          resolve_optimizer_config,
+                                         takes_line_oracle,
                                          variances_from_diagonal,
                                          variances_from_matrix)
 from photon_ml_tpu.optim.regularization import intercept_mask
@@ -46,7 +47,12 @@ def run(
     intercept_index: Optional[int] = None,
     already_sharded: bool = False,
 ) -> tuple[Coefficients, OptResult]:
-    """Fit one GLM over the mesh (DistributedOptimizationProblem.run)."""
+    """Fit one GLM over the mesh (DistributedOptimizationProblem.run).
+
+    Plain L-BFGS takes the objective apart as a ``LineOracle``
+    (``objective.make_line_oracle``): its line search then reads rows, and
+    an iteration is one pair of passes over X whatever its trials. OWL-QN
+    and TRON evaluate the objective as before."""
     if not already_sharded:
         batch = shard_batch(batch, mesh)
     dim = batch.dim
@@ -62,9 +68,14 @@ def run(
     l1w = l1_weights_vector(l1, dim, intercept_index) if l1 > 0.0 else None
     opt_cfg = resolve_optimizer_config(config.optimizer, l1w is not None)
 
+    line = None
+    if takes_line_oracle(config):
+        line = dobj.make_line_oracle(loss, mesh, batch, norm, reg,
+                                     intercept_index, dim)
+
     w0 = initial.means if initial is not None else jnp.zeros(
         (dim,), batch.features.dtype)
-    result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w)
+    result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w, line=line)
 
     variances = None
     kind = VarianceComputationType(config.variance_computation)

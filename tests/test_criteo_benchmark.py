@@ -277,7 +277,7 @@ def test_the_dense_rehearsal_reads_what_it_read(run, capsys):
                            "rehearsal.expected.json")) as f:
         want = json.load(f)
     with open(os.path.join(REPO, "tests", "data",
-                           "ml20m.rehearsal.pr36.json")) as f:
+                           "ml20m.rehearsal.pr38.json")) as f:
         recorded = json.load(f)["compared"]
     assert run.main(want["argv"]) == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
