@@ -108,12 +108,14 @@ class FixedEffectCoordinate:
             coef, res = dist_problem.run(
                 loss, batch, mesh, cfg, initial=Coefficients(w0), norm=norm,
                 intercept_index=ii, already_sharded=True)
-            # Histories, the evaluation count and, under the oracle, the
-            # trials ride along for the run ledger's post-fit spill (tiny
-            # (max_it+1,) vectors and two integers; they stay on device —
-            # and cost nothing — unless a ledger is active).
+            # Histories, the evaluation count, under the oracle the trials
+            # and under TRON each iteration's Hessian-vector products ride
+            # along for the run ledger's post-fit spill (tiny (max_it+1,)
+            # vectors and two integers; they stay on device — and cost
+            # nothing — unless a ledger is active).
             return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations, res.trials if oracle else None)
+                    res.evaluations, res.trials if oracle else None,
+                    res.hvp_history)
 
         @scoped("fe.fit")
         def fit(staged: LabeledBatch, offsets: Array, w0: Array):
@@ -198,12 +200,14 @@ class FixedEffectCoordinate:
             # inside one XLA program (one host read, once per update).
             # The update's evaluation count rides on the last row (under
             # the oracle: pairs of passes over X, one an iteration and the
-            # first), and beside it, where the oracle ran, its trials.
-            vals, gns, evals, trials = jax.device_get(spill)
+            # first), and beside it, where the oracle ran, its trials;
+            # under TRON every row carries its iteration's ``hvps``.
+            vals, gns, evals, trials, hvps = jax.device_get(spill)
             spill_history(
                 led, vals, gns,
                 opt=self.config.optimizer.optimizer_type.value.lower(),
                 evaluations=int(evals),
+                counts=None if hvps is None else {"hvps": hvps},
                 trials=None if trials is None else int(trials))
         raw = Coefficients(self.norm.model_to_original_space(w_t))
         return FixedEffectModel(shard_id=self.shard_id, coefficients=raw)

@@ -61,19 +61,32 @@ _LANE_CHUNK = stg.LANE_CHUNK
 # What a fit wave counts inside its program, reduced over live lanes: the
 # solver fields of its ``re_fit_wave`` ledger row (docs/OBSERVABILITY.md).
 _WAVE_STATS = ("iters_sum", "iters_max", "evals_sum", "lanes_at_cap",
-               "trials_sum")
+               "trials_sum", "hvp_sum", "hvp_wave")
 
 
-def _wave_stats(rows, iterations, evaluations, trials, max_iterations: int):
-    """(5,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
+def _wave_stats(rows, iterations, evaluations, trials, hvp_history,
+                max_iterations: int):
+    """(7,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
     entity (``rows >= 0``; padding lanes solve a benign problem of their
-    own). Stays on the device until the update's ledger drain."""
+    own). ``hvp_history`` is TRON's (lanes, iterations + 1) CG steps, None
+    for a solver without them: ``hvp_sum`` the live lanes' own products,
+    ``hvp_wave`` those the wave computed, every lane stepping each CG loop
+    until its slowest lane stops. Stays on the device until the update's
+    ledger drain."""
     live = rows >= 0
     its = jnp.where(live, iterations, 0)
-    return jnp.stack([its.sum(), its.max(),
-                      jnp.where(live, evaluations, 0).sum(),
-                      (its >= max_iterations).sum(),
-                      jnp.where(live, trials, 0).sum()]).astype(jnp.int32)
+    if hvp_history is None:
+        hvps = jnp.zeros((2,), jnp.int32)
+    else:
+        hvps = jnp.stack([
+            jnp.where(live, hvp_history.sum(axis=-1), 0).sum(),
+            rows.shape[0] * hvp_history.max(axis=0).sum()])
+    return jnp.concatenate([
+        jnp.stack([its.sum(), its.max(),
+                   jnp.where(live, evaluations, 0).sum(),
+                   (its >= max_iterations).sum(),
+                   jnp.where(live, trials, 0).sum()]),
+        hvps]).astype(jnp.int32)
 
 
 def _wave_rows(pending):
@@ -910,7 +923,8 @@ class RandomEffectCoordinate:
     def _solve_one(self, X, y, w, o, w0, norm=None, intercept_index=_UNSET):
         """One entity's GLM solve in transformed space (vmapped per bucket):
         the fitted row, and the iterations, objective evaluations and
-        line-search trials the solver took for it.
+        line-search trials the solver took for it, and under TRON the CG
+        steps of each iteration (None under the others).
 
         The projected path passes a per-entity NormalizationContext and the
         projected intercept slot; the unprojected path uses the coordinate's
@@ -936,7 +950,7 @@ class RandomEffectCoordinate:
         trials = result.trials  # TRON has no line search
         return (result.w, result.iterations, result.evaluations,
                 jnp.zeros_like(result.iterations) if trials is None
-                else trials)
+                else trials, result.hvp_history)  # TRON's alone
 
     @property
     def _line_oracle(self) -> bool:

@@ -46,7 +46,9 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
   (``cap``, ``lanes``, ``rows_useful``, ``rows_padded``), and the
   solver's own counts reduced on the device over live lanes
   (``iters_sum``, ``iters_max``, ``evals_sum``, ``lanes_at_cap``,
-  ``trials_sum``), and ``line``: whether a line-search trial read the
+  ``trials_sum``; under TRON ``hvp_sum``, the lanes' Hessian-vector
+  products, and ``hvp_wave``, those the wave computed: 0 under the other
+  solvers), and ``line``: whether a line-search trial read the
   lane's rows alone (``oracle``) or evaluated the objective over its
   block (``evaluation``).
   Written through :meth:`RunLedger.defer`: the counts are read once per
@@ -484,7 +486,7 @@ def spill_history(led: "RunLedger", values, grad_norms,
     included) and ``trials`` (all its line searches' trials), when given,
     ride on the last row; ``counts`` (name → one whole number an
     iteration, as long as ``values``: OWL-QN's ``trials``, ``nnz``,
-    ``crossings``) on every row. Returns the number of rows written."""
+    ``crossings``; TRON's ``hvps``) on every row. Returns the number of rows written."""
     rows = []
     for i, (v, g) in enumerate(zip(values, grad_norms)):
         v, g = float(v), float(g)

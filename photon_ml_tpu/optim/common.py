@@ -95,6 +95,11 @@ class OptResult:
     # whatever a trial cost (under a LineOracle no pass over the data, so
     # ``evaluations`` no longer holds them)
     trials: Optional[Array] = None
+    # TRON only: int32, the Hessian-vector products of its CG solves (one a
+    # CG step), and (max_iterations + 1,) int32, those of each iteration
+    # (0 at the start and past the end)
+    hvps: Optional[Array] = None
+    hvp_history: Optional[Array] = None
 
 
 def scoped(name: str, fn: Optional[Callable] = None) -> Callable:

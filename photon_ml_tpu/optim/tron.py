@@ -12,6 +12,14 @@ fused matmul pair (+ one psum when distributed), the analogue of the
 reference's one ``treeAggregate(HessianVectorAggregator)`` per CG step.
 Masked updates make the machine vmappable for per-entity solves, like
 photon_ml_tpu/optim/lbfgs.py.
+
+The products are most of a solve, so the result counts them: ``hvps``, and
+``hvp_history[k]`` the CG steps of outer iteration k (0 at the start and
+past the end). Under ``vmap`` the CG loop runs until its slowest lane's
+residual is small, so a wave computes Σₖ maxₗ ``hvp_history[l, k]``
+products in every lane; a lane that has converged runs no CG step of its
+own and so does not lengthen the loop. The Steihaug loop runs under the
+scope ``tron.cg``.
 """
 
 from __future__ import annotations
@@ -24,7 +32,7 @@ from jax import lax
 
 from photon_ml_tpu.optim.common import (Hvp, OptResult, OptimizerConfig,
                                         ValueAndGrad, check_convergence,
-                                        masked_update)
+                                        masked_update, scoped)
 
 Array = jax.Array
 
@@ -46,13 +54,18 @@ class _TronState:
     g0_norm: Array
     value_history: Array
     grad_norm_history: Array
+    hvps: Array  # Hessian-vector products taken, all iterations
+    hvp_history: Array  # (max_iter + 1,) int32: the CG steps of each
 
 
-def _cg_steihaug(hvp, w, g, delta, max_cg, tol_cg):
+@scoped("tron.cg")
+def _cg_steihaug(hvp, w, g, delta, max_cg, tol_cg, idle):
     """Truncated CG: approximately solve H s = −g within ‖s‖ ≤ delta.
 
-    Returns (s, sHs, gs) where sHs = sᵀHs and gs = gᵀs, the pieces needed
-    for the model-decrease computation.
+    Returns (s, sHs, gs, steps) where sHs = sᵀHs and gs = gᵀs, the pieces
+    needed for the model-decrease computation, and steps the CG iterations
+    taken, one Hessian-vector product each. ``idle`` (a converged lane of a
+    vmapped solve, whose result is discarded) takes none.
     """
     d = g.shape[-1]
     s0 = jnp.zeros_like(g)
@@ -87,11 +100,11 @@ def _cg_steihaug(hvp, w, g, delta, max_cg, tol_cg):
         p_new = r_new + beta * p
         return (s_new, r_new, p_new, rr_new, i + 1, done | over)
 
-    st = (s0, r0, p0, rr0, jnp.asarray(0, jnp.int32), jnp.asarray(False))
+    st = (s0, r0, p0, rr0, jnp.asarray(0, jnp.int32), jnp.asarray(idle))
     s, r, p, rr, i, done = lax.while_loop(cond, body, st)
     sHs = jnp.dot(s, -g - r)  # H s = -g - r by the residual invariant
     gs = jnp.dot(g, s)
-    return s, sHs, gs
+    return s, sHs, gs, i
 
 
 def minimize(
@@ -118,11 +131,14 @@ def minimize(
         failed=jnp.asarray(False),
         g0_norm=g0_norm,
         value_history=vh, grad_norm_history=gh,
+        hvps=jnp.asarray(0, jnp.int32),
+        hvp_history=jnp.zeros((max_iter + 1,), jnp.int32),
     )
 
     def body(state: _TronState) -> _TronState:
-        s, sHs, gs = _cg_steihaug(hvp, state.w, state.g, state.delta,
-                                  config.max_cg_iterations, 0.1)
+        s, sHs, gs, steps = _cg_steihaug(
+            hvp, state.w, state.g, state.delta, config.max_cg_iterations, 0.1,
+            state.converged)
         prered = -(gs + 0.5 * sHs)  # predicted decrease of the quadratic model
         w_new = state.w + s
         f_new, g_new = value_and_grad(w_new)
@@ -165,6 +181,8 @@ def minimize(
             failed=state.failed | (stalled & ~conv),
             g0_norm=state.g0_norm,
             value_history=vh, grad_norm_history=gh,
+            hvps=state.hvps + steps,
+            hvp_history=state.hvp_history.at[it].set(steps),
         )
         return masked_update(state.converged, new_state, state)
 
@@ -181,4 +199,6 @@ def minimize(
         converged=final.converged & ~final.failed,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
+        hvps=final.hvps,
+        hvp_history=final.hvp_history,
     )

@@ -47,26 +47,57 @@ def _batch_specs(batch: LabeledBatch) -> LabeledBatch:
         lambda leaf: P(DATA_AXIS, *(None,) * (jnp.ndim(leaf) - 1)), batch)
 
 
+def _feature_major(batch: LabeledBatch):
+    """(Xᵀ, the batch without its features, their specs). Taken where the
+    objective is made, outside the solver's loops: the staged ``(n, d)``
+    X is laid out feature-major on the TPU, so Xᵀ is the same bytes, and
+    the loops carry an array whose natural layout they are. Carried as
+    ``(n, d)`` into a nested loop (TRON's CG inside its outer iteration),
+    X is copied once into row-major ``(8, 128)`` tiles, its d = 32
+    columns padded to 128 lanes: 8.2 GB of scratch at 16M rows, where
+    the feature-major program holds 65 MB (PERF.md section 6)."""
+    rest = dataclasses.replace(batch, features=None)
+    return (batch.features.T, rest,
+            (P(None, DATA_AXIS), _batch_specs(rest)))
+
+
+def _margins_t(xt: Array, b: LabeledBatch, w: Array,
+               norm: NormalizationContext) -> Array:
+    """``agg.margins`` over Xᵀ: ``_tmatvec`` of Xᵀ is X·w."""
+    w_eff, shift = norm.effective_coefficients(w)
+    z = agg._tmatvec(xt, w_eff) + jnp.expand_dims(shift, -1) + b.offsets
+    return jnp.where(b.weights > 0.0, z, 0.0)
+
+
+def _pullback_t(xt: Array, r: Array, norm: NormalizationContext) -> Array:
+    """``norm.pullback_gradient(Xᵀ·r, Σ r)``: ``_matvec`` of Xᵀ is Xᵀ·r."""
+    return norm.pullback_gradient(agg._matvec(xt, r), jnp.sum(r, axis=-1))
+
+
 def make_value_and_gradient(
     loss: PointwiseLoss,
     mesh: Mesh,
     batch: LabeledBatch,
     norm: NormalizationContext = _IDENTITY,
 ):
-    """(w) → (Σ value, Σ grad) over the full sharded batch.
+    """(w) → (Σ value, Σ grad) over the full sharded batch:
+    ``agg.value_and_gradient`` over the feature-major view of X
+    (``_feature_major``).
 
     The returned callable closes over the sharded batch; coefficients are
     replicated in, results are replicated out.
     """
-    specs = _batch_specs(batch)
+    xt, rest, (xt_spec, specs) = _feature_major(batch)
 
     @functools.partial(shard_map, mesh=mesh,
-                       in_specs=(P(), specs), out_specs=(P(), P()))
-    def _vg(w, b):
-        v, g = agg.value_and_gradient(loss, w, b, norm)
+                       in_specs=(P(), xt_spec, specs), out_specs=(P(), P()))
+    def _vg(w, xt, b):
+        l, dl = loss.loss_and_dz(_margins_t(xt, b, w, norm), b.labels)
+        v = jnp.sum(agg._masked(b.weights, l), axis=-1)
+        g = _pullback_t(xt, agg._masked(b.weights, dl), norm)
         return lax.psum(v, DATA_AXIS), lax.psum(g, DATA_AXIS)
 
-    return lambda w: _vg(w, batch)
+    return lambda w: _vg(w, xt, rest)
 
 
 def make_hvp(
@@ -75,15 +106,21 @@ def make_hvp(
     batch: LabeledBatch,
     norm: NormalizationContext = _IDENTITY,
 ):
-    """(w, v) → Σ H·v over the full sharded batch (TRON's inner product)."""
-    specs = _batch_specs(batch)
+    """(w, v) → Σ H·v over the full sharded batch (TRON's inner product):
+    ``agg.hessian_vector`` over the feature-major view of X
+    (``_feature_major``)."""
+    xt, rest, (xt_spec, specs) = _feature_major(batch)
 
     @functools.partial(shard_map, mesh=mesh,
-                       in_specs=(P(), P(), specs), out_specs=P())
-    def _hvp(w, v, b):
-        return lax.psum(agg.hessian_vector(loss, w, v, b, norm), DATA_AXIS)
+                       in_specs=(P(), P(), xt_spec, specs), out_specs=P())
+    def _hvp(w, v, xt, b):
+        d2 = loss.d2z(_margins_t(xt, b, w, norm), b.labels)
+        v_eff, v_shift = norm.effective_coefficients(v)
+        u = agg._tmatvec(xt, v_eff) + jnp.expand_dims(v_shift, -1)
+        hv = _pullback_t(xt, agg._masked(b.weights, d2 * u), norm)
+        return lax.psum(hv, DATA_AXIS)
 
-    return lambda w, v: _hvp(w, v, batch)
+    return lambda w, v: _hvp(w, v, xt, rest)
 
 
 def make_hessian_diagonal(

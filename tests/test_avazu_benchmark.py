@@ -186,24 +186,25 @@ def test_the_selfcheck_holds_the_new_schema_to_the_contract(run, capsys):
                  "glmix-criteo-1m-logistic: game_criteo ok",
                  "glmix-ml20m-logistic: game_dense ok"):
         assert line in err, line
-    assert err.count("selfcheck check_generator: ok") == 3
-    assert err.count("selfcheck check_work: ok") == 4
+    assert err.count("selfcheck check_generator: ok") >= 3
+    assert err.count("selfcheck check_work: ok") >= 4
 
 
 def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
-    """One configuration and one cell at the end of their lists, the cell's
-    name appended to the lists of the 22 readers it shares, nine new metrics
-    of its own; every reader is found by name."""
+    """One configuration and one cell after the three before it (later
+    cells after it), the cell's name appended to the lists of the 22
+    readers it shares, nine new metrics of its own; every reader is found
+    by name."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [c["name"] for c in bench["configs"]] == [
+    assert [c["name"] for c in bench["configs"]][:4] == [
         "glmix-ml20m-logistic", "glmix-criteo-1m-logistic",
         "glmix-kdd12-poisson-l1", "glmix-avazu-logistic-sparse-re"]
-    assert [w["name"] for w in bench["workloads"]] == [
+    assert [w["name"] for w in bench["workloads"]][:4] == [
         "ml20m-logistic.steady", "criteo-1m-logistic.steady",
         "kdd12-poisson-l1.steady", CELL]
     assert all(w["chips"] == 1 for w in bench["workloads"])
-    assert bench["configs"][-1]["reduced"] == ["num_rows",
+    assert bench["configs"][3]["reduced"] == ["num_rows",
                                                "lbfgs_max_iterations"]
     assert all(1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
                for c in bench["configs"])
@@ -217,7 +218,8 @@ def test_the_entries_are_added_and_nothing_that_was_there_is_changed(run):
     assert mine == OLD_READERS | NEW_METRICS | SETUP_WALL
     for m in cell["per_layer"]:
         assert callable(run.layer_reader(m["name"])), m["name"]
-        assert m["workloads"][-1] == CELL
+        # where it was appended: a later cell's name comes after it
+        assert m["workloads"][:4][-1] == CELL
         if m["name"] in NEW_METRICS:
             assert m["workloads"] == [CELL]
             assert m["moves"] == ("setup_s" if m["name"] == "phase_s.project"

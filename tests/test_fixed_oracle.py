@@ -171,12 +171,14 @@ def _fit_jaxpr(coord):
 
 def _search_reads(coord):
     """For each line search of the fit program (a ``while`` inside the
-    solve's ``while``): whether any operand has the feature block's shape."""
+    solve's ``while``): whether any operand has the feature block's shape,
+    or its feature-major view's (``parallel/objective._feature_major``)."""
     block = coord._staged.features.shape
     searches = [e for depth, e in _loops(_fit_jaxpr(coord).jaxpr)
                 if depth == 1]
     assert searches
-    return [any(getattr(v.aval, "shape", None) == block for v in e.invars)
+    return [any(getattr(v.aval, "shape", None) in (block, block[::-1])
+                for v in e.invars)
             for e in searches]
 
 
@@ -220,8 +222,12 @@ def test_coordinate_rows_count_passes_and_trials(mesh, monkeypatch, tmp_path):
     assert rows[-1]["trials"] >= its
     assert all("trials" not in r for r in rows[:-1])
     monkeypatch.setattr(dist_problem, "takes_line_oracle", lambda c: False)
+    # run to its cap, so that its searches meet float32's floor and try
+    # more than once whatever the order of the passes' sums
     w_eval, rows_eval = _fit_with_rows(
-        FixedEffectCoordinate(ds, "global", losses.LOGISTIC, cfg, mesh),
+        FixedEffectCoordinate(ds, "global", losses.LOGISTIC,
+                              _config(max_iterations=40, tolerance=0.0),
+                              mesh),
         tmp_path, "eval")
     np.testing.assert_allclose(w, w_eval, rtol=1e-2, atol=1e-3)
     assert all("trials" not in r for r in rows_eval)

@@ -629,19 +629,26 @@ def test_benchmark_lists_the_new_metrics_additively():
     new = _new_metrics()
     first, second = "ml20m-logistic.steady", "criteo-1m-logistic.steady"
     third, fourth = "kdd12-poisson-l1.steady", "avazu-sparse-re.steady"
+    fifth = "yahoo-music-tron.steady"
     # PR 26's nineteen read the first cell alone; PR 29 appended its cell to
     # the eleven of them a cell with a sparse fixed effect and one table can
     # report, and added four of these stems for that table; PR 33 appended
     # its cell to the same eleven and added the four for its own table, as
     # PR 35 did, with ``phase_s.project`` for its projection pass
-    both = {"ls_evals.fixed"} | {"phase_s." + p for p in (
+    # The TRON cell appended itself to those of the eleven a solver without
+    # a line search reads and to the first cell's tables' three that are not
+    # ``ls_evals``, and added those three for its third table.
+    all_four = {"ls_evals.fixed", "scope_s.line_search", "scope_s.direction"}
+    all_five = {"phase_s." + p for p in (
         "digest", "bucketing", "host_stage", "transfer", "program_load")} | {
-        "scope_s." + p for p in ("line_search", "value_grad", "direction",
-                                 "gather_scatter", "score")}
+        "scope_s." + p for p in ("value_grad", "gather_scatter", "score")}
     stems = ("re_iters", "lane_util", "pad_share", "ls_evals")
-    assert len(new) == 32
+    assert len(new) == 35
     assert {m["name"] for m in new
-            if m["workloads"] == [first, second, third, fourth]} == both
+            if m["workloads"] == [first, second, third, fourth]} == all_four
+    assert {m["name"] for m in new
+            if m["workloads"] == [first, second, third, fourth, fifth]
+            } == all_five
     assert {m["name"] for m in new if m["workloads"] == [second]} == {
         stem + ".per-c10" for stem in stems}
     assert {m["name"] for m in new if m["workloads"] == [third]} == {
@@ -649,8 +656,13 @@ def test_benchmark_lists_the_new_metrics_additively():
     assert {m["name"] for m in new if m["workloads"] == [fourth]} == {
         stem + ".per-publisher" for stem in stems} | {"phase_s.project"}
     assert {m["name"] for m in new if m["workloads"] == [first]} == {
-        stem + c for stem in ("re_iters", "lane_util", "pad_share",
-                              "ls_evals") for c in (".per-user", ".per-item")}
+        "ls_evals.per-user", "ls_evals.per-item"}
+    assert {m["name"] for m in new if m["workloads"] == [first, fifth]} == {
+        stem + c for stem in ("re_iters", "lane_util", "pad_share")
+        for c in (".per-user", ".per-item")}
+    assert {m["name"] for m in new if m["workloads"] == [fifth]} == {
+        stem + ".per-artist" for stem in ("re_iters", "lane_util",
+                                          "pad_share")}
     for m in new:
         assert os.path.exists(os.path.join(
             BENCH, "layer_metrics", m["name"].split(".", 1)[0] + ".py"))

@@ -183,7 +183,9 @@ def test_benchmark_lists_the_seven_additively():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
-    mine = bench["per_layer"][-len(NEW):]
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW[0])  # appended together; later metrics after
+    mine = bench["per_layer"][first:first + len(NEW)]
     assert [m["name"] for m in mine] == list(NEW)
     for m in mine:
         assert m == {"name": m["name"], "unit": "s", "better": "lower",
