@@ -109,13 +109,14 @@ class FixedEffectCoordinate:
                 loss, batch, mesh, cfg, initial=Coefficients(w0), norm=norm,
                 intercept_index=ii, already_sharded=True)
             # Histories, the evaluation count, under the oracle the trials
-            # and under TRON each iteration's Hessian-vector products ride
-            # along for the run ledger's post-fit spill (tiny (max_it+1,)
-            # vectors and two integers; they stay on device — and cost
-            # nothing — unless a ledger is active).
+            # and under TRON each iteration's Hessian-vector products and
+            # whether the solve ended at float32's floor ride along for the
+            # run ledger's post-fit spill (tiny (max_it+1,) vectors and a
+            # few scalars; they stay on device — and cost nothing — unless
+            # a ledger is active).
             return (coef.means, res.value_history, res.grad_norm_history,
                     res.evaluations, res.trials if oracle else None,
-                    res.hvp_history)
+                    res.hvp_history, getattr(res, "floor_stop", None))
 
         @scoped("fe.fit")
         def fit(staged: LabeledBatch, offsets: Array, w0: Array):
@@ -201,14 +202,16 @@ class FixedEffectCoordinate:
             # The update's evaluation count rides on the last row (under
             # the oracle: pairs of passes over X, one an iteration and the
             # first), and beside it, where the oracle ran, its trials;
-            # under TRON every row carries its iteration's ``hvps``.
-            vals, gns, evals, trials, hvps = jax.device_get(spill)
+            # under TRON every row carries its iteration's ``hvps`` and the
+            # last one ``floor_stop``.
+            vals, gns, evals, trials, hvps, floor = jax.device_get(spill)
             spill_history(
                 led, vals, gns,
                 opt=self.config.optimizer.optimizer_type.value.lower(),
                 evaluations=int(evals),
                 counts=None if hvps is None else {"hvps": hvps},
-                trials=None if trials is None else int(trials))
+                trials=None if trials is None else int(trials),
+                floor_stop=None if floor is None else bool(floor))
         raw = Coefficients(self.norm.model_to_original_space(w_t))
         return FixedEffectModel(shard_id=self.shard_id, coefficients=raw)
 

@@ -61,17 +61,19 @@ _LANE_CHUNK = stg.LANE_CHUNK
 # What a fit wave counts inside its program, reduced over live lanes: the
 # solver fields of its ``re_fit_wave`` ledger row (docs/OBSERVABILITY.md).
 _WAVE_STATS = ("iters_sum", "iters_max", "evals_sum", "lanes_at_cap",
-               "trials_sum", "hvp_sum", "hvp_wave")
+               "trials_sum", "hvp_sum", "hvp_wave", "floor_sum")
 
 
 def _wave_stats(rows, iterations, evaluations, trials, hvp_history,
-                max_iterations: int):
-    """(7,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
+                max_iterations: int, floor_stop=None):
+    """(8,) int32 in ``_WAVE_STATS`` order, over the lanes that hold an
     entity (``rows >= 0``; padding lanes solve a benign problem of their
     own). ``hvp_history`` is TRON's (lanes, iterations + 1) CG steps, None
     for a solver without them: ``hvp_sum`` the live lanes' own products,
     ``hvp_wave`` those the wave computed, every lane stepping each CG loop
-    until its slowest lane stops. Stays on the device until the update's
+    until its slowest lane stops. ``floor_stop`` is TRON's (lanes,) bool,
+    None for the others: ``floor_sum`` the live lanes that ended at their
+    objective's float32 floor. Stays on the device until the update's
     ledger drain."""
     live = rows >= 0
     its = jnp.where(live, iterations, 0)
@@ -81,12 +83,14 @@ def _wave_stats(rows, iterations, evaluations, trials, hvp_history,
         hvps = jnp.stack([
             jnp.where(live, hvp_history.sum(axis=-1), 0).sum(),
             rows.shape[0] * hvp_history.max(axis=0).sum()])
+    floor = (jnp.zeros((1,), jnp.int32) if floor_stop is None
+             else (live & floor_stop).sum(keepdims=True))
     return jnp.concatenate([
         jnp.stack([its.sum(), its.max(),
                    jnp.where(live, evaluations, 0).sum(),
                    (its >= max_iterations).sum(),
                    jnp.where(live, trials, 0).sum()]),
-        hvps]).astype(jnp.int32)
+        hvps, floor]).astype(jnp.int32)
 
 
 def _wave_rows(pending):
@@ -713,8 +717,8 @@ class RandomEffectCoordinate:
                 ob = offsets[jnp.maximum(ex, 0)]
                 w0 = _gather_rows(W, rows)
             with jax.named_scope("re.solve"):
-                w_fit, *counts = solve(Xb, yb, wb, ob, w0)
-                stats = _wave_stats(rows, *counts, max_it)
+                w_fit, *counts, floor = solve(Xb, yb, wb, ob, w0)
+                stats = _wave_stats(rows, *counts, max_it, floor)
             with jax.named_scope("re.scatter"):
                 return _scatter_rows(W, rows, w_fit), stats
 
@@ -877,8 +881,8 @@ class RandomEffectCoordinate:
 
         def solve(rows, *args):
             with jax.named_scope("re.solve"):
-                w_fit, *counts = vsolve(*args)
-                return w_fit, _wave_stats(rows, *counts, max_it)
+                w_fit, *counts, floor = vsolve(*args)
+                return w_fit, _wave_stats(rows, *counts, max_it, floor)
 
         def fit_bucket(W, offsets, Xb, yb, wb, ex, rows, *extra):
             cols, f, s = unpack(extra)
@@ -924,7 +928,8 @@ class RandomEffectCoordinate:
         """One entity's GLM solve in transformed space (vmapped per bucket):
         the fitted row, and the iterations, objective evaluations and
         line-search trials the solver took for it, and under TRON the CG
-        steps of each iteration (None under the others).
+        steps of each iteration and whether it ended at its objective's
+        float32 floor (None under the others).
 
         The projected path passes a per-entity NormalizationContext and the
         projected intercept slot; the unprojected path uses the coordinate's
@@ -950,7 +955,8 @@ class RandomEffectCoordinate:
         trials = result.trials  # TRON has no line search
         return (result.w, result.iterations, result.evaluations,
                 jnp.zeros_like(result.iterations) if trials is None
-                else trials, result.hvp_history)  # TRON's alone
+                else trials, result.hvp_history,  # TRON's alone
+                getattr(result, "floor_stop", None))
 
     @property
     def _line_oracle(self) -> bool:

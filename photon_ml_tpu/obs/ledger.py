@@ -47,8 +47,9 @@ coordinate, outer iteration, descent step, grid point, tuning trial):
   solver's own counts reduced on the device over live lanes
   (``iters_sum``, ``iters_max``, ``evals_sum``, ``lanes_at_cap``,
   ``trials_sum``; under TRON ``hvp_sum``, the lanes' Hessian-vector
-  products, and ``hvp_wave``, those the wave computed: 0 under the other
-  solvers), and ``line``: whether a line-search trial read the
+  products, ``hvp_wave``, those the wave computed, and ``floor_sum``,
+  the lanes that ended at their objective's float32 floor: 0 under the
+  other solvers), and ``line``: whether a line-search trial read the
   lane's rows alone (``oracle``) or evaluated the objective over its
   block (``evaluation``).
   Written through :meth:`RunLedger.defer`: the counts are read once per
@@ -478,12 +479,14 @@ def spill_history(led: "RunLedger", values, grad_norms,
                   opt: str = "compiled",
                   evaluations: Optional[int] = None,
                   counts: Optional[dict] = None,
-                  trials: Optional[int] = None) -> int:
+                  trials: Optional[int] = None,
+                  floor_stop: Optional[bool] = None) -> int:
     """Spill a compiled optimizer's NaN-padded value/grad-norm histories
     as post-fit ``opt_iter`` rows (``clock: "post_fit"`` — row ``t`` is
     the spill time, so wall resolution is the coordinate update). The
     solve's ``evaluations`` (objective evaluations, line-search trials
-    included) and ``trials`` (all its line searches' trials), when given,
+    included), ``trials`` (all its line searches' trials) and TRON's
+    ``floor_stop`` (it ended at its objective's float32 floor), when given,
     ride on the last row; ``counts`` (name → one whole number an
     iteration, as long as ``values``: OWL-QN's ``trials``, ``nnz``,
     ``crossings``; TRON's ``hvps``) on every row. Returns the number of rows written."""
@@ -499,6 +502,8 @@ def spill_history(led: "RunLedger", values, grad_norms,
         rows[-1]["evaluations"] = int(evaluations)
     if rows and trials is not None:
         rows[-1]["trials"] = int(trials)
+    if rows and floor_stop is not None:
+        rows[-1]["floor_stop"] = bool(floor_stop)
     for row in rows:
         led.record("opt_iter", opt=opt, clock="post_fit", **row)
     return len(rows)

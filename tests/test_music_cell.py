@@ -3,8 +3,10 @@ quadratic to its normal equations under ``vmap``, its Hessian-vector
 products are counted as they run (``hvps``, ``hvp_history``), a wave's rows
 carry what its lanes needed and what the wave computed (``hvp_sum``,
 ``hvp_wave``), the fixed effect's rows carry each iteration's products, the
-dense fixed effect's TRON program holds no tiled copy of X, and the
-benchmark's item -> artist map is a function.
+dense fixed effect's TRON program holds no tiled copy of X, a solve ends at
+its objective's float32 floor (``floor_stop``, ``floor_sum``) and not on a
+rejection it can resolve, and the benchmark's item -> artist map is a
+function.
 
 Values, shapes and counts only, never a time.
 """
@@ -30,12 +32,14 @@ from photon_ml_tpu.game.coordinates import random_effect as re_mod
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.obs.ledger import RunLedger, read_rows
 from photon_ml_tpu.ops import losses
-from photon_ml_tpu.optim import OptimizerConfig, OptimizerType, tron
+from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType,
+                                 minimize_lbfgs, tron)
 from photon_ml_tpu.optim.problem import (GLMOptimizationConfiguration,
                                          make_objective)
 from photon_ml_tpu.optim.regularization import (RegularizationContext,
                                                 RegularizationType)
 from photon_ml_tpu.parallel.mesh import make_mesh
+from tests.test_optimizers import _logistic_problem
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
 for _p in (os.path.join(REPO, "benchmark"),
@@ -160,6 +164,88 @@ def test_a_solver_without_products_counts_none():
     assert stats["iters_sum"] == 12 and stats["trials_sum"] == 12
 
 
+# -- float32's floor -----------------------------------------------------------
+
+def _last_accepted(res):
+    """The last iteration whose step was accepted: a rejected step leaves
+    the value history where it was, an accepted one lowers it."""
+    vh = np.asarray(res.value_history)[:int(res.iterations) + 1]
+    moved = np.flatnonzero(vh[1:] != vh[:-1]) + 1
+    return int(moved[-1]) if moved.size else 0
+
+
+@pytest.mark.parametrize("rows,seed,tolerance", [
+    (2000, 0, 1e-7), (3000, 1, 1e-7), (4000, 4, 1e-7), (2000, 2, 0.0),
+    (4000, 3, 0.0)])
+def test_a_solve_ends_at_its_objectives_float32_floor(rows, seed, tolerance):
+    """A block of 0-100 ratings whose float32 objective is 4e5-9e5: after
+    its Newton steps the next one's decrease is under the objective's last
+    place, so it is rejected and the solve ends there, converged (under
+    ``tolerance`` 0 nothing else could end it but the radius's collapse,
+    which reads ``failed``) and at the block minimum all the same."""
+    X, y, w, o = (a[0] for a in _lanes(1, rows, seed))
+    w = jnp.ones_like(w)
+    res = jax.jit(lambda *b: _solve(*b, _config(tolerance=tolerance)))(
+        X, y, w, o)
+    assert 1e5 < float(res.value) < 1e6
+    assert bool(res.floor_stop) and bool(res.converged)
+    assert int(res.iterations) <= _last_accepted(res) + 1
+    want = _normal_equations(X, y, w, o)
+    np.testing.assert_allclose(np.asarray(res.w), want, rtol=1e-3,
+                               atol=1e-3 * np.abs(want).max())
+
+
+def test_each_lane_of_a_wave_stops_at_its_own_floor():
+    """Lanes whose objectives lie 1e1 to 1e11 apart: each ends on the
+    rejection its own objective cannot resolve, ``floor_sum`` counts the
+    live ones, and the wave ends far under the iteration cap."""
+    lanes = 6
+    X, y, w, o = _lanes(lanes, 300, 7)
+    scale = jnp.logspace(-2, 3, lanes)[:, None]
+    w, y, o = jnp.ones_like(w), y * scale, o * scale
+    res = jax.jit(jax.vmap(lambda *b: _solve(*b, _config(tolerance=0.0))))(
+        X, y, w, o)
+    f = np.asarray(res.value)
+    assert f.max() / f.min() > 1e9
+    assert np.asarray(res.floor_stop).all() and np.asarray(res.converged).all()
+    for k in range(lanes):
+        lane = jax.tree.map(lambda a: a[k], res)
+        assert int(lane.iterations) <= _last_accepted(lane) + 1
+        want = _normal_equations(X[k], y[k], w[k], o[k])
+        np.testing.assert_allclose(np.asarray(lane.w), want, rtol=1e-3,
+                                   atol=1e-3 * np.abs(want).max())
+    live = jnp.arange(lanes)
+    args = (res.iterations, res.evaluations, jnp.zeros_like(res.iterations),
+            res.hvp_history, 25, res.floor_stop)
+    stats = dict(zip(re_mod._WAVE_STATS, map(int, re_mod._wave_stats(
+        live, *args))))
+    assert stats["floor_sum"] == lanes
+    assert stats["iters_max"] < 25 and stats["lanes_at_cap"] == 0
+    padded = dict(zip(re_mod._WAVE_STATS, map(int, re_mod._wave_stats(
+        jnp.where(live == 0, -1, live), *args))))
+    assert padded["floor_sum"] == lanes - 1
+
+
+def test_resolvable_rejections_do_not_end_a_logistic_solve(rng):
+    """Started far from its optimum, a logistic solve's model overshoots
+    and early steps are rejected with decreases far above the floor: the
+    solve goes on past them to the optimum of
+    ``test_tron_logistic_matches_scipy_and_lbfgs``."""
+    vg, hvp, w_ref, _ = _logistic_problem(rng)
+    cfg = OptimizerConfig(max_iterations=100, tolerance=1e-9)
+    res = tron.minimize(vg, hvp, jnp.full((8,), 5.0), cfg)
+    vh = np.asarray(res.value_history)[:int(res.iterations) + 1]
+    rejected = np.flatnonzero(vh[1:] == vh[:-1]) + 1
+    first = int(rejected[0])
+    assert first < _last_accepted(res)  # accepted steps after it
+    eps = np.finfo(np.float32).eps
+    assert vh[first] - vh[-1] > 1e4 * eps * vh[first]  # far above the floor
+    assert bool(res.converged)
+    np.testing.assert_allclose(res.w, w_ref, rtol=2e-2, atol=2e-2)
+    lbfgs = minimize_lbfgs(vg, jnp.zeros(8), cfg)
+    np.testing.assert_allclose(res.w, lbfgs.w, rtol=2e-2, atol=2e-2)
+
+
 # -- the coordinates' rows ----------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -226,6 +312,37 @@ def test_fixed_rows_carry_each_iterations_products(mesh, tmp_path,
         assert rows[-1]["evaluations"] == rows[-1]["iteration"] + 1
     else:
         assert all("hvps" not in r for r in rows)
+
+
+@pytest.mark.parametrize("optimizer", [OptimizerType.TRON,
+                                       OptimizerType.LBFGS])
+def test_rows_say_which_solves_ended_at_the_floor(mesh, tmp_path, optimizer):
+    """Under TRON the fixed effect's last ``opt_iter`` row carries
+    ``floor_stop`` and every wave row ``floor_sum`` (at most its live
+    lanes); under L-BFGS the rows carry no ``floor_stop`` and ``floor_sum``
+    reads 0."""
+    ds = _game()
+    fixed = FixedEffectCoordinate(ds, "global", losses.SQUARED,
+                                  _opt(optimizer), mesh)
+    table = RandomEffectCoordinate(ds, "userId", "re_userId", losses.SQUARED,
+                                   _opt(optimizer), mesh)
+
+    def train():
+        fixed.train_model(jnp.asarray(ds.offsets))
+        table.train_model(jnp.asarray(ds.offsets))
+
+    rows = _rows(tmp_path, "floor", train)
+    opt_rows = [r for r in rows if r["kind"] == "opt_iter"]
+    waves = [r for r in rows if r["kind"] == "re_fit_wave"]
+    assert opt_rows and waves
+    assert all("floor_stop" not in r for r in opt_rows[:-1])
+    if optimizer == OptimizerType.TRON:
+        assert isinstance(opt_rows[-1]["floor_stop"], bool)
+        assert all(0 <= r["floor_sum"] <= r["entities_fit"] for r in waves)
+        assert sum(r["floor_sum"] for r in waves) > 0
+    else:
+        assert "floor_stop" not in opt_rows[-1]
+        assert all(r["floor_sum"] == 0 for r in waves)
 
 
 def test_tron_fixed_program_holds_no_row_major_copy_of_x(mesh):
