@@ -54,8 +54,13 @@ _HOT_EIGHTHS_OF_FREE = 4
 # of ``optim.lbfgs.minimize`` for a described v5e: 37 vectors under L-BFGS
 # and 44 under OWL-QN with m = 10, fragmentation included; PERF.md section
 # 6, PR 33), and the layout's two permutations.
+# TRON keeps no history: the one-shard hybrid ``fit`` under TRON, compiled
+# for a described v5e at 20,216,830 columns and 1M rows, holds 665,690,624 B
+# of scratch, 8.2 vectors of d (5.5 by its slope in d, the rest the rows'
+# and the cold entries' arrays), where L-BFGS's same fit holds 36.9 (PERF.md
+# section 6, the KDD Cup 2010 deployment).
 _SOLVER_VECTORS = {OptimizerType.LBFGS: 17, OptimizerType.OWLQN: 24,
-                   OptimizerType.TRON: 17}
+                   OptimizerType.TRON: 9}
 _LAYOUT_VECTORS = 2
 
 
@@ -64,7 +69,8 @@ def solver_state_bytes(dim: int,
     """Bytes the fixed effect's solve will hold on a device at ``dim``
     columns under ``config``'s optimiser and history length, beside the
     staged rows: what ``hot_block_budget`` takes off before it halves. 0.16
-    GB at d = 2**20; 10 GB, most of a v5e, at 54.7M under OWL-QN."""
+    GB at d = 2**20; 10 GB, most of a v5e, at 54.7M under OWL-QN; 0.89 GB
+    at 20.2M under TRON."""
     opt = resolve_optimizer_config(
         config.optimizer, config.regularization.l1_weight() > 0.0)
     kind = OptimizerType(opt.optimizer_type)
@@ -145,6 +151,17 @@ def _cold_column_counts(host) -> np.ndarray:
         counts[start:start + per_row.size - rem] += per_row[rem:]
         off += rem
     return counts
+
+
+def _spilled(res) -> tuple:
+    """What a fit hands the run ledger's post-fit spill besides the
+    coefficients: the value and gradient-norm histories, the evaluation
+    count, OWL-QN's trials and non-zeros an iteration, and TRON's products
+    an iteration and whether it ended at float32's floor (None where the
+    solver has no such count: no output of the program)."""
+    return (res.value_history, res.grad_norm_history, res.evaluations,
+            res.trials_history, res.nnz_history, res.hvp_history,
+            getattr(res, "floor_stop", None))
 
 
 class SparseFixedEffectCoordinate:
@@ -380,9 +397,7 @@ class SparseFixedEffectCoordinate:
             # Histories and the evaluation count ride along for the run
             # ledger's post-fit spill (tiny, device-resident, free when no
             # ledger is active).
-            return (coef.means[:d_true], res.value_history,
-                    res.grad_norm_history, res.evaluations,
-                    res.trials_history, res.nnz_history)
+            return (coef.means[:d_true], *_spilled(res))
 
         @scoped("fe.fit")
         def fit_sampled(staged, idx, mult, offsets, w0):
@@ -398,9 +413,7 @@ class SparseFixedEffectCoordinate:
                                initial=Coefficients(lift(w0)),
                                intercept_index=ii,
                                feature_sharded=fs, already_sharded=True)
-            return (coef.means[:d_true], res.value_history,
-                    res.grad_norm_history, res.evaluations,
-                    res.trials_history, res.nnz_history)
+            return (coef.means[:d_true], *_spilled(res))
 
         @scoped("fe.score")
         def score_fn(staged, means):
@@ -439,8 +452,7 @@ class SparseFixedEffectCoordinate:
             coef, res = sp.run_hybrid(loss, hbo, cfg,
                                       initial=Coefficients(w0),
                                       intercept_index_permuted=ii_perm)
-            return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations, res.trials_history, res.nnz_history)
+            return (coef.means, *_spilled(res))
 
         @scoped("fe.fit")
         def fit_sampled(hb, idx, mult, offsets, w0):
@@ -451,8 +463,7 @@ class SparseFixedEffectCoordinate:
             coef, res = sp.run_hybrid(loss, hbo, cfg,
                                       initial=Coefficients(w0),
                                       intercept_index_permuted=ii_perm)
-            return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations, res.trials_history, res.nnz_history)
+            return (coef.means, *_spilled(res))
 
         @scoped("fe.score")
         def score_fn(hb, means):
@@ -504,8 +515,7 @@ class SparseFixedEffectCoordinate:
             coef, res = sp.run_hybrid_sharded(
                 loss, shbo, mesh, cfg, initial=Coefficients(w0),
                 intercept_index_permuted=ii_perm)
-            return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations, res.trials_history, res.nnz_history)
+            return (coef.means, *_spilled(res))
 
         @scoped("fe.fit")
         def fit_sampled(shb, idx, mult, offsets, w0):
@@ -517,8 +527,7 @@ class SparseFixedEffectCoordinate:
             coef, res = sp.run_hybrid_sharded(
                 loss, shbo, mesh, cfg, initial=Coefficients(w0),
                 intercept_index_permuted=ii_perm)
-            return (coef.means, res.value_history, res.grad_norm_history,
-                    res.evaluations, res.trials_history, res.nnz_history)
+            return (coef.means, *_spilled(res))
 
         @scoped("fe.score")
         def score_fn(shb, means):
@@ -586,8 +595,13 @@ class SparseFixedEffectCoordinate:
             # the passes over the shard's non-zeros they cost: two for the
             # first evaluation, then a trial each and one for the accepted
             # point's gradient where the solve has its ValueOracle (the
-            # one-shard hybrid layout), two a trial elsewhere.
-            vals, gns, evals, trials, nnz = jax.device_get(spill)
+            # one-shard hybrid layout), two a trial elsewhere. A TRON
+            # solve's rows carry each iteration's Hessian-vector products
+            # and their crossings, three a product (margins at w and at v,
+            # the gradient pass) and two for the evaluation at the step,
+            # and its last row ``floor_stop``.
+            vals, gns, evals, trials, nnz, hvps, floor = jax.device_get(
+                spill)
             counts = None
             if trials is not None:
                 oracle = self.hybrid and not self._hybrid_sharded
@@ -595,11 +609,14 @@ class SparseFixedEffectCoordinate:
                 crossings[0] = 2
                 counts = {"trials": trials, "nnz": nnz,
                           "crossings": crossings}
+            elif hvps is not None:
+                counts = {"hvps": hvps, "crossings": 2 + 3 * hvps}
             spill_history(
                 led, vals, gns,
-                opt=("owlqn" if counts else
+                opt=("owlqn" if trials is not None else
                      self.config.optimizer.optimizer_type.value.lower()),
-                evaluations=int(evals), counts=counts)
+                evaluations=int(evals), counts=counts,
+                floor_stop=None if floor is None else bool(floor))
         return FixedEffectModel(shard_id=self.shard_id,
                                 coefficients=Coefficients(w))
 

@@ -904,12 +904,15 @@ def hessian_vector(
     v_perm: Array,
     hb: HybridSparseBatch,
 ) -> Array:
-    """Σ w·d2l·(x·v)·x in permuted space (TRON's H·v)."""
-    z = margins(hb, w_perm)
-    xv = margins(hb, v_perm) - hb.offsets
-    d2 = loss.d2z(z, hb.labels)
-    r = _masked(hb.weights, d2) * xv
-    return row_gradient(hb, r)
+    """Σ w·d2l·(x·v)·x in permuted space (TRON's H·v), under the scope
+    ``fe.hvp``: three passes over the non-zeros, the margins at ``w`` among
+    them, though ``w`` is the same for every step of one CG solve."""
+    with jax.named_scope("fe.hvp"):
+        z = margins(hb, w_perm)
+        xv = margins(hb, v_perm) - hb.offsets
+        d2 = loss.d2z(z, hb.labels)
+        r = _masked(hb.weights, d2) * xv
+        return row_gradient(hb, r)
 
 
 def hessian_diagonal(

@@ -629,7 +629,7 @@ def test_benchmark_lists_the_new_metrics_additively():
     new = _new_metrics()
     first, second = "ml20m-logistic.steady", "criteo-1m-logistic.steady"
     third, fourth = "kdd12-poisson-l1.steady", "avazu-sparse-re.steady"
-    fifth = "yahoo-music-tron.steady"
+    fifth, sixth = "yahoo-music-tron.steady", "kdd10-algebra-tron.steady"
     # PR 26's nineteen read the first cell alone; PR 29 appended its cell to
     # the eleven of them a cell with a sparse fixed effect and one table can
     # report, and added four of these stems for that table; PR 33 appended
@@ -637,18 +637,23 @@ def test_benchmark_lists_the_new_metrics_additively():
     # PR 35 did, with ``phase_s.project`` for its projection pass
     # The TRON cell appended itself to those of the eleven a solver without
     # a line search reads and to the first cell's tables' three that are not
-    # ``ls_evals``, and added those three for its third table.
+    # ``ls_evals``, and added those three for its third table. The KDD Cup
+    # 2010 cell appended itself to the eight that every TRON cell reads and
+    # added three of these stems for its per-student table.
     all_four = {"ls_evals.fixed", "scope_s.line_search", "scope_s.direction"}
     all_five = {"phase_s." + p for p in (
         "digest", "bucketing", "host_stage", "transfer", "program_load")} | {
         "scope_s." + p for p in ("value_grad", "gather_scatter", "score")}
     stems = ("re_iters", "lane_util", "pad_share", "ls_evals")
-    assert len(new) == 35
+    assert len(new) == 38
     assert {m["name"] for m in new
             if m["workloads"] == [first, second, third, fourth]} == all_four
     assert {m["name"] for m in new
-            if m["workloads"] == [first, second, third, fourth, fifth]
+            if m["workloads"] == [first, second, third, fourth, fifth, sixth]
             } == all_five
+    assert {m["name"] for m in new if m["workloads"] == [sixth]} == {
+        "re_iters.per-student", "lane_util.per-student",
+        "pad_share.per-student"}
     assert {m["name"] for m in new if m["workloads"] == [second]} == {
         stem + ".per-c10" for stem in stems}
     assert {m["name"] for m in new if m["workloads"] == [third]} == {

@@ -290,7 +290,7 @@ def test_the_budget_takes_the_solver_s_bytes_off_before_it_halves():
     ) == 4000 * (40 + 17 + 2)
     assert sparse_fixed.solver_state_bytes(
         1000, _config(OptimizerType.TRON, RegularizationType.L2)
-    ) == 4000 * (17 + 2)
+    ) == 4000 * (9 + 2)
     assert sparse_fixed.hot_block_budget(mesh, 2 * V5E_BYTES) == 0
     assert sparse_fixed.hot_block_budget(_Mesh({}), solver) is None
 
