@@ -157,10 +157,11 @@ def _spilled(res) -> tuple:
     """What a fit hands the run ledger's post-fit spill besides the
     coefficients: the value and gradient-norm histories, the evaluation
     count, OWL-QN's trials and non-zeros an iteration, and TRON's products
-    an iteration and whether it ended at float32's floor (None where the
-    solver has no such count: no output of the program)."""
+    and curvatures an iteration and whether it ended at float32's floor
+    (None where the solver has no such count: no output of the program)."""
     return (res.value_history, res.grad_norm_history, res.evaluations,
             res.trials_history, res.nnz_history, res.hvp_history,
+            getattr(res, "curvature_history", None),
             getattr(res, "floor_stop", None))
 
 
@@ -596,12 +597,14 @@ class SparseFixedEffectCoordinate:
             # first evaluation, then a trial each and one for the accepted
             # point's gradient where the solve has its ValueOracle (the
             # one-shard hybrid layout), two a trial elsewhere. A TRON
-            # solve's rows carry each iteration's Hessian-vector products
-            # and their crossings, three a product (margins at w and at v,
-            # the gradient pass) and two for the evaluation at the step,
-            # and its last row ``floor_stop``.
-            vals, gns, evals, trials, nnz, hvps, floor = jax.device_get(
-                spill)
+            # solve's rows carry each iteration's Hessian-vector products,
+            # the curvatures its CG solve computed (the one-shard hybrid
+            # layout computes the margins at w once a solve; elsewhere each
+            # product crosses for them itself) and their crossings: two for
+            # the evaluation at the step, one a curvature and two a product
+            # (X·v, the gradient pass); and its last row ``floor_stop``.
+            (vals, gns, evals, trials, nnz, hvps, curv,
+             floor) = jax.device_get(spill)
             counts = None
             if trials is not None:
                 oracle = self.hybrid and not self._hybrid_sharded
@@ -610,7 +613,9 @@ class SparseFixedEffectCoordinate:
                 counts = {"trials": trials, "nnz": nnz,
                           "crossings": crossings}
             elif hvps is not None:
-                counts = {"hvps": hvps, "crossings": 2 + 3 * hvps}
+                curv = hvps if curv is None else curv
+                counts = {"hvps": hvps, "curvatures": curv,
+                          "crossings": 2 + curv + 2 * hvps}
             spill_history(
                 led, vals, gns,
                 opt=("owlqn" if trials is not None else

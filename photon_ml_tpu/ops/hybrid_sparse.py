@@ -898,21 +898,41 @@ def value_and_gradient(
     return value, row_gradient(hb, r)
 
 
+def curvature(
+    loss: PointwiseLoss,
+    w_perm: Array,
+    hb: HybridSparseBatch,
+) -> Array:
+    """(n,) w·d2l at the margins of ``w``: the rows' curvature, which every
+    Hessian-vector product of one CG solve shares. One pass over the
+    non-zeros, under the scope ``fe.hvp``."""
+    with jax.named_scope("fe.hvp"):
+        return _masked(hb.weights, loss.d2z(margins(hb, w_perm), hb.labels))
+
+
+def hessian_vector_at(
+    d2: Array,
+    v_perm: Array,
+    hb: HybridSparseBatch,
+) -> Array:
+    """Σ d2·(x·v)·x in permuted space at the rows' ``curvature`` ``d2``:
+    TRON's H·v in two passes over the non-zeros (X·v, then the gradient
+    pass), under the scope ``fe.hvp``."""
+    with jax.named_scope("fe.hvp"):
+        xv = margins(hb, v_perm) - hb.offsets
+        return row_gradient(hb, d2 * xv)
+
+
 def hessian_vector(
     loss: PointwiseLoss,
     w_perm: Array,
     v_perm: Array,
     hb: HybridSparseBatch,
 ) -> Array:
-    """Σ w·d2l·(x·v)·x in permuted space (TRON's H·v), under the scope
-    ``fe.hvp``: three passes over the non-zeros, the margins at ``w`` among
-    them, though ``w`` is the same for every step of one CG solve."""
-    with jax.named_scope("fe.hvp"):
-        z = margins(hb, w_perm)
-        xv = margins(hb, v_perm) - hb.offsets
-        d2 = loss.d2z(z, hb.labels)
-        r = _masked(hb.weights, d2) * xv
-        return row_gradient(hb, r)
+    """Σ w·d2l·(x·v)·x in permuted space (TRON's H·v): the curvature at
+    ``w``, then the product at it, three passes over the non-zeros. A CG
+    solve computes the curvature once and takes ``hessian_vector_at``."""
+    return hessian_vector_at(curvature(loss, w_perm, hb), v_perm, hb)
 
 
 def hessian_diagonal(

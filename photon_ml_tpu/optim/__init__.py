@@ -12,8 +12,9 @@ import jax
 
 from photon_ml_tpu.optim import lbfgs as _lbfgs
 from photon_ml_tpu.optim import tron as _tron
-from photon_ml_tpu.optim.common import (Hvp, OptResult, OptimizerConfig,
-                                        OptimizerType, ValueAndGrad)
+from photon_ml_tpu.optim.common import (Curvature, Hvp, OptResult,
+                                        OptimizerConfig, OptimizerType,
+                                        ValueAndGrad)
 from photon_ml_tpu.optim.regularization import (RegularizationContext,
                                                 RegularizationType,
                                                 intercept_mask,
@@ -37,6 +38,7 @@ def optimize(
     hvp: Optional[Hvp] = None,
     l1_weights: Optional[Array] = None,
     line: "Optional[LineOracle | ValueOracle]" = None,
+    curvature: Optional[Curvature] = None,
 ) -> OptResult:
     """Dispatch on OptimizerType (reference: OptimizerFactory.scala).
 
@@ -45,7 +47,8 @@ def optimize(
     ``line`` is the same objective taken apart for the line search
     (optim/lbfgs.py): a ``LineOracle`` for L-BFGS, a ``ValueOracle`` for
     OWL-QN, and ``minimize`` refuses the one under the other; TRON has no
-    line search and does not ask it.
+    line search and does not ask it. ``curvature`` is TRON's alone: with it
+    ``hvp`` takes what it returns in place of ``w`` (optim/tron.py).
     """
     t = OptimizerType(config.optimizer_type)
     if t == OptimizerType.LBFGS:
@@ -62,7 +65,8 @@ def optimize(
             raise ValueError("TRON requires a Hessian-vector product (hvp)")
         if l1_weights is not None:
             raise ValueError("TRON does not support L1 (reference parity)")
-        return minimize_tron(value_and_grad, hvp, w0, config)
+        return minimize_tron(value_and_grad, hvp, w0, config,
+                             curvature=curvature)
     if t in (OptimizerType.SDCA, OptimizerType.SGD):
         raise ValueError(
             f"{t.value} is a streamed-path stochastic solver (it needs "

@@ -34,8 +34,10 @@ Array = jax.Array
 # objective(w) -> (value, grad). Regularization is folded in by the caller
 # (see photon_ml_tpu/optim/regularization.py).
 ValueAndGrad = Callable[[Array], tuple[Array, Array]]
-# hvp(w, v) -> H·v for TRON.
+# hvp(w, v) -> H·v for TRON; under a curvature, hvp(curvature(w), v).
 Hvp = Callable[[Array, Array], Array]
+# curvature(w) -> what every product of one CG solve at w shares.
+Curvature = Callable[[Array], Array]
 
 
 class OptimizerType(enum.Enum):

@@ -103,6 +103,9 @@ def with_l2_hvp(
     l2_weight: float,
     reg_mask: Optional[Array] = None,
 ) -> Callable[[Array, Array], Array]:
+    """``hvp`` plus the L2 term's H·v, λ·(v∘mask). The term reads ``v``
+    alone, so the first argument (``w``, or TRON's curvature) passes
+    through untouched."""
     if l2_weight == 0.0:
         return hvp
 

@@ -13,13 +13,22 @@ reference's one ``treeAggregate(HessianVectorAggregator)`` per CG step.
 Masked updates make the machine vmappable for per-entity solves, like
 photon_ml_tpu/optim/lbfgs.py.
 
+Every product of one CG solve is taken at the same ``w``, so a caller may
+hand the product over in two parts: ``curvature(w) -> c``, computed once a
+solve before its loop, and ``hvp(c, v)`` each step (for a GLM, ``c`` is the
+rows' curvature l''(z(w)) and a product is Xᵀ(c ⊙ X·v): one pass over X
+fewer a product). Without ``curvature``, ``c`` is ``w`` itself and the
+program is the one the plain ``hvp(w, v)`` traces to.
+
 The products are most of a solve, so the result counts them: ``hvps``, and
 ``hvp_history[k]`` the CG steps of outer iteration k (0 at the start and
 past the end). Under ``vmap`` the CG loop runs until its slowest lane's
 residual is small, so a wave computes Σₖ maxₗ ``hvp_history[l, k]``
 products in every lane; a lane that has converged runs no CG step of its
-own and so does not lengthen the loop. The Steihaug loop runs under the
-scope ``tron.cg``.
+own and so does not lengthen the loop. Under a ``curvature``, the result
+also counts the curvatures computed: ``curvatures``, and
+``curvature_history[k]`` 1 where outer iteration k's CG ran. The Steihaug
+loop and its curvature run under the scope ``tron.cg``.
 
 A solve also ends at the objective's own resolution: a rejected step whose
 predicted decrease is within a few units in the last place of ``f``
@@ -37,9 +46,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from photon_ml_tpu.optim.common import (Hvp, OptResult, OptimizerConfig,
-                                        ValueAndGrad, check_convergence,
-                                        masked_update, scoped)
+from photon_ml_tpu.optim.common import (Curvature, Hvp, OptResult,
+                                        OptimizerConfig, ValueAndGrad,
+                                        check_convergence, masked_update,
+                                        scoped)
 
 Array = jax.Array
 
@@ -65,6 +75,11 @@ class TronResult(OptResult):
     such field."""
 
     floor_stop: Optional[Array] = None
+    # Under a ``curvature``: int32, the curvatures the CG solves computed
+    # (one a solve), and (max_iterations + 1,) int32, those of each
+    # iteration (0 at the start and past the end); None without one.
+    curvatures: Optional[Array] = None
+    curvature_history: Optional[Array] = None
 
 
 @jax.tree_util.register_dataclass
@@ -83,18 +98,22 @@ class _TronState:
     hvps: Array  # Hessian-vector products taken, all iterations
     hvp_history: Array  # (max_iter + 1,) int32: the CG steps of each
     floor_stop: Array  # ended on a rejected step float32 could not resolve
+    # (max_iter + 1,) int32 under a ``curvature``: 1 where CG ran; else None
+    curvature_history: Optional[Array]
 
 
 @scoped("tron.cg")
-def _cg_steihaug(hvp, w, g, delta, max_cg, tol_cg, idle):
+def _cg_steihaug(hvp, w, g, delta, max_cg, tol_cg, idle, curvature=None):
     """Truncated CG: approximately solve H s = −g within ‖s‖ ≤ delta.
 
     Returns (s, sHs, gs, steps) where sHs = sᵀHs and gs = gᵀs, the pieces
     needed for the model-decrease computation, and steps the CG iterations
-    taken, one Hessian-vector product each. ``idle`` (a converged lane of a
-    vmapped solve, whose result is discarded) takes none.
+    taken, one Hessian-vector product ``hvp(c, p)`` each, at ``c =
+    curvature(w)`` computed once before the loop (``w`` without one).
+    ``idle`` (a converged lane of a vmapped solve, whose result is
+    discarded) takes none.
     """
-    d = g.shape[-1]
+    c = w if curvature is None else curvature(w)
     s0 = jnp.zeros_like(g)
     r0 = -g  # residual = -g - H s, s=0
     p0 = r0
@@ -107,7 +126,7 @@ def _cg_steihaug(hvp, w, g, delta, max_cg, tol_cg, idle):
 
     def body(st):
         s, r, p, rr, i, done = st
-        hp = hvp(w, p)
+        hp = hvp(c, p)
         php = jnp.dot(p, hp)
         # Negative curvature or tiny curvature → step to the boundary.
         alpha = rr / jnp.maximum(php, 1e-30)
@@ -139,8 +158,12 @@ def minimize(
     hvp: Hvp,
     w0: Array,
     config: OptimizerConfig = OptimizerConfig(),
+    curvature: Optional[Curvature] = None,
 ) -> TronResult:
-    """Trust-region Newton minimization of a twice-differentiable objective."""
+    """Trust-region Newton minimization of a twice-differentiable objective.
+
+    ``hvp(w, v)`` is H·v at ``w``; with ``curvature``, ``hvp(c, v)`` is H·v
+    at the point whose ``curvature(w)`` is ``c`` (the module's docstring)."""
     max_iter = config.max_iterations
 
     f0, g0 = value_and_grad(w0)
@@ -161,13 +184,15 @@ def minimize(
         hvps=jnp.asarray(0, jnp.int32),
         hvp_history=jnp.zeros((max_iter + 1,), jnp.int32),
         floor_stop=jnp.asarray(False),
+        curvature_history=(None if curvature is None else
+                           jnp.zeros((max_iter + 1,), jnp.int32)),
     )
     floor_rel = _FLOOR_ULPS * jnp.finfo(f0.dtype).eps
 
     def body(state: _TronState) -> _TronState:
         s, sHs, gs, steps = _cg_steihaug(
             hvp, state.w, state.g, state.delta, config.max_cg_iterations, 0.1,
-            state.converged)
+            state.converged, curvature)
         prered = -(gs + 0.5 * sHs)  # predicted decrease of the quadratic model
         w_new = state.w + s
         f_new, g_new = value_and_grad(w_new)
@@ -216,6 +241,9 @@ def minimize(
             hvps=state.hvps + steps,
             hvp_history=state.hvp_history.at[it].set(steps),
             floor_stop=state.floor_stop | (floor & ~conv),
+            # A converged lane's count is frozen with the rest of its state.
+            curvature_history=(None if curvature is None else
+                               state.curvature_history.at[it].set(1)),
         )
         return masked_update(state.converged, new_state, state)
 
@@ -235,4 +263,7 @@ def minimize(
         hvps=final.hvps,
         hvp_history=final.hvp_history,
         floor_stop=final.floor_stop,
+        curvatures=(None if curvature is None else
+                    jnp.sum(final.curvature_history)),
+        curvature_history=final.curvature_history,
     )

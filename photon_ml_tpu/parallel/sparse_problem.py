@@ -166,8 +166,10 @@ def run_hybrid(
 
     vg = scoped("glm.value_grad", with_l2(
         lambda w: hybrid.value_and_gradient(loss, w, hb), l2, mask))
+    # TRON takes its products at the rows' curvature, computed once a CG
+    # solve, so a product makes two passes over the non-zeros, not three.
     hvp = with_l2_hvp(
-        lambda w, v: hybrid.hessian_vector(loss, w, v, hb), l2, mask)
+        lambda d2, v: hybrid.hessian_vector_at(d2, v, hb), l2, mask)
 
     l1 = reg.l1_weight()
     l1w = l1 * mask if l1 > 0.0 else None
@@ -179,7 +181,8 @@ def run_hybrid(
         w0 = jnp.zeros((dim,), jnp.float32)
 
     result = optimize(vg, w0, opt_cfg, hvp=hvp, l1_weights=l1w,
-                      line=_hybrid_line(loss, hb, l2, mask, l1w is not None))
+                      line=_hybrid_line(loss, hb, l2, mask, l1w is not None),
+                      curvature=lambda w: hybrid.curvature(loss, w, hb))
 
     variances = None
     kind = VarianceComputationType(config.variance_computation)

@@ -141,6 +141,8 @@ PASSES = {
         [x.reshape(-1) for x in hs.value_and_gradient(loss, w, hb)]),
     "hessian_vector": lambda loss, hb, w, v: hs.hessian_vector(
         loss, w, v, hb),
+    "hessian_vector_at": lambda loss, hb, w, v: hs.hessian_vector_at(
+        hs.curvature(loss, w, hb), v, hb),
     "hessian_diagonal": lambda loss, hb, w, v: hs.hessian_diagonal(
         loss, w, hb),
 }

@@ -2,16 +2,19 @@
 rows of varying length is real-valued, so the resident layout holds its hot
 columns as float32 values beside the cold classes (``hot_storage``); the
 layout's Hessian-vector product is the dense float64 Xᵀ·D·X·v over both
-parts; the sparse coordinate's TRON solve reaches the reference's minimiser
-of the same logistic objective; its ``opt_iter`` rows carry each
-iteration's products, the crossings they cost and ``floor_stop``; and the
-hot block's budget reckons TRON's own vectors, not L-BFGS's.
+parts, and its two parts (the rows' curvature, then the product at it) are
+that product to the bit; the sparse coordinate's TRON solve reaches the
+reference's minimiser of the same logistic objective; its ``opt_iter`` rows
+carry each iteration's products and curvatures, the crossings they cost
+and ``floor_stop``; and the hot block's budget reckons TRON's own vectors,
+not L-BFGS's.
 
 Values, shapes and counts only, never a time.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 
@@ -114,6 +117,39 @@ def test_hessian_vector_is_the_dense_float64_product(data, hot_threshold):
                                atol=2e-4 * np.abs(want).max())
 
 
+@pytest.mark.parametrize("hot_threshold", [8, 40, None])
+def test_the_product_at_the_curvature_is_the_product(data, hot_threshold):
+    """``hessian_vector_at(curvature(w), v)``, the two parts TRON takes a
+    CG solve's products in, is the dense float64 Xᵀ·D·X·v and, to the bit,
+    ``hessian_vector(w, v)``: over both parts of the layout and with no hot
+    block at all (a threshold above every column's count)."""
+    rng = np.random.default_rng(7)
+    if hot_threshold is None:
+        hb = hs.build_hybrid(_batch(data), hot_threshold=10 ** 6)
+        assert hb.num_hot == 0
+    else:
+        hb = hs.build_hybrid(_batch(data), hot_threshold=hot_threshold)
+        assert hs.hot_storage(hb) == "float32" and hb.num_hot > 0
+    assert len(hb.cold_rowids) > 0
+    hb = dataclasses.replace(hb, offsets=jnp.asarray(
+        rng.normal(size=data.response.shape[0]), jnp.float32))
+    X = _dense(data)
+    w = rng.normal(size=data.num_features)
+    v = rng.normal(size=data.num_features)
+    p = 1 / (1 + np.exp(-(X @ w + np.asarray(hb.offsets, np.float64))))
+    want = X.T @ (p * (1 - p) * (X @ v))
+    w_p = hs.to_permuted_space(hb, jnp.asarray(w, jnp.float32))
+    v_p = hs.to_permuted_space(hb, jnp.asarray(v, jnp.float32))
+    split = jax.jit(lambda w, v, hb: hs.hessian_vector_at(
+        hs.curvature(losses.LOGISTIC, w, hb), v, hb))(w_p, v_p, hb)
+    whole = jax.jit(lambda w, v, hb: hs.hessian_vector(
+        losses.LOGISTIC, w, v, hb))(w_p, v_p, hb)
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(whole))
+    got = hs.to_original_space(hb, split)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4,
+                               atol=2e-4 * np.abs(want).max())
+
+
 def _dataset(data) -> GameDataset:
     n = data.response.shape[0]
     return GameDataset(
@@ -177,19 +213,70 @@ def test_the_tron_fit_reaches_the_references_minimiser(data, fitted):
     assert not got[untouched].any()  # no row, no gradient: exactly 0
 
 
+def _count(jaxpr, name):
+    """Equations of primitive ``name`` in ``jaxpr`` and every sub-jaxpr."""
+    n = 0
+    for e in jaxpr.eqns:
+        n += e.primitive.name == name
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count(sub, name)
+    return n
+
+
+def _loops(jaxpr, depth=0):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "while":
+            yield depth, e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _loops(sub, depth + (e.primitive.name
+                                                    == "while"))
+
+
+def test_the_cg_loop_crosses_the_cold_entries_twice_a_product(data, one):
+    """The fit's Steihaug loop (TRON's inner ``while``) holds the product at
+    the curvature and no more: its scatter-adds and gathers are
+    ``hessian_vector_at``'s, one scatter fewer than ``hessian_vector``'s,
+    whose margins at w the loop no longer recomputes each step."""
+    coord = SparseFixedEffectCoordinate(_dataset(data), "global",
+                                        losses.LOGISTIC, _tron(), one)
+    hb, n = coord._staged, data.response.shape[0]
+    jaxpr = jax.make_jaxpr(coord._fit)(hb, jnp.zeros(n, jnp.float32),
+                                       jnp.zeros(coord.dim, jnp.float32))
+    cg = [e for depth, e in _loops(jaxpr.jaxpr) if depth == 1]
+    assert len(cg) == 1
+    body = cg[0].params["body_jaxpr"].jaxpr
+    w = jnp.zeros(hb.num_features, jnp.float32)
+    d2 = jnp.ones(n, jnp.float32)
+    at = jax.make_jaxpr(lambda d2, v: hs.hessian_vector_at(d2, v, hb))(
+        d2, w).jaxpr
+    whole = jax.make_jaxpr(lambda w, v: hs.hessian_vector(
+        losses.LOGISTIC, w, v, hb))(w, w).jaxpr
+    for prim in ("scatter-add", "gather"):
+        assert _count(body, prim) == _count(at, prim) > 0, prim
+    assert _count(whole, "scatter-add") == _count(at, "scatter-add") + 1
+
+
 def test_tron_rows_carry_products_crossings_and_the_floor(fitted):
     """Every ``opt_iter`` row of the solve carries its products (0 at the
-    start) and the passes over the shard they cost: two for the starting
-    evaluation, then two for the step's evaluation and three a product;
-    the last row ``evaluations`` and ``floor_stop``; the layout row says
-    the block holds float32 values."""
+    start), the curvatures its CG solve computed (one an iteration, none at
+    the start) and the passes over the shard they cost: two for the
+    starting evaluation, then two for the step's evaluation, one a
+    curvature and two a product; the last row ``evaluations`` and
+    ``floor_stop``; the layout row says the block holds float32 values."""
     _, rows = fitted
     its = [r for r in rows if r["kind"] == "opt_iter"]
     assert its and its[0]["iteration"] == 0 and its[0]["hvps"] == 0
     assert all(r["opt"] == "tron" for r in its)
     assert all(r["hvps"] >= 1 for r in its[1:])
+    assert [r["curvatures"] for r in its] == [0] + [1] * (len(its) - 1)
     assert [r["crossings"] for r in its] == [2] + [
-        2 + 3 * r["hvps"] for r in its[1:]]
+        2 + r["curvatures"] + 2 * r["hvps"] for r in its[1:]]
     assert its[-1]["evaluations"] == its[-1]["iteration"] + 1
     assert isinstance(its[-1]["floor_stop"], bool)
     assert all("floor_stop" not in r for r in its[:-1])

@@ -225,6 +225,90 @@ def test_vmapped_tron_matches_individual(rng):
         np.testing.assert_allclose(outs.w[i], out_i.w, rtol=5e-3, atol=5e-3)
 
 
+def _split_logistic(X, y, weights, l2=0.1):
+    """A logistic objective's (vg, hvp, curvature, hvp_at): the product at
+    w, and the same product in TRON's two parts, the rows' curvature at w
+    and the product at it."""
+    X_j, y_j, wt = (jnp.asarray(a, jnp.float32) for a in (X, y, weights))
+
+    def vg(w):
+        z = X_j @ w
+        l, dl = losses.LOGISTIC.loss_and_dz(z, y_j)
+        return (jnp.sum(wt * l) + 0.5 * l2 * jnp.dot(w, w),
+                X_j.T @ (wt * dl) + l2 * w)
+
+    def curvature(w):
+        return wt * losses.LOGISTIC.d2z(X_j @ w, y_j)
+
+    def hvp_at(c, v):
+        return X_j.T @ (c * (X_j @ v)) + l2 * v
+
+    return vg, (lambda w, v: hvp_at(curvature(w), v)), curvature, hvp_at
+
+
+def _same_solve(split, plain):
+    for name in ("w", "value", "iterations", "value_history",
+                 "grad_norm_history", "hvps", "hvp_history"):
+        np.testing.assert_array_equal(np.asarray(getattr(split, name)),
+                                      np.asarray(getattr(plain, name)),
+                                      err_msg=name)
+
+
+def test_tron_with_a_curvature_solves_as_without(rng):
+    """The CG solve's curvature computed once before its loop takes the
+    steps the product at w takes, to the bit; the result counts one
+    curvature an iteration whose CG ran, and none without a curvature."""
+    n, d = 200, 8
+    X = rng.normal(size=(n, d))
+    X[:, -1] = 1.0
+    y = (rng.uniform(size=n) < 0.4).astype(np.float32)
+    vg, hvp, curvature, hvp_at = _split_logistic(X, y, np.ones(n))
+    cfg = OptimizerConfig(max_iterations=30, tolerance=1e-9)
+    plain = jax.jit(lambda w0: minimize_tron(vg, hvp, w0, cfg))(
+        jnp.zeros(d))
+    split = jax.jit(lambda w0: minimize_tron(
+        vg, hvp_at, w0, cfg, curvature=curvature))(jnp.zeros(d))
+    _same_solve(split, plain)
+    it = int(plain.iterations)
+    ran = (np.asarray(plain.hvp_history) > 0).astype(np.int32)
+    assert it >= 2 and ran.sum() == it
+    np.testing.assert_array_equal(np.asarray(split.curvature_history), ran)
+    assert int(split.curvatures) == it
+    assert plain.curvatures is None and plain.curvature_history is None
+
+
+def test_vmapped_tron_with_a_curvature_leaves_an_idle_lane_alone(rng):
+    """Lanes solved under ``vmap`` through the split take the plain
+    product's steps; a lane that starts converged (every weight 0, so its
+    gradient is 0) computes no curvature, takes no product and keeps its
+    starting point."""
+    E, n, d = 3, 60, 4
+    Xs = rng.normal(size=(E, n, d))
+    ys = (rng.uniform(size=(E, n)) < 0.5).astype(np.float32)
+    wts = np.ones((E, n))
+    wts[1] = 0.0
+    cfg = OptimizerConfig(max_iterations=30, tolerance=1e-9)
+
+    def solve(X, y, wt, split):
+        vg, hvp, curvature, hvp_at = _split_logistic(X, y, wt)
+        if split:
+            return minimize_tron(vg, hvp_at, jnp.zeros(d), cfg,
+                                 curvature=curvature)
+        return minimize_tron(vg, hvp, jnp.zeros(d), cfg)
+
+    outs = [jax.jit(jax.vmap(lambda X, y, wt, s=s: solve(X, y, wt, s)))(
+        jnp.asarray(Xs), jnp.asarray(ys), jnp.asarray(wts))
+        for s in (True, False)]
+    split, plain = outs
+    _same_solve(split, plain)
+    its = np.asarray(plain.iterations)
+    assert its[1] == 0 and (its[[0, 2]] >= 2).all()
+    np.testing.assert_array_equal(np.asarray(split.curvatures), its)
+    assert int(split.hvps[1]) == 0
+    assert not np.asarray(split.curvature_history[1]).any()
+    np.testing.assert_array_equal(np.asarray(split.w[1]), np.zeros(d))
+
+
 def test_history_tracking(rng):
     vg, _, _ = _quadratic(6, rng)
     out = minimize_lbfgs(vg, jnp.zeros(6), OptimizerConfig(
